@@ -1,6 +1,6 @@
 """Paper Fig 6.1/6.3: runtime vs problem size + strong-scaling model.
 
-Real-TPU wall times are unavailable (CPU container); reported here:
+Reported here:
   (a) measured single-device AWPM runtime across matrix sizes (the paper's
       "sequential AWPM" baseline),
   (b) the analytic strong-scaling model of §5.3 evaluated with v5e constants
@@ -8,10 +8,10 @@ Real-TPU wall times are unavailable (CPU container); reported here:
       reproducing the shape of Fig 6.3,
   (c) measured AWAC per-round cost decomposition (requests, join, select).
   (d) measured distributed-BATCHED throughput (DESIGN.md §5): one
-      planned ``Matcher`` dispatch for B instances on a simulated p-device
-      2D grid, p in {1, 2, 4, 8} x B in {1, 8, 32}. Each p runs in a
-      subprocess because the fake device count must be set before jax
-      initializes (same constraint as tests/test_core_dist.py).
+      planned ``Matcher`` dispatch for B instances on a p-device 2D grid,
+      p in {1, 2, 4, 8} x B in {1, 8, 32}. Grids the attached devices can
+      hold run in-process; on the CPU, larger grids run in a child with
+      fake host devices (see ``run_dist_batched``).
 """
 import os
 import pathlib
@@ -29,58 +29,79 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 # p -> 2D mesh shape (both orientations are CI-tested; the bench uses one)
 DIST_MESHES = {1: (1, 1), 2: (1, 2), 4: (2, 2), 8: (2, 4)}
 
-DIST_CHILD = r"""
-import time
-import numpy as np, jax
-from repro.core import MatchingProblem, SolveOptions, graph, plan, solve
-from repro.runtime.straggler import StragglerMonitor
+def dist_rows(p: int, n: int, deg: float, emit) -> None:
+    """Distributed-batched rows for one p-device grid of ``jax.devices()``;
+    ``emit(name, us, derived)`` receives each row."""
+    import time
 
-p, pr, pc, n, deg = {p}, {pr}, {pc}, {n}, {deg}
-mesh = jax.sharding.Mesh(
-    np.array(jax.devices()[:p]).reshape(pr, pc), ("data", "model"))
-# 1x1 grid routes Steps A+B+C through core.batch's fused sweep directly
-backend = "xla" if p == 1 else "fused"
-for b in (1, 8, 32):
-    gs = [graph.generate(n, avg_degree=deg, kind="uniform", seed=s)
-          for s in range(b)]
-    problem = MatchingProblem.stack(gs)
-    # plan once: capacity + bucket planning, engine build (the Matcher
-    # replaces the old DistBatchedAWPM + make_awpm_dist_batched zoo); each
-    # timed call is partition + one shard_map dispatch (serving shape)
-    matcher = plan(problem, SolveOptions(grid=mesh, backend=backend))
-    res = matcher(problem)  # compile + warmup
-    jax.block_until_ready(res)
-    # straggler monitor over the serving loop: each dispatch is one "step"
-    # on serving rank 0 (simulated meshes share one host, so the cross-rank
-    # z-score path is idle — slow_steps() is the single-rank alarm)
-    mon = StragglerMonitor(alpha=0.2, threshold=2.0, warmup=5)
-    reps = 6
-    t0 = time.perf_counter()
-    for step in range(reps):
-        ts = time.perf_counter()
-        out = matcher(problem)
-        jax.block_until_ready(out)
-        mon.record(step, time.perf_counter() - ts, rank=0)
-    dt = (time.perf_counter() - t0) / reps
-    slow = mon.slow_ranks() or mon.slow_steps()
-    resL = solve(problem)
-    ident = bool(np.array_equal(np.array(resL.mate_row),
-                                np.array(res.mate_row)))
-    # timed=serving marks the measurement-definition change vs the pre-facade
-    # rows: each rep now includes host partition + device_put + dispatch
-    # (the real serving shape), not just the compiled engine call — the two
-    # regimes are not comparable under one name without this flag.
-    print(f"ROW,awpm_dist_batched_p{{p}}_B{{b}},{{dt / b * 1e6:.1f}},"
-          f"matchings_per_s={{b / dt:.1f}};mesh={{pr}}x{{pc}};"
-          f"backend={{backend}};timed=serving;identical_to_local={{ident}};"
-          f"straggler_flagged={{'|'.join(map(str, slow)) or 'none'}}",
-          flush=True)
+    import jax
+
+    from repro.core import SolveOptions, plan
+    from repro.runtime.straggler import StragglerMonitor
+
+    pr, pc = DIST_MESHES[p]
+    mesh = jax.sharding.Mesh(
+        np.array(jax.devices()[:p]).reshape(pr, pc), ("data", "model"))
+    # 1x1 grid routes Steps A+B+C through core.batch's fused sweep directly
+    backend = "xla" if p == 1 else "fused"
+    for b in (1, 8, 32):
+        gs = [graph.generate(n, avg_degree=deg, kind="uniform", seed=s)
+              for s in range(b)]
+        problem = MatchingProblem.stack(gs)
+        # plan once: capacity + bucket planning, engine build; each timed
+        # call is partition + one shard_map dispatch (serving shape)
+        matcher = plan(problem, SolveOptions(grid=mesh, backend=backend))
+        res = matcher(problem)  # compile + warmup
+        jax.block_until_ready(res)
+        # straggler monitor over the serving loop: each dispatch is one
+        # "step" on serving rank 0 (the cross-rank z-score path is idle on
+        # one host — slow_steps() is the single-rank alarm)
+        mon = StragglerMonitor(alpha=0.2, threshold=2.0, warmup=5)
+        reps = 6
+        t0 = time.perf_counter()
+        for step in range(reps):
+            ts = time.perf_counter()
+            out = matcher(problem)
+            jax.block_until_ready(out)
+            mon.record(step, time.perf_counter() - ts, rank=0)
+        dt = (time.perf_counter() - t0) / reps
+        slow = mon.slow_ranks() or mon.slow_steps()
+        resL = solve(problem)
+        ident = bool(np.array_equal(np.array(resL.mate_row),
+                                    np.array(res.mate_row)))
+        # timed=serving: each rep includes host partition + device_put +
+        # dispatch (the real serving shape), not just the compiled engine
+        emit(f"awpm_dist_batched_p{p}_B{b}", dt / b * 1e6,
+             f"matchings_per_s={b / dt:.1f};mesh={pr}x{pc};"
+             f"backend={backend};timed=serving;identical_to_local={ident};"
+             f"straggler_flagged={'|'.join(map(str, slow)) or 'none'}")
+
+
+DIST_CHILD = r"""
+from benchmarks.bench_scaling import dist_rows
+
+dist_rows({p}, {n}, {deg},
+          lambda name, us, d: print(f"ROW,{{name}},{{us:.1f}},{{d}}",
+                                    flush=True))
 """
 
 
 def run_dist_batched(n: int = 24, deg: float = 6.0):
-    """Distributed-batched matchings/sec rows via one subprocess per p."""
-    for p, (pr, pc) in DIST_MESHES.items():
+    """Distributed-batched matchings/sec rows, p in DIST_MESHES. A grid the
+    attached devices can hold runs in this process. Only on the CPU does a
+    larger grid run in a child with fake host devices (the device count
+    must be set before jax initializes); on an accelerator it is skipped,
+    because this process holds the chip and a child must never need it."""
+    import jax
+
+    for p in DIST_MESHES:
+        if p <= jax.device_count():
+            dist_rows(p, n, deg, row)
+            continue
+        if jax.default_backend() != "cpu":
+            print(f"# skip p={p}: only {jax.device_count()} "
+                  f"{jax.default_backend()} device(s) attached", flush=True)
+            continue
         env = dict(os.environ)
         # strip any inherited device-count token entirely — XLA aborts on
         # unknown flags, so the stale token can't just be renamed
@@ -88,8 +109,10 @@ def run_dist_batched(n: int = 24, deg: float = 6.0):
                            env.get("XLA_FLAGS", ""))
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={p} {inherited}").strip()
-        env["PYTHONPATH"] = f"{REPO / 'src'}:{env.get('PYTHONPATH', '')}"
-        script = DIST_CHILD.format(p=p, pr=pr, pc=pc, n=n, deg=deg)
+        env["JAX_PLATFORMS"] = "cpu"  # fake host devices, never a chip
+        env["PYTHONPATH"] = (f"{REPO}:{REPO / 'src'}:"
+                             f"{env.get('PYTHONPATH', '')}")
+        script = DIST_CHILD.format(p=p, n=n, deg=deg)
         proc = subprocess.run([sys.executable, "-c", script],
                               capture_output=True, text=True, env=env,
                               timeout=1800)
@@ -101,6 +124,7 @@ def run_dist_batched(n: int = 24, deg: float = 6.0):
             if line.startswith("ROW,"):
                 _, name, us, derived = line.split(",", 3)
                 row(name, float(us), derived)
+
 
 ALPHA = 1e-6  # s per message (ICI latency)
 BETA = 1.0 / 50e9  # s per byte per link

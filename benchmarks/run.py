@@ -47,6 +47,9 @@ def main() -> None:
                     help="skip writing BENCH_*.json files")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_approx_ratio, bench_breakdown, bench_kernels, bench_moe_router,
         bench_pivot, bench_scaling, bench_serving,

@@ -1,14 +1,20 @@
-"""Distributed AWPM on a 4x4 device grid (fake devices — the same shard_map
-program that the 512-chip dry-run lowers), through the unified API: the ONLY
+"""Distributed AWPM on a 2D device grid through the unified API: the ONLY
 change vs a local solve is ``SolveOptions(grid=mesh)``, and ``plan()`` gives
 a compile-once/run-many ``Matcher`` for serving many batches.
 
+The grid is the largest power-of-two rectangle of the attached devices
+(2x2 on a four-chip host). With ``JAX_PLATFORMS=cpu`` the example rehearses
+a 4x4 grid on 16 fake host devices; everything runs in this one process.
+
   PYTHONPATH=src python examples/distributed_matching.py [--n 256]
+  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/distributed_matching.py
 """
 import argparse
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=16")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=16")
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
@@ -17,11 +23,18 @@ from repro.core import MatchingProblem, SolveOptions, graph, plan, ref, solve  #
 from repro.core.dist import make_mesh  # noqa: E402
 
 
+def grid_shape(count: int) -> tuple[int, int]:
+    """Largest power-of-two (pr, pc) grid with pr <= pc and pr * pc <= count."""
+    k = count.bit_length() - 1
+    return 2 ** (k // 2), 2 ** (k - k // 2)
+
+
 def main(n=256, degree=8.0, seed=0, batch=4):
-    mesh = make_mesh((4, 4))
+    pr, pc = grid_shape(len(jax.devices()))
+    mesh = make_mesh((pr, pc))
     g = graph.generate(n, avg_degree=degree, kind="uniform", seed=seed)
-    print(f"matrix n={g.n} nnz={g.nnz} on a 4x4 process grid "
-          f"({len(jax.devices())} devices)")
+    print(f"matrix n={g.n} nnz={g.nnz} on a {pr}x{pc} process grid "
+          f"({len(jax.devices())} {jax.default_backend()} devices)")
 
     # one-shot: identical call shape to the local path, plus grid=
     problem = MatchingProblem.from_graph(g)

@@ -31,6 +31,7 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO_ROOT / "src"))
 
 from repro.experiments import paper_eval  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def _parse_grids(text: str):
@@ -58,8 +59,9 @@ def main() -> None:
                          "(default: all three; --quick: reference,xla)")
     ap.add_argument("--grids", default=None,
                     help="comma list of PRxPC grids (default: 1x1,2x2; "
-                         "--quick: 1x1). Grids beyond the attached device "
-                         "count run in a fake-device subprocess.")
+                         "--quick: 1x1). On the CPU, grids beyond the "
+                         "attached device count run in a fake-device "
+                         "subprocess.")
     ap.add_argument("--suite-count", type=int, default=None,
                     help="number of synthetic suite matrices (default 10)")
     ap.add_argument("--suite-n", type=int, default=None,
@@ -85,6 +87,7 @@ def main() -> None:
                          "~/.cache/repro-suitesparse)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     spec = dict(paper_eval.QUICK_SPEC if args.quick
                 else paper_eval.DEFAULT_SPEC)
     if args.suite_count is not None:

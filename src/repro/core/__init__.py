@@ -10,6 +10,7 @@ the ``Dist*`` driver zoo) remain as bit-identical deprecation shims.
 from repro.core import api, batch, dual, graph, pivot, preflight, ref, single
 from repro.core.api import (
     BACKENDS,
+    BackendUnavailableError,
     ON_INVALID,
     Matcher,
     MatchingProblem,
@@ -34,6 +35,7 @@ __all__ = [
     "ref",
     "single",
     "BACKENDS",
+    "BackendUnavailableError",
     "MIN_GAIN",
     "ON_INVALID",
     "DualCertificate",

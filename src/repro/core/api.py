@@ -56,7 +56,7 @@ from repro.core import graph as _graph
 from repro.core import preflight as _preflight
 from repro.core import single as _single
 from repro.core.constants import MIN_GAIN
-from repro.core.single import MatchState
+from repro.core.single import BackendUnavailableError, MatchState
 from repro.sparse.csr import window_depth
 
 #: every backend ``SolveOptions`` accepts. "auto" resolves locally via the
@@ -75,6 +75,7 @@ ON_INVALID = ("raise", "sanitize", "degrade")
 
 __all__ = [
     "BACKENDS",
+    "BackendUnavailableError",
     "MIN_GAIN",
     "ON_INVALID",
     "MatchResult",
@@ -267,6 +268,8 @@ class SolveOptions:
     max_iter      AWAC round budget (>= 0; 0 skips refinement entirely).
     min_gain      minimum 4-cycle gain to count as augmenting (paper eps).
     backend       one of :data:`BACKENDS`; "auto" picks per dispatch target.
+                  A Pallas backend that the platform cannot compile
+                  raises :class:`BackendUnavailableError`.
     window_steps  windowed-search depth override (None = measured/derived;
                   extra depth never changes results, and an undersized
                   override is clamped up to the measured need).
@@ -311,6 +314,9 @@ class SolveOptions:
             raise ValueError(
                 f"unknown backend {self.backend!r}: expected one of "
                 f"{BACKENDS}")
+        # a backend the platform's compiler refuses fails here, typed,
+        # not deep inside lowering
+        _single.check_backend_available(self.backend)
         if self.on_invalid not in ON_INVALID:
             raise ValueError(
                 f"unknown on_invalid policy {self.on_invalid!r}: expected "
@@ -393,17 +399,18 @@ class ExecutionInfo:
     ``backend``: the concrete engine that ran (never "auto").
     ``source``: how it was chosen — "explicit" (user-pinned), "table" (the
     measured dispatch table, ``repro.kernels.dispatch``), "heuristic"
-    (platform fallback when the table has no measurements for this
-    platform), or "grid-default" (the distributed route's fused engine).
-    ``ran_interpreted``: for Pallas backends, whether the kernel executes
-    in the Pallas interpreter (True on platforms without a compiled
-    lowering) — None for non-Pallas backends. Interpreter execution is
+    (the fused XLA sweep, when the table has no winner that can run on
+    this platform), or "grid-default" (the distributed route's fused
+    engine).
+    ``ran_interpreted``: whether the solve ran a Pallas kernel in the
+    Pallas interpreter (True only for a Pallas backend on a platform
+    without a compiled lowering). Interpreter execution is
     correctness-grade, never performance-grade.
     """
 
     backend: str
     source: str
-    ran_interpreted: bool | None = None
+    ran_interpreted: bool = False
     #: True when the solve was seeded from previous mates (warm-start
     #: rematching) instead of running greedy + MCM from scratch.
     warm_started: bool = False
@@ -531,31 +538,16 @@ def _execution_info(problem: MatchingProblem,
     the kernel wrappers' ``interpret=None`` auto-detection) so the record
     matches the dispatch decision made inside the solve."""
     if options.grid is not None:
-        return ExecutionInfo(
-            backend=options._dist_backend(),
-            source="explicit" if options.backend != "auto"
-            else "grid-default")
-    batch = problem.batch_size
-    if options.backend != "auto":
-        backend, source = options.backend, "explicit"
+        backend = options._dist_backend()
+        source = "explicit" if options.backend != "auto" else "grid-default"
     else:
-        try:
-            from repro.kernels.dispatch import choose_backend
-
-            winner = choose_backend(n=problem.n, batch=batch)
-        except ImportError:
-            winner = None
-        backend = winner if winner is not None else \
-            _single.resolve_backend("auto", n=problem.n, batch=batch)
-        source = "table" if winner is not None else "heuristic"
-    interpreted = None
+        backend, source = _single.resolve_backend_source(
+            options.backend, n=problem.n, batch=problem.batch_size)
+    interpreted = False
     if backend.startswith("pallas"):
-        try:
-            from repro.kernels.backend import resolve_execution
+        from repro.kernels.backend import resolve_execution
 
-            interpreted = resolve_execution(None).interpret
-        except ImportError:
-            pass
+        interpreted = resolve_execution(None).interpret
     return ExecutionInfo(backend=backend, source=source,
                          ran_interpreted=interpreted)
 

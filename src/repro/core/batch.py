@@ -70,7 +70,7 @@ def empty_mates(b: int, n: int):
 
 def matching_weight_batched(state: MatchState, n: int) -> jnp.ndarray:
     """Per-instance matching weight [B]."""
-    return state.u[:, :n].sum(axis=1)
+    return single.ordered_sum(state.u[:, :n])
 
 
 def is_perfect_batched(state: MatchState, n: int) -> jnp.ndarray:

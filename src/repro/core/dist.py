@@ -26,7 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.core import batch, single
@@ -43,25 +43,14 @@ from repro.sparse.ops import (
 )
 from repro.sparse.partition import partition_coo_2d, partition_coo_2d_batched
 
-try:  # jax >= 0.6 spelling
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-except AttributeError:  # jax 0.4.x: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    _shard_map = functools.partial(_shard_map_exp, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def make_mesh(shape, axes=("data", "model")):
-    """Version-proof ``jax.make_mesh`` — THE mesh builder to pair with
-    ``GridSpec`` / ``api.SolveOptions(grid=...)``: explicit Auto axis types
-    on jax >= 0.6 (the shard_map engines need Auto axes), plain make_mesh
-    on 0.4.x where every axis is Auto already."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types — THE mesh builder to pair with
+    ``GridSpec`` / ``api.SolveOptions(grid=...)`` (the shard_map engines
+    need Auto axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,8 +180,9 @@ def make_dist_awac(spec: GridSpec, n: int, cap: int, a2a_caps: tuple[int, int],
     completion lookup searches only inside row ``qi``'s short segment
     (``window_steps`` rounds ~ log2(max block-row degree), vs log2(cap) for
     the seed's global per-block lex search). "reference" keeps the seed
-    path. Both are bit-identical; callers wrap the run in ``enable_x64`` to
-    additionally collapse Step C's reductions into packed-key single passes.
+    path. Both are bit-identical; callers wrap the run in
+    ``jax.enable_x64(True)`` to additionally collapse Step C's reductions
+    into packed-key single passes.
     """
     pr, pc = spec.pr, spec.pc
     br = -(-n // pr)
@@ -535,7 +525,7 @@ class DistAWPM:
         awac = self._get_awac(ws if self.backend == "fused" else None)
         if self.backend == "fused":
             # Packed-key single-pass Step C reductions (repro.sparse.ops)
-            with enable_x64():
+            with jax.enable_x64(True):
                 return awac(brow, bcol, bval, state)
         return awac(brow, bcol, bval, state)
 
@@ -707,6 +697,7 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
         raise ValueError(
             f"backend {backend!r} routes through core.batch's local sweep "
             f"and needs the 1x1 grid, got {pr}x{pc}")
+    single.check_backend_available(backend)
     br = -(-n // pr)
     bc = -(-n // pc)
     cap1, cap2 = a2a_caps
@@ -987,7 +978,7 @@ class _DistBatchedAWPM:
             exchange_check=self.exchange_check)
         # x64 trace context: every winner reduction collapses to the
         # packed-key single pass (repro.sparse.ops), as in core.batch.
-        with enable_x64():
+        with jax.enable_x64(True):
             if state is not None:
                 return fn(brow, bcol, bval, *state)
             return fn(brow, bcol, bval)

@@ -36,6 +36,17 @@ def matching_weight(dense_val, mate_row):
     return float(dense_val[mate_row[m], j[m]].sum())
 
 
+def ordered_sum(x):
+    """float32 sum of a 1-D array in the fixed pairwise order of
+    ``single.ordered_sum``, which gives the engine's weight bit for bit."""
+    x = np.asarray(x, np.float32)
+    size = 1 << max(x.size - 1, 0).bit_length()
+    x = np.pad(x, (0, size - x.size))
+    while x.size > 1:
+        x = x[0::2] + x[1::2]
+    return x[0]
+
+
 def is_perfect(mate_row, n):
     return bool((np.asarray(mate_row[:n]) < n).all())
 

@@ -25,7 +25,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core._compat import warn_legacy
 from repro.core.constants import MIN_GAIN
@@ -72,8 +71,24 @@ def state_from_mates(row, col, val, n, mate_row, mate_col) -> MatchState:
     return MatchState(mate_row, mate_col, u, v)
 
 
+def ordered_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the last axis in one fixed pairwise order.
+
+    ``jnp.sum`` leaves the order of the additions to the compiler, and the
+    TPU's order differs from the CPU's, so the same f32 values can sum to
+    different last bits. Here each level adds adjacent pairs elementwise,
+    which every platform rounds alike: the weight is bit-identical across
+    platforms, batch shapes and device grids."""
+    m = x.shape[-1]
+    size = 1 << max(m - 1, 0).bit_length()
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, size - m)])
+    while x.shape[-1] > 1:
+        x = x[..., 0::2] + x[..., 1::2]
+    return x[..., 0]
+
+
 def matching_weight(state: MatchState, n: int) -> jnp.ndarray:
-    return state.u[:n].sum()
+    return ordered_sum(state.u[:n])
 
 
 def is_perfect(state: MatchState, n: int) -> jnp.ndarray:
@@ -405,40 +420,79 @@ def _cwinners(backend, row, col, val, row_ptr, n, state, min_gain,
     raise ValueError(f"unknown AWAC backend {backend!r}")
 
 
-def resolve_backend(backend: str, n: int | None = None,
-                    batch: int | None = None) -> str:
-    """Resolve ``"auto"`` to a concrete local AWAC backend.
+#: Local Pallas backends that the TPU compiler (Mosaic) refuses today, with
+#: the refusal it gives for v5e. On TPU, ``"auto"`` never resolves to them
+#: and an explicit request raises :class:`BackendUnavailableError` before
+#: anything is lowered.
+NO_TPU_LOWERING = {
+    "pallas": ("the awac_sweep kernel's 1-D gathers have no Mosaic lowering "
+               "(NotImplementedError: Only 2D gather is supported)"),
+    "pallas_persistent": ("the persistent kernel's tile loop has no Mosaic "
+                          "lowering (Unimplemented primitive in Pallas TPU "
+                          "lowering: dynamic_slice)"),
+}
 
-    Consults the measured dispatch table (``BENCH_dispatch.json``, written
-    by the kernels bench job — see ``repro.kernels.dispatch``) for the
-    winner on this platform and shape class. Only when no measurement
-    exists for the platform does the old structural heuristic apply
-    (compiled Pallas lowering on TPU, fused XLA elsewhere) — a guess, and
-    labeled as one in the dispatch module docs, never a claim.
+
+class BackendUnavailableError(ValueError):
+    """The requested AWAC backend cannot be compiled on this platform."""
+
+
+def backend_available(backend: str) -> bool:
+    """False when ``backend`` has no compiled lowering on the current
+    platform."""
+    return backend not in NO_TPU_LOWERING or jax.default_backend() != "tpu"
+
+
+def check_backend_available(backend: str) -> None:
+    """Raise :class:`BackendUnavailableError` naming the missing lowering
+    when ``backend`` cannot be compiled on the current platform."""
+    if not backend_available(backend):
+        raise BackendUnavailableError(
+            f"backend {backend!r} cannot run on {jax.default_backend()}: "
+            f"{NO_TPU_LOWERING[backend]}. Use backend='auto' or 'xla'.")
+
+
+def resolve_backend_source(backend: str, n: int | None = None,
+                           batch: int | None = None) -> tuple[str, str]:
+    """Resolve ``backend`` to (concrete local AWAC backend, source).
+
+    An explicit backend is checked against the platform and returned with
+    source "explicit". ``"auto"`` takes the measured dispatch table's winner
+    for this platform and shape class (``BENCH_dispatch.json``, see
+    ``repro.kernels.dispatch``; source "table") when that winner can run
+    here, and otherwise the fused XLA sweep (source "heuristic") — the one
+    route that compiles on every platform.
     """
     if backend != "auto":
-        return backend
+        check_backend_available(backend)
+        return backend, "explicit"
     try:
         from repro.kernels.dispatch import choose_backend
 
         winner = choose_backend(n=n, batch=batch)
     except ImportError:  # core stays usable without the kernel package
         winner = None
-    if winner is not None:
-        return winner
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if winner is not None and backend_available(winner):
+        return winner, "table"
+    return "xla", "heuristic"
+
+
+def resolve_backend(backend: str, n: int | None = None,
+                    batch: int | None = None) -> str:
+    """The backend half of :func:`resolve_backend_source`."""
+    return resolve_backend_source(backend, n=n, batch=batch)[0]
 
 
 def _x64_scope(row):
     """The packed-key single-pass reductions (repro.sparse.ops) need an
-    x64-enabled TRACE context — but entering ``enable_x64`` in the middle of
-    an outer trace promotes fresh loop carries to int64 while existing
+    x64-enabled TRACE context — but entering ``jax.enable_x64(True)`` in the
+    middle of an outer trace promotes fresh loop carries to int64 while existing
     values stay int32 (while_loop carry type mismatch). Inside an outer jit
     the scope is skipped and the two-pass fallback runs instead
     (bit-identical by the sparse.ops contract)."""
     if isinstance(row, jax.core.Tracer):
         return contextlib.nullcontext()
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def _resolve_window_steps(row, n, window_steps):

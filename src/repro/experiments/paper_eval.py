@@ -266,16 +266,23 @@ def _cases_from_spec(spec: dict) -> list[EvalCase]:
 
 def _eval_grid(cases: Sequence[EvalCase], spec: dict, grid: tuple[int, int],
                oracle_max_n: int, aux_by_name: dict) -> list[EvalRecord]:
-    """One grid's rows for every case. In-process when enough devices are
-    attached (reusing the sweep's per-case oracle/reference baselines),
-    else one subprocess with fake host devices (the
+    """One grid's rows for every case. In-process on the attached devices
+    when they can hold the grid (reusing the sweep's per-case
+    oracle/reference baselines). Only on the CPU does a larger grid fall
+    back to one subprocess with fake host devices (the
     ``--xla_force_host_platform_device_count`` must-precede-jax rule;
-    baselines are recomputed child-side)."""
+    baselines are recomputed child-side): this process may hold an
+    accelerator, and a child must never need it."""
     import jax
 
     pr, pc = grid
     if pr * pc <= jax.device_count():
         return _eval_grid_inproc(cases, grid, oracle_max_n, aux_by_name)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"grid {pr}x{pc} needs {pr * pc} devices; only "
+            f"{jax.device_count()} {jax.default_backend()} device(s) are "
+            f"attached")
     return _eval_grid_subproc(spec, grid, oracle_max_n, n_cases=len(cases))
 
 
@@ -330,6 +337,7 @@ def _eval_grid_subproc(spec, grid, oracle_max_n, n_cases):
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={pr * pc} {inherited}"
     ).strip()
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices, never a chip
     env.setdefault("PYTHONPATH", str(REPO_ROOT / "src"))
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, env=env,

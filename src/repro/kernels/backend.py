@@ -1,18 +1,19 @@
 """Kernel execution-mode selection shared by all Pallas kernel wrappers.
 
-``interpret=None`` everywhere means "auto": run the compiled kernel wherever
-a Pallas lowering exists for the current platform (Mosaic on TPU, Triton on
+``interpret=None`` everywhere means "auto": stage the kernel for the
+platform's Pallas compiler wherever one exists (Mosaic on TPU, Triton on
 GPU), and fall back to the Pallas interpreter only where none does (CPU CI,
 unit tests).
 
-The seed version of this module conflated "not TPU" with "run the
-interpreter", which silently labeled GPU runs — where a compiled lowering
-exists — as interpreter runs, and (worse) let interpreter timings land in
-BENCH_kernels.json indistinguishable from kernel timings. Every resolution
-now returns/records an :class:`ExecutionMode` carrying the explicit
-``interpret`` flag, and the kernel wrappers thread it into bench rows and
-``MatchResult.execution`` so an interpreter timing can never masquerade as a
-compiled-kernel timing again.
+A compiler existing does not mean a given kernel lowers. On TPU, Mosaic
+refuses both AWAC sweep kernels (``awac_sweep`` and the persistent loop);
+``core.single.NO_TPU_LOWERING`` records the refusals, and the solve path
+routes around them instead of staging them here.
+
+Every resolution returns/records an :class:`ExecutionMode` carrying the
+explicit ``interpret`` flag, and the kernel wrappers thread it into bench
+rows and ``MatchResult.execution`` so an interpreter timing can never
+masquerade as a compiled-kernel timing.
 """
 from __future__ import annotations
 
@@ -20,9 +21,8 @@ import dataclasses
 
 import jax
 
-#: Platforms with a compiled Pallas lowering in the pinned jax floor
-#: (0.4.37): Mosaic on TPU, Triton on CUDA/ROCm ("gpu" is the platform name
-#: older jax reports for both).
+#: Platforms that have a Pallas compiler: Mosaic on TPU, Triton on CUDA/ROCm
+#: ("gpu" is the platform name older jax reports for both).
 COMPILED_PLATFORMS = frozenset({"tpu", "gpu", "cuda", "rocm"})
 
 

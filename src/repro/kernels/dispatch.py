@@ -23,8 +23,9 @@ with the small/large split at ``n <= SMALL_N`` — because the bench job must
 be able to measure every class on every CI run. Lookup falls back
 class -> same-kind class -> any class for the platform -> None; a None
 answer means "unmeasured here", and the caller (``core.single.
-resolve_backend``) falls back to the platform heuristic, clearly labeled as
-such.
+resolve_backend``) falls back to the fused XLA sweep, labeled "heuristic".
+A winner that cannot compile on the platform (a Pallas sweep on TPU) is
+passed over the same way.
 
 ``check_regression.py --dispatch`` gates the committed table against fresh
 measurements so a stale winner (losing by more than the routing factor)

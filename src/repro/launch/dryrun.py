@@ -41,8 +41,6 @@ def _compile_metrics(cell, mesh):
         compiled = lowered.compile()
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per device
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     return compiled, ma, float(ca.get("flops", 0.0)), \

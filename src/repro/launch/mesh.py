@@ -5,18 +5,12 @@ FUNCTION, per the dry-run contract)."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6: meshes carry explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: every axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -18,11 +18,7 @@ import dataclasses
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.6
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: Mesh has no axis_types argument
-    AxisType = None
+from jax.sharding import AxisType
 
 
 @dataclasses.dataclass
@@ -62,8 +58,6 @@ def surviving_mesh(fleet: FleetState, axis_names=("data", "model")):
     kept = devs[rows_ok]
     if kept.shape[0] == 0:
         raise RuntimeError("no complete data-parallel row survived")
-    if AxisType is None:
-        return jax.sharding.Mesh(kept, axis_names)
     return jax.sharding.Mesh(
         kept, axis_names,
         axis_types=(AxisType.Auto,) * len(axis_names),

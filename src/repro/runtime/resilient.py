@@ -14,8 +14,9 @@ with the standard serving guards:
     (``TransientFault``, XLA runtime errors) on the same rung;
   - **backend degradation chain** — the requested engine first, then each
     strictly-more-conservative rung: a grid engine falls back to the local
-    engines, ``pallas -> xla -> reference``; the rung that finally served
-    the request is recorded, never hidden;
+    engines, ``pallas -> xla -> reference``, leaving out rungs the
+    platform cannot compile (no Pallas rung on TPU); the rung that finally
+    served the request is recorded, never hidden;
   - **device-loss recovery** — with a ``runtime.elastic.FleetState``, a
     dead device folds the grid down to ``surviving_mesh`` before the grid
     rung runs (and to the local chain when no full row survived);
@@ -122,8 +123,8 @@ class Attempt:
     """One execution attempt: which rung, what happened."""
 
     rung: str  # e.g. "grid 2x4 (fused)", "local xla"
-    outcome: str  # "ok" | "transient" | "integrity" | "verify_failed"
-    #               | "error"
+    outcome: str  # "ok" | "transient" | "integrity" | "unsupported"
+    #               | "verify_failed"
     detail: str = ""
     wall_s: float = 0.0
     retry: int = 0  # 0 = first try on this rung
@@ -262,7 +263,8 @@ def verify_result(problem: _api.MatchingProblem, result: _api.MatchResult,
 
 #: most-aggressive to most-conservative: the persistent whole-loop kernel
 #: degrades to the per-sweep kernel, then the fused XLA sweep, then the seed
-#: reference path
+#: reference path. Rungs the platform cannot compile are left out
+#: (``core.single.backend_available``).
 _LOCAL_CHAIN = ("pallas_persistent", "pallas", "xla", "reference")
 
 
@@ -302,20 +304,33 @@ def _build_rungs(options: _api.SolveOptions, fleet=None):
     if start not in _LOCAL_CHAIN:  # "fused" is grid-only: full local chain
         start = _LOCAL_CHAIN[0]
     for b in _LOCAL_CHAIN[_LOCAL_CHAIN.index(start):]:
-        rungs.append((f"local {b}", _local_options(options, b)))
+        if _single.backend_available(b):
+            rungs.append((f"local {b}", _local_options(options, b)))
     return rungs
+
+
+def _is_compiler_refusal(exc: BaseException) -> bool:
+    """A lowering or compiler that refuses the program: the same rung will
+    refuse it again, however often it is retried."""
+    if isinstance(exc, NotImplementedError):
+        return True
+    msg = str(exc)
+    return msg.startswith("UNIMPLEMENTED") or "Mosaic" in msg
 
 
 def _classify(exc: BaseException) -> str:
     """fatal: the request is wrong — propagate. integrity: this rung's
-    result can't be trusted — next rung, no retry. transient: same rung is
-    worth retrying."""
+    result can't be trusted — next rung, no retry. unsupported: the
+    compiler refused this rung — next rung, no retry, recorded.
+    transient: same rung is worth retrying."""
     if isinstance(exc, PreflightError):
         return "fatal"
     if isinstance(exc, (TypeError, ValueError)):
         return "fatal"
     if isinstance(exc, ExchangeIntegrityError):
         return "integrity"
+    if _is_compiler_refusal(exc):
+        return "unsupported"
     return "transient"  # TransientFault, XlaRuntimeError, other RuntimeErrors
 
 
@@ -353,11 +368,10 @@ def _serve(problem, rungs, requested_label, options, resilience, run_rung):
                 if kind == "fatal":
                     raise
                 attempts.append(Attempt(
-                    rung=label,
-                    outcome="integrity" if kind == "integrity" else
-                    "transient", detail=f"{type(e).__name__}: {e}",
+                    rung=label, outcome=kind,
+                    detail=f"{type(e).__name__}: {e}",
                     wall_s=time.monotonic() - t0, retry=retry))
-                if kind == "integrity" or retry >= resilience.max_retries:
+                if kind != "transient" or retry >= resilience.max_retries:
                     break  # next rung
                 delay = resilience.backoff_s * \
                     resilience.backoff_factor ** retry

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.loadgen import StreamSpec, run_stream
 from repro.serving.service import MatchingService, ServiceConfig
 
@@ -40,6 +41,7 @@ def main(argv=None) -> None:
                     help="serve through runtime.resilient rung chains")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     service = MatchingService(ServiceConfig(
         num_shards=args.shards, deadline_s=args.deadline_ms / 1e3,
         max_batch=args.batch, warm_start=not args.no_warm,

@@ -173,7 +173,8 @@ def solve_linear_system(a, b, *, pivoting: str = "awpm",
     # map back: A x = b  <=>  (P Dr A Dc) y = P Dr b,  x = Dc y
     sb = pivot.scale_rhs(b)
     refinement = refine(scaled, factor, sb,
-                        tol=max(tol * 1e-2, 1e-14), max_iter=max_iter)
+                        tol=max(tol * 1e-2, 1e-14), max_iter=max_iter,
+                        row_weight=1.0 / pivot.dr[pivot.row_perm])
     y = refinement.x
     x = pivot.unscale_solution(y)
 

@@ -159,7 +159,8 @@ def _csr_matvec(a: CsrMatrix, x: np.ndarray) -> np.ndarray:
 def refine(a: CsrMatrix, factor: LUFactorization, b: np.ndarray, *,
            tol: float = 1e-12, max_iter: int = 40,
            stall_window: int = 3, stall_factor: float = 0.5,
-           divergence_factor: float = 1e4) -> RefineResult:
+           divergence_factor: float = 1e4,
+           row_weight: np.ndarray | None = None) -> RefineResult:
     """Iteratively refine ``A x = b`` through the (possibly perturbed,
     possibly garbage) factors of ``a``.
 
@@ -171,6 +172,12 @@ def refine(a: CsrMatrix, factor: LUFactorization, b: np.ndarray, *,
     ``stall_window`` consecutive sweeps), or ``max_iter``. Frozen lanes
     stop updating — their x is exactly what it was at freeze time — while
     live lanes continue, so one bad RHS never poisons its batch.
+
+    ``row_weight`` ([n], positive) measures every residual as
+    ``||w * r||_2 / ||w * b||_2``. A caller refining a row-scaled system
+    ``(D A) x = D b`` passes ``w = 1 / D`` so that convergence is judged
+    in the frame of the ORIGINAL system: a residual that is tiny after
+    scaling can still be large before it.
     """
     b = np.asarray(b)
     single = b.ndim == 1
@@ -182,7 +189,8 @@ def refine(a: CsrMatrix, factor: LUFactorization, b: np.ndarray, *,
         raise ValueError(f"rhs width {n} != matrix order {a.n}")
 
     l_strict, u_strict, u_diag = _dense_factors(factor)
-    bnorm = np.linalg.norm(bb, axis=-1)
+    w = np.ones(n) if row_weight is None else np.asarray(row_weight, np.float64)
+    bnorm = np.linalg.norm(w * bb, axis=-1)
     bnorm = np.where(bnorm == 0.0, 1.0, bnorm)
 
     x = np.zeros((B, n), acc)
@@ -196,7 +204,7 @@ def refine(a: CsrMatrix, factor: LUFactorization, b: np.ndarray, *,
 
     for _ in range(max_iter + 1):
         r = bb - _csr_matvec(a, x)
-        rel = np.linalg.norm(r, axis=-1) / bnorm
+        rel = np.linalg.norm(w * r, axis=-1) / bnorm
         # frozen lanes keep their freeze-time residual on the record
         if history:
             rel = np.where(live, rel, history[-1])

@@ -6,7 +6,7 @@ dropped by passing ``num_segments + 1`` internally and slicing the tail off, or
 by masking values to the reduction identity.
 
 Packed-key fast path (fused AWAC sweep engine, DESIGN.md §3): when 64-bit
-types are available at trace time (``jax.experimental.enable_x64`` entered
+types are available at trace time (``jax.enable_x64(True)`` entered
 around the jitted call), the two-reduction argmax-with-tie-break ops below
 collapse into a single ``segment_max`` over a packed uint64 key
 ``f32-key-bits ⧺ bitwise-not(payload)``, halving the number of O(m) scatter
@@ -27,7 +27,7 @@ _SIGN32 = np.int32(np.uint32(0x80000000))
 
 def x64_available() -> bool:
     """True when 64-bit dtypes survive canonicalization in the current trace
-    context (i.e. we are under ``jax.experimental.enable_x64``)."""
+    context (i.e. we are under ``jax.enable_x64(True)``)."""
     return jax.dtypes.canonicalize_dtype(np.uint64).itemsize == 8
 
 

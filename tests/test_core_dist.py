@@ -11,11 +11,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import graph, ref, single
 from repro.core.dist import GridSpec, DistAWPM, default_caps
 
-try:  # jax >= 0.6: explicit Auto axis types
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh({mesh_shape}, {mesh_axes}, axis_types=(AxisType.Auto,)*{nax})
-except ImportError:  # jax 0.4.x: all axes are Auto already
-    mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+from jax.sharding import AxisType
+mesh = jax.make_mesh({mesh_shape}, {mesh_axes}, axis_types=(AxisType.Auto,)*{nax})
 spec = GridSpec(mesh, {row_axes}, "model")
 for seed in range(3):
     g = graph.generate(64, avg_degree=6.0, kind="{kind}", seed=seed)
@@ -58,11 +55,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import graph, ref, single
 from repro.core.dist import GridSpec, DistAWPM
 
-try:
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((4, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
-except ImportError:
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 spec = GridSpec(mesh, ("data",), "model")
 g = graph.generate(64, avg_degree=8.0, kind="uniform", seed=5)
 struct = g.structure_dense()

@@ -118,3 +118,15 @@ def test_awac_weight_monotone_nondecreasing():
     w0 = float(single.matching_weight(st, g.n))
     stJ, _ = single.awac(row, col, val, g.n, st)
     assert float(single.matching_weight(stJ, g.n)) >= w0 - 1e-5
+
+
+@pytest.mark.parametrize("m", [0, 1, 2, 7, 64, 1000])
+def test_ordered_sum_is_one_fixed_order(m):
+    """The engine's weight sum is the numpy oracle's bit for bit, and a
+    batch row sums as the lone vector does."""
+    x = np.random.default_rng(m).random(m, dtype=np.float32) * 100
+    one = np.asarray(single.ordered_sum(jnp.asarray(x)))
+    rows = np.asarray(single.ordered_sum(jnp.asarray(np.stack([x, x[::-1]]))))
+    assert one.tobytes() == ref.ordered_sum(x).tobytes()
+    assert rows[0].tobytes() == one.tobytes()
+    assert rows[1].tobytes() == ref.ordered_sum(x[::-1]).tobytes()

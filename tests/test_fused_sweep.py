@@ -6,10 +6,10 @@ Covers the three Step-C backends on padded COO instances:
   * pallas     — fused ``awac_sweep`` kernel (interpret mode on CPU)
 including gain ties, the all-padding instance, and the no-candidate case.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import graph, single
 from repro.kernels.cycle_gain.ops import awac_sweep_winners
@@ -31,7 +31,7 @@ def _winners_all_backends(row, col, val, n, st, min_gain=1e-6):
     ws = window_depth(max_row_nnz(row, n))
     ref = single.awac_cwinners(row, col, val, n, st, min_gain)
     xla = single.awac_cwinners_fused(row, col, val, rp, n, st, min_gain, ws)
-    with enable_x64():  # packed-key one-pass reduction branch
+    with jax.enable_x64(True):  # packed-key one-pass reduction branch
         xla64 = single.awac_cwinners_fused(row, col, val, rp, n, st,
                                            min_gain, ws)
     pal = awac_sweep_winners(row, col, val, rp, st.mate_row, st.mate_col,
@@ -125,7 +125,7 @@ def test_packed_segment_max_matches_two_pass():
     pay = jnp.asarray(rng.integers(0, 1 << 20, m), jnp.int32)
     seg = jnp.asarray(rng.integers(0, ns + 1, m), jnp.int32)  # incl. dump seg
     g1, p1 = segment_max_with_payload(vals, pay, seg, ns + 1)
-    with enable_x64():
+    with jax.enable_x64(True):
         g2, p2 = segment_max_with_payload(vals, pay, seg, ns + 1)
     np.testing.assert_array_equal(np.array(g1), np.array(g2))
     np.testing.assert_array_equal(np.array(p1), np.array(p2))

@@ -346,32 +346,49 @@ def test_identity_pivoting_is_noop():
 # --------------------------------------------------------------------------
 
 
+def _dominant_system(n, seed, offsets, cyclic):
+    """Row-dominant system with rows scaled over 2^-20..2^20: diagonal d_i,
+    off-diagonals at columns ``i + k`` for k in ``offsets`` (mod n when
+    ``cyclic``, else dropped outside 0..n-1), each at most 0.22 d_i. The
+    right-hand side is ``A @ x_true``, so every entry of b carries its
+    row's scale: with a b drawn independently of the rows, no float64 x
+    can meet a 1e-10 relative residual (the floor,
+    eps * ||A|| ||x|| / ||b||, exceeds it)."""
+    rng = np.random.default_rng(seed)
+    row, col, val = [], [], []
+    for i in range(n):
+        d = float(np.exp2(rng.integers(-20, 20))) * (1.0 + rng.random())
+        row.append(i)
+        col.append(i)
+        val.append(d)
+        for k in offsets:
+            j = (i + k) % n if cyclic else i + k
+            if j != i and 0 <= j < n:
+                row.append(i)
+                col.append(j)
+                val.append(0.2 * d * (0.1 + rng.random()))
+    row, col, val = np.array(row), np.array(col), np.array(val)
+    b = dense_of(row, col, val, n) @ rng.standard_normal(n)
+    return row, col, val, b
+
+
 def test_property_random_dominant_systems_converge():
-    """Hypothesis sweep: on random row-dominant systems with wildly
-    scaled rows, AWPM static pivoting always converges to the dense
+    """Hypothesis sweep: on random tridiagonal row-dominant systems with
+    wildly scaled rows, AWPM static pivoting always converges to the dense
     reference (skipped where hypothesis is not installed — the CI solver
-    job runs it)."""
+    job runs it). Tridiagonal, because every other perfect matching of that
+    pattern differs from the diagonal by disjoint adjacent swaps, each an
+    augmenting 4-cycle: AWAC then reaches the maximum-product matching and
+    the scaled diagonal is exactly 1. On patterns with longer cycles AWPM
+    can stop on a matching that no 4-cycle improves, and static pivoting
+    may then fail: that is the approximation, not a solver fault."""
     hypothesis = pytest.importorskip("hypothesis")
     st = hypothesis.strategies
 
     @hypothesis.settings(max_examples=20, deadline=None)
     @hypothesis.given(st.integers(3, 14), st.integers(0, 10_000))
     def check(n, seed):
-        rng = np.random.default_rng(seed)
-        row, col, val = [], [], []
-        for i in range(n):
-            d = float(np.exp2(rng.integers(-20, 20))) * (1.0 + rng.random())
-            row.append(i)
-            col.append(i)
-            val.append(d)
-            for j in ((i + 1) % n, (i + 5) % n):
-                if j != i:
-                    row.append(i)
-                    col.append(j)
-                    val.append(0.2 * d * (0.1 + rng.random()))
-        row, col = np.array(row), np.array(col)
-        val = np.array(val)
-        b = rng.standard_normal(n)
+        row, col, val, b = _dominant_system(n, seed, (-1, 1), cyclic=False)
         rep = solve_linear_system((row, col, val, n), b)
         assert rep.ok, rep.summary()
         dense = dense_of(row, col, val, n)
@@ -380,6 +397,15 @@ def test_property_random_dominant_systems_converge():
         assert err <= 100 * np.linalg.cond(dense) * 1e-10
 
     check()
+
+
+def test_refinement_converges_in_the_original_frame():
+    """Refinement runs on the scaled system but must stop on the ORIGINAL
+    system's residual: on this instance the scaled residual reaches 5e-15
+    while the original one is still 1.3e-8 (row scalings span 6e9)."""
+    row, col, val, b = _dominant_system(9, 1, (1, 5), cyclic=True)
+    rep = solve_linear_system((row, col, val, 9), b)
+    assert rep.ok, rep.summary()
 
 
 def test_solver_export_surface():
