@@ -25,6 +25,15 @@ def _mode_note(backend: str) -> str:
     return ";" + resolve_execution(None).describe()
 
 
+def _backends() -> tuple[str, ...]:
+    """The measured AWAC backends the current platform can compile (on a
+    TPU, neither Pallas sweep has a Mosaic lowering)."""
+    from repro.core.single import backend_available
+
+    return tuple(b for b in kdispatch.MEASURED_BACKENDS
+                 if backend_available(b))
+
+
 def _measure_awac_single(n: int, avg_degree: float, seed: int = 0,
                          emit_rows: bool = False):
     """Per-iteration AWAC time for every local backend on one synthetic
@@ -38,7 +47,7 @@ def _measure_awac_single(n: int, avg_degree: float, seed: int = 0,
     st = single.mcm(r, c, v, g.n, st.mate_row, st.mate_col)
 
     us, interp, weights = {}, {}, {}
-    for backend in kdispatch.MEASURED_BACKENDS:
+    for backend in _backends():
         dt, (stf, iters) = time_call(
             lambda b=backend: single.awac(r, c, v, g.n, st, backend=b),
             iters=3, warmup=1,
@@ -71,11 +80,12 @@ def bench_awac_sweep(n: int = 2048, avg_degree: float = 8.0):
         f"speedup_vs_reference={speedup:.2f}x;"
         f"weight_identical="
         f"{abs(weights['reference'] - weights['xla']) == 0.0}")
-    row(f"awac_persistent_speedup_n{n}", us["pallas_persistent"],
-        f"speedup_vs_pallas_sweep={us['pallas'] / us['pallas_persistent']:.2f}x;"
-        f"weight_identical="
-        f"{abs(weights['reference'] - weights['pallas_persistent']) == 0.0}"
-        + _mode_note("pallas_persistent"))
+    if "pallas_persistent" in us and "pallas" in us:
+        same = weights["reference"] == weights["pallas_persistent"]
+        row(f"awac_persistent_speedup_n{n}", us["pallas_persistent"],
+            f"speedup_vs_pallas_sweep="
+            f"{us['pallas'] / us['pallas_persistent']:.2f}x;"
+            f"weight_identical={same}" + _mode_note("pallas_persistent"))
     return speedup, us, interp
 
 
@@ -96,7 +106,7 @@ def _measure_awac_batched(n: int, bsize: int, avg_degree: float = 6.0):
     st = batch._state_from_mates_windowed(r, c, v, rp, n, mr, mc, ws)
 
     us, interp = {}, {}
-    for backend in kdispatch.MEASURED_BACKENDS:
+    for backend in _backends():
         dt, (stf, iters) = time_call(
             lambda b=backend: batch.awac_batched(
                 r, c, v, n, st, backend=b, row_ptr=rp, window_steps=ws),
@@ -141,7 +151,7 @@ def bench_dispatch(single_large=None):
         "date": datetime.datetime.now(datetime.timezone.utc).isoformat(),
         "jax": jax.__version__,
         "backend": platform,
-        "measured_backends": list(kdispatch.MEASURED_BACKENDS),
+        "measured_backends": list(_backends()),
     })
     return entries
 
@@ -205,7 +215,8 @@ def run():
         f"arith_intensity={ai:.2f}flop/B;v5e_bound=memory")
     dt_k, _ = time_call(
         lambda: cycle_gain_padded(a, a2, u, v, tm=256, tn=256), iters=2)
-    row("cycle_gain_pallas_interp_512", dt_k * 1e6, "interpret-mode")
+    row("cycle_gain_pallas_interp_512", dt_k * 1e6,
+        resolve_execution(None).describe())
 
     # flash attention S=512 D=64
     q = jnp.asarray(rng.normal(size=(1, 4, 512, 64)), jnp.bfloat16)
@@ -216,7 +227,8 @@ def run():
     row("attention_ref_512", dt_ref * 1e6,
         f"flops={flops:.2e};v5e_us={flops / 197e12 * 1e6:.2f}")
     dt_k, _ = time_call(lambda: flash_attention(q, k, vv), iters=1)
-    row("flash_attention_interp_512", dt_k * 1e6, "interpret-mode")
+    row("flash_attention_interp_512", dt_k * 1e6,
+        resolve_execution(None).describe())
 
     # router_swap: T=512 tokens, E=64 experts
     from repro.kernels.router_swap import router_swap_padded, router_swap_ref
@@ -229,7 +241,7 @@ def run():
     dt_k, _ = time_call(
         lambda: router_swap_padded(aff, assign, cur, ti=256, tj=256), iters=2)
     row("router_swap_pallas_interp_512", dt_k * 1e6,
-        "tiled, no [T,T] in HBM")
+        "tiled, no [T,T] in HBM;" + resolve_execution(None).describe())
 
     # embedding bag B=64 L=16 V=4096 D=64
     idx = jnp.asarray(rng.integers(-1, 4096, (64, 16)), jnp.int32)
@@ -239,7 +251,8 @@ def run():
     row("embedding_bag_ref", dt_ref * 1e6, "take+segsum")
     dt_k, _ = time_call(
         lambda: embedding_bag_padded(idx, w, tbl, tb=8, tv=512), iters=2)
-    row("embedding_bag_pallas_interp", dt_k * 1e6, "interpret-mode")
+    row("embedding_bag_pallas_interp", dt_k * 1e6,
+        resolve_execution(None).describe())
     return True
 
 

@@ -13,7 +13,10 @@ Phases, each printing its own line:
                 call with its inputs on the host CPU device, and the AWAC
                 phase bit-identical to the numpy oracle
                 (``core.ref.awac_parallel_rule``) from the engine's own MCM
-                matching.
+                matching. The end-to-end oracle ``core.ref.awpm_reference``
+                is printed, not held: its sequential greedy and Kuhn MCM
+                start AWAC from another perfect matching than the engine's
+                proposal rounds and layered BFS, so it ends elsewhere.
   (c) main      one instance at the size of ``configs/awpm_paper.py``
                 (n = 4,194,304, average degree 16) through
                 ``solve(..., SolveOptions(backend="auto"))``, checked by the
@@ -195,12 +198,13 @@ def phase_oracle(n: int = 512, degree: float = 8.0, seed: int = 0,
         mr_full, _, _ = ref.awpm_reference(dense, struct)
         w_full = ref.matching_weight(dense, mr_full)
         same = np.array_equal(mates, mr_full)
-        say("b oracle", f"{kind}: awpm_reference end to end: mates "
-                        f"{'equal' if same else 'differ'}, weight "
+        say("b oracle", f"{kind}: awpm_reference end to end (not held): "
+                        f"mates {'equal' if same else 'differ'}, weight "
                         f"{w_full!r} against {float(res.weight)!r}")
         say("b oracle", f"{kind} n={n} nnz={g.nnz} ok: mates, weight "
                         f"{float(res.weight)!r} and {int(res.awac_iters)} "
-                        f"AWAC rounds equal to the CPU run and the oracle")
+                        f"AWAC rounds equal to the CPU run and to the AWAC-"
+                        f"phase oracle awac_parallel_rule")
 
 
 # --------------------------------------------------------------------------

@@ -56,12 +56,13 @@ def main() -> None:
                          "reference/xla, 1x1 grid")
     ap.add_argument("--backends", default=None,
                     help="comma list from reference,xla,pallas "
-                         "(default: all three; --quick: reference,xla)")
+                         "(default: those the platform compiles, all three "
+                         "on the CPU; --quick: reference,xla)")
     ap.add_argument("--grids", default=None,
                     help="comma list of PRxPC grids (default: 1x1,2x2; "
                          "--quick: 1x1). On the CPU, grids beyond the "
                          "attached device count run in a fake-device "
-                         "subprocess.")
+                         "subprocess; on an accelerator they are skipped.")
     ap.add_argument("--suite-count", type=int, default=None,
                     help="number of synthetic suite matrices (default 10)")
     ap.add_argument("--suite-n", type=int, default=None,
@@ -110,7 +111,7 @@ def main() -> None:
               f"{suitesparse.cache_dir(args.cache_dir)}")
     backends = (args.backends.split(",") if args.backends
                 else (["reference", "xla"] if args.quick
-                      else list(paper_eval.LOCAL_BACKENDS)))
+                      else list(paper_eval.default_backends())))
     grids = _parse_grids(args.grids) if args.grids \
         else ([(1, 1)] if args.quick else list(paper_eval.GRIDS))
 

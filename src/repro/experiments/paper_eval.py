@@ -8,9 +8,10 @@ module is that experiment's harness:
     loaded through ``repro.data.mtx`` with a per-fixture weight transform)
     plus instances of the synthetic ``core.graph.matrix_suite``;
   - sweep: every case through the ``solve()``/``Matcher`` facade across
-    local backends (reference / xla / pallas) and device grids (1x1 in
-    process; larger grids in a subprocess with fake host devices, the
-    tests/_subproc.py constraint);
+    local backends (reference / xla / pallas, those the platform compiles)
+    and device grids (in process where the attached devices hold them; on
+    the CPU, larger grids in a subprocess with fake host devices, the
+    tests/_subproc.py constraint; on an accelerator, skipped);
   - evidence per (case, engine): matching weight, AWAC iterations, wall
     time, the LP-dual certified ratio bound (``core.dual``), the exact
     ratio when the ``ref.exact_mwpm`` oracle is tractable, and
@@ -58,6 +59,14 @@ FIXTURE_TRANSFORMS = {
 # plan()-once path with backend "auto")
 LOCAL_BACKENDS = ("reference", "xla", "pallas")
 GRIDS = ((1, 1), (2, 2))
+
+
+def default_backends() -> tuple[str, ...]:
+    """The :data:`LOCAL_BACKENDS` the current platform can compile (on a
+    TPU, the Pallas sweep has no Mosaic lowering)."""
+    from repro.core.single import backend_available
+
+    return tuple(b for b in LOCAL_BACKENDS if backend_available(b))
 
 _ROW_MARK = "PAPER_EVAL_ROW "  # subprocess -> parent protocol
 
@@ -272,17 +281,17 @@ def _eval_grid(cases: Sequence[EvalCase], spec: dict, grid: tuple[int, int],
     back to one subprocess with fake host devices (the
     ``--xla_force_host_platform_device_count`` must-precede-jax rule;
     baselines are recomputed child-side): this process may hold an
-    accelerator, and a child must never need it."""
+    accelerator, and a child must never need it, so on an accelerator the
+    grid is skipped."""
     import jax
 
     pr, pc = grid
     if pr * pc <= jax.device_count():
         return _eval_grid_inproc(cases, grid, oracle_max_n, aux_by_name)
     if jax.default_backend() != "cpu":
-        raise RuntimeError(
-            f"grid {pr}x{pc} needs {pr * pc} devices; only "
-            f"{jax.device_count()} {jax.default_backend()} device(s) are "
-            f"attached")
+        print(f"# skip grid {pr}x{pc}: only {jax.device_count()} "
+              f"{jax.default_backend()} device(s) attached", flush=True)
+        return []
     return _eval_grid_subproc(spec, grid, oracle_max_n, n_cases=len(cases))
 
 
@@ -362,13 +371,15 @@ QUICK_SPEC = {"fixtures": True, "synthetic_count": 3, "synthetic_n": 48}
 
 
 def run_eval(spec: dict | None = None,
-             backends: Sequence[str] = LOCAL_BACKENDS,
+             backends: Sequence[str] | None = None,
              grids: Sequence[tuple[int, int]] = GRIDS,
              oracle_max_n: int = 256) -> list[EvalRecord]:
     """The full sweep: every case in ``spec`` (see :func:`_cases_from_spec`;
-    default :data:`DEFAULT_SPEC`) x (local ``backends`` + device ``grids``).
-    Raises on any soundness / bit-identity / perfection violation (see
-    module docstring)."""
+    default :data:`DEFAULT_SPEC`) x (local ``backends``, default
+    :func:`default_backends`, + device ``grids``). Raises on any soundness
+    / bit-identity / perfection violation (see module docstring)."""
+    if backends is None:
+        backends = default_backends()
     spec = dict(DEFAULT_SPEC if spec is None else spec)
     cases = _cases_from_spec(spec)
     aux_by_name = {c.name: _case_aux(c, oracle_max_n) for c in cases}

@@ -311,11 +311,9 @@ def _build_rungs(options: _api.SolveOptions, fleet=None):
 
 def _is_compiler_refusal(exc: BaseException) -> bool:
     """A lowering or compiler that refuses the program: the same rung will
-    refuse it again, however often it is retried."""
-    if isinstance(exc, NotImplementedError):
-        return True
-    msg = str(exc)
-    return msg.startswith("UNIMPLEMENTED") or "Mosaic" in msg
+    refuse it again, however often it is retried. Mosaic raises both of
+    its known refusals of the sweep kernels as ``NotImplementedError``."""
+    return isinstance(exc, NotImplementedError)
 
 
 def _classify(exc: BaseException) -> str:

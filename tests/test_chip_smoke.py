@@ -45,8 +45,8 @@ def test_oracle_phase(kind, capsys):
     chip_smoke.phase_oracle(n=48, degree=6.0, kinds=(kind,))
     out = capsys.readouterr().out
     assert "ran_interpreted=False" in out
-    assert f"{kind} n=48" in out and "equal to the CPU run and the oracle" \
-        in out
+    assert f"{kind} n=48" in out and "equal to the CPU run and to the " \
+        "AWAC-phase oracle awac_parallel_rule" in out
 
 
 def test_main_phase(capsys):
@@ -184,8 +184,9 @@ def test_resilient_chain_unchanged_on_cpu():
 def test_compiler_refusal_is_not_transient():
     assert _classify(NotImplementedError("Only 2D gather is supported")) \
         == "unsupported"
-    assert _classify(RuntimeError("UNIMPLEMENTED: no lowering")) \
-        == "unsupported"
+    # classified on the exception's type, never on its text
+    assert _classify(RuntimeError("Mosaic: device preempted")) \
+        == "transient"
     assert _classify(RuntimeError("device preempted")) == "transient"
     problem = graph.generate(16, avg_degree=4.0, seed=0)
     with chaos.failing_backend("pallas_persistent", "pallas",
@@ -197,3 +198,23 @@ def test_compiler_refusal_is_not_transient():
         ["unsupported", "unsupported", "ok"]
     assert rr.report.backend_used == "local xla" and rr.report.degraded
     assert bool(np.asarray(rr.result.perfect))
+
+
+def test_harness_defaults_skip_what_a_tpu_cannot_run(on_tpu, capsys):
+    from benchmarks import bench_kernels
+    from repro.experiments import paper_eval
+
+    assert paper_eval.default_backends() == ("reference", "xla")
+    assert bench_kernels._backends() == ("reference", "xla")
+    # a grid beyond the attached devices is skipped, never a child process
+    grid = (jax.device_count() + 1, 1)
+    assert paper_eval._eval_grid([], {}, grid, 0, {}) == []
+    assert f"skip grid {grid[0]}x1" in capsys.readouterr().out
+
+
+def test_harness_defaults_unchanged_on_cpu():
+    from benchmarks import bench_kernels
+    from repro.experiments import paper_eval
+
+    assert paper_eval.default_backends() == paper_eval.LOCAL_BACKENDS
+    assert bench_kernels._backends() == kdispatch.MEASURED_BACKENDS

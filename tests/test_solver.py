@@ -372,29 +372,56 @@ def _dominant_system(n, seed, offsets, cyclic):
     return row, col, val, b
 
 
+#: off-diagonal pattern of each swept family: (offsets, cyclic)
+_DOMINANT_FAMILIES = {
+    "tridiagonal": ((-1, 1), False),
+    "cyclic_1_5": ((1, 5), True),
+}
+
+
 def test_property_random_dominant_systems_converge():
-    """Hypothesis sweep: on random tridiagonal row-dominant systems with
-    wildly scaled rows, AWPM static pivoting always converges to the dense
-    reference (skipped where hypothesis is not installed — the CI solver
-    job runs it). Tridiagonal, because every other perfect matching of that
-    pattern differs from the diagonal by disjoint adjacent swaps, each an
-    augmenting 4-cycle: AWAC then reaches the maximum-product matching and
-    the scaled diagonal is exactly 1. On patterns with longer cycles AWPM
-    can stop on a matching that no 4-cycle improves, and static pivoting
-    may then fail: that is the approximation, not a solver fault."""
+    """Hypothesis sweep over random row-dominant systems with wildly scaled
+    rows (skipped where hypothesis is not installed — the CI solver job
+    runs it). A converged report always carries the dense reference's x.
+
+    Tridiagonal: every other perfect matching of the pattern differs from
+    the diagonal by disjoint adjacent swaps, each an augmenting 4-cycle, so
+    AWAC reaches the maximum-product matching and AWPM static pivoting
+    always converges.
+
+    Cyclic (i+1, i+5): the pattern has longer cycles, and AWPM can stop on
+    a matching that no 4-cycle improves; static LU then meets pivots near
+    1e-4 and refinement can stall above the bar (26 of the 3,600 systems
+    with n in 3..14 and seed in 0..299, all at n >= 11, among them (13, 3)
+    and (12, 51), the examples pinned below). That failure must
+    show as ``report.ok == False``, never as a wrong x, and must be the
+    approximation's: AWPM's matching is lighter than the optimum, its dual
+    certificate is not tight, and exact-matching pivoting converges."""
     hypothesis = pytest.importorskip("hypothesis")
     st = hypothesis.strategies
 
-    @hypothesis.settings(max_examples=20, deadline=None)
-    @hypothesis.given(st.integers(3, 14), st.integers(0, 10_000))
-    def check(n, seed):
-        row, col, val, b = _dominant_system(n, seed, (-1, 1), cyclic=False)
+    @hypothesis.settings(max_examples=40, deadline=None)
+    @hypothesis.given(st.sampled_from(sorted(_DOMINANT_FAMILIES)),
+                      st.integers(3, 14), st.integers(0, 10_000))
+    @hypothesis.example("cyclic_1_5", 13, 3)
+    @hypothesis.example("cyclic_1_5", 12, 51)
+    def check(family, n, seed):
+        offsets, cyclic = _DOMINANT_FAMILIES[family]
+        row, col, val, b = _dominant_system(n, seed, offsets, cyclic)
         rep = solve_linear_system((row, col, val, n), b)
-        assert rep.ok, rep.summary()
-        dense = dense_of(row, col, val, n)
-        x_ref = np.linalg.solve(dense, b)
-        err = np.linalg.norm(rep.x - x_ref) / np.linalg.norm(x_ref)
-        assert err <= 100 * np.linalg.cond(dense) * 1e-10
+        if family == "tridiagonal":
+            assert rep.ok, rep.summary()
+        if rep.ok:
+            dense = dense_of(row, col, val, n)
+            x_ref = np.linalg.solve(dense, b)
+            err = np.linalg.norm(rep.x - x_ref) / np.linalg.norm(x_ref)
+            assert err <= 100 * np.linalg.cond(dense) * 1e-10
+        else:
+            exact = solve_linear_system((row, col, val, n), b,
+                                        pivoting="reference")
+            assert exact.ok, exact.summary()
+            assert rep.matching_weight < exact.matching_weight
+            assert rep.matching_tight is False
 
     check()
 
