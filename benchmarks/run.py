@@ -40,7 +40,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names (approx_ratio, scaling, "
-                         "breakdown, pivot, moe_router, kernels, serving)")
+                         "pivot, moe_router, kernels, serving)")
     ap.add_argument("--full", action="store_true",
                     help="larger problem sizes (slower)")
     ap.add_argument("--no-persist", action="store_true",
@@ -51,8 +51,8 @@ def main() -> None:
 
     enable_compile_cache()
     from benchmarks import (
-        bench_approx_ratio, bench_breakdown, bench_kernels, bench_moe_router,
-        bench_pivot, bench_scaling, bench_serving,
+        bench_approx_ratio, bench_kernels, bench_moe_router, bench_pivot,
+        bench_scaling, bench_serving,
     )
     from benchmarks._util import drain_rows
 
@@ -60,7 +60,6 @@ def main() -> None:
         "approx_ratio": lambda: bench_approx_ratio.run(
             n_matrices=100 if args.full else 50, n=120 if args.full else 96),
         "scaling": bench_scaling.run,
-        "breakdown": bench_breakdown.run,
         "pivot": bench_pivot.run,
         "moe_router": bench_moe_router.run,
         "kernels": bench_kernels.run,

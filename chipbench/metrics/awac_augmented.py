@@ -1,0 +1,16 @@
+"""4-cycles augmented per solve over all AWAC rounds (core/single.py
+``_awac_rounds``): the ``awac_augmented`` counter, counted on the device. Program counter, read from
+``repro.core.telemetry``'s records of the traced solves; None from a program
+that keeps none."""
+
+
+def read(ctx):
+    try:
+        from repro.core import telemetry
+    except ImportError:
+        return None
+    records = telemetry.recent(len(ctx.solves))
+    counts = [r["counters"].get("awac_augmented") for r in records]
+    if not ctx.solves or len(counts) < len(ctx.solves) or None in counts:
+        return None
+    return sum(counts) / len(counts)

@@ -43,6 +43,7 @@ performance decision.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Sequence
@@ -55,6 +56,7 @@ from repro.core import batch as _batch
 from repro.core import graph as _graph
 from repro.core import preflight as _preflight
 from repro.core import single as _single
+from repro.core import telemetry
 from repro.core.constants import MIN_GAIN
 from repro.core.single import BackendUnavailableError, MatchState
 from repro.sparse.csr import window_depth
@@ -452,12 +454,13 @@ class MatchResult:
 
 
 def _result(state: MatchState, iters, n: int, batched: bool) -> MatchResult:
-    if batched:
-        weight = _batch.matching_weight_batched(state, n)
-        perfect = _batch.is_perfect_batched(state, n)
-    else:
-        weight = _single.matching_weight(state, n)
-        perfect = _single.is_perfect(state, n)
+    with telemetry.span("repro.result"):
+        if batched:
+            weight = _batch.matching_weight_batched(state, n)
+            perfect = _batch.is_perfect_batched(state, n)
+        else:
+            weight = _single.matching_weight(state, n)
+            perfect = _single.is_perfect(state, n)
     return MatchResult(mate_row=state.mate_row, mate_col=state.mate_col,
                        weight=weight, awac_iters=iters, perfect=perfect)
 
@@ -482,6 +485,14 @@ def _is_traced(problem: MatchingProblem) -> bool:
                for x in (problem.row, problem.col, problem.val))
 
 
+def _record(problem: MatchingProblem):
+    """The solve's telemetry record, with its root span ``repro.solve``;
+    none for a problem traced under an outer jit."""
+    if _is_traced(problem):
+        return contextlib.nullcontext()
+    return telemetry.record()
+
+
 def _apply_preflight(problem: MatchingProblem, options: SolveOptions):
     """Host-side input screening per ``options.on_invalid``. Returns the
     (possibly sanitized) problem and the report to carry into
@@ -490,7 +501,8 @@ def _apply_preflight(problem: MatchingProblem, options: SolveOptions):
     protects infeasible instances from burning the round budget)."""
     if _is_traced(problem):
         return problem, None
-    report = _preflight.preflight(problem)
+    with telemetry.span("repro.preflight"):
+        report = _preflight.preflight(problem)
     if report.fatal:
         if options.on_invalid == "raise":
             raise _preflight.PreflightError(
@@ -498,7 +510,8 @@ def _apply_preflight(problem: MatchingProblem, options: SolveOptions):
                 f"preflight rejected the problem: {report.summary()}. Pass "
                 f"SolveOptions(on_invalid='sanitize') to repair, or "
                 f"'degrade' to also accept infeasible instances.")
-        problem, report = _preflight.sanitize(problem)
+        with telemetry.span("repro.preflight"):
+            problem, report = _preflight.sanitize(problem)
     if report.structural and options.on_invalid == "raise":
         # empty rows/columns make a perfect matching impossible — under the
         # strict policy that is an error, and it is known before solving
@@ -516,7 +529,9 @@ def _finish(problem: MatchingProblem, result: MatchResult,
     degraded matching with the deficiency folded into the diagnosis."""
     if isinstance(result.perfect, jax.core.Tracer):
         return result
-    if bool(np.asarray(result.perfect).all()):
+    with telemetry.span("repro.finish"):
+        perfect = bool(np.asarray(result.perfect).all())
+    if perfect:
         if report is not None and report.issues:
             return dataclasses.replace(result, diagnosis=report)
         return result
@@ -603,6 +618,13 @@ def solve(problem: MatchingProblem,
     bit-identically."""
     options = SolveOptions() if options is None else options
     _check_types(problem, options)
+    with _record(problem):
+        return _solve(problem, options, warm_start)
+
+
+def _solve(problem: MatchingProblem, options: SolveOptions,
+           warm_start) -> MatchResult:
+    """:func:`solve` inside its telemetry record."""
     warm = None if warm_start is None else _warm_mates(problem, warm_start)
     problem, report = _apply_preflight(problem, options)
     if options.grid is not None:
@@ -677,11 +699,13 @@ def _solve_dist(problem: MatchingProblem, options: SolveOptions,
         wmr, wmc = warm
         if not batched:
             wmr, wmc = jnp.asarray(wmr)[None], jnp.asarray(wmc)[None]
-        jrow, jcol, jval = jnp.asarray(row), jnp.asarray(col), \
-            jnp.asarray(val)
-        ws = _batch._resolve_window_steps_batched(
-            jrow, problem.n, options.window_steps)
-        row_ptr = batched_row_ptr_from_sorted(jrow, problem.n)
+        jrow, jcol, jval = telemetry.call("repro.device_put", jax.device_put,
+                                          (row, col, val))
+        with telemetry.span("repro.window_depth"):
+            ws = _batch._resolve_window_steps_batched(
+                jrow, problem.n, options.window_steps)
+        row_ptr = telemetry.call("repro.row_ptr", batched_row_ptr_from_sorted,
+                                 jrow, problem.n)
         wmr, wmc = _batch._normalize_mates_batched(
             wmr, wmc, row.shape[0], problem.n)
         wmr, wmc = _batch.warm_mates_batched(
@@ -826,15 +850,17 @@ class Matcher:
         if self._driver is not None:
             warm = None if warm_start is None \
                 else _warm_mates(problem, warm_start)
-            problem, report = _apply_preflight(problem, opts)
             try:
-                result = _solve_dist(problem, opts, driver=self._driver,
-                                     warm=warm)
-                if result.execution is not None:
-                    result = dataclasses.replace(
-                        result, execution=dataclasses.replace(
-                            result.execution, warm_started=warm is not None))
-                return _finish(problem, result, opts, report)
+                with _record(problem):
+                    problem, report = _apply_preflight(problem, opts)
+                    result = _solve_dist(problem, opts, driver=self._driver,
+                                         warm=warm)
+                    if result.execution is not None:
+                        result = dataclasses.replace(
+                            result, execution=dataclasses.replace(
+                                result.execution,
+                                warm_started=warm is not None))
+                    return _finish(problem, result, opts, report)
             except ValueError as e:
                 if "refusing to truncate" not in str(e):
                     raise

@@ -27,13 +27,14 @@ Backends mirror ``core.single.awac``:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import single
+from repro.core import single, telemetry
 from repro.core._compat import warn_legacy
 from repro.core.single import MIN_GAIN, NEG, MatchState
 from repro.sparse.csr import batched_row_ptr_from_sorted
@@ -163,21 +164,28 @@ def greedy_loop(n: int, b: int, propose_fn):
     per-instance convergence masks. ``propose_fn(mate_row, mate_col) ->
     (pv, prow)`` supplies each round's per-column proposals — the full edge
     list here, blocks + collectives in the distributed engine. Returns
-    (mate_row, mate_col), each [B, n + 1]."""
+    (mate_row, mate_col, counters): mates [B, n + 1], and per instance the
+    rounds it ran and the pairs it matched (``telemetry``)."""
 
     def round_body(carry):
-        mate_row, mate_col, active = carry
-        pv, prow = propose_fn(mate_row, mate_col)
-        return greedy_commit(pv, prow, n, mate_row, mate_col, active)
+        mate_row, mate_col, active, rounds = carry
+        with jax.named_scope("greedy_round"):
+            pv, prow = propose_fn(mate_row, mate_col)
+            mate_row, mate_col, still = greedy_commit(pv, prow, n, mate_row,
+                                                      mate_col, active)
+        return mate_row, mate_col, still, rounds + active.astype(jnp.int32)
 
     def cond(carry):
         return carry[2].any()
 
     mr0, mc0 = empty_mates(b, n)
-    mate_row, mate_col, _ = jax.lax.while_loop(
-        cond, round_body, (mr0, mc0, jnp.ones((b,), bool))
+    mate_row, mate_col, _, rounds = jax.lax.while_loop(
+        cond, round_body, (mr0, mc0, jnp.ones((b,), bool),
+                           jnp.zeros((b,), jnp.int32))
     )
-    return mate_row, mate_col
+    matched = (mate_col[:, :n] < n).sum(axis=1, dtype=jnp.int32)
+    return mate_row, mate_col, {"greedy_rounds": rounds,
+                                "greedy_matched": matched}
 
 
 def greedy_maximal_batched(row, col, val, n: int):
@@ -185,14 +193,17 @@ def greedy_maximal_batched(row, col, val, n: int):
     while_loop: each round is ``single.greedy_round`` re-expressed on the
     flat offset-segment reductions, and instances whose round proposes
     nothing go inactive (their mates freeze). Returns (mate_row, mate_col),
-    each [B, n + 1].
+    each [B, n + 1], its counters added to the solve in progress.
 
     Traced under x64 so both per-round reductions run as single packed-key
     passes (bit-identical to the two-pass reference — sparse.ops); under an
     outer jit the scope is a no-op and the two-pass fallback runs (see
     ``single._x64_scope``)."""
     with single._x64_scope(row):
-        return _greedy_maximal_batched(row, col, val, n)
+        mate_row, mate_col, counters = telemetry.call(
+            "repro.greedy", _greedy_maximal_batched, row, col, val, n)
+    telemetry.count(counters)
+    return mate_row, mate_col
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -275,11 +286,12 @@ def mcm_bfs_loop(n: int, b: int, mate_row, mate_col, parents_fn):
         _, _, _, found, layers, progressed = carry
         return act_of(found, layers, progressed).any()
 
-    frontier, parent_col, visited, found, layers, _ = jax.lax.while_loop(
-        bfs_cond, bfs_body,
-        (frontier0, parent_col0, visited0, jnp.zeros((b,), bool),
-         jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool)),
-    )
+    with jax.named_scope("mcm_bfs_layer"):
+        frontier, parent_col, visited, found, layers, _ = jax.lax.while_loop(
+            bfs_cond, bfs_body,
+            (frontier0, parent_col0, visited0, jnp.zeros((b,), bool),
+             jnp.zeros((b,), jnp.int32), jnp.ones((b,), bool)),
+        )
     return parent_col, visited, found, layers
 
 
@@ -292,6 +304,7 @@ def _mcm_bfs_batched(row, col, val, n: int, mate_row, mate_col):
         functools.partial(bfs_parents_full, row, col, val, n))
 
 
+@jax.named_scope("mcm_trace_flip")
 def trace_and_flip_batched(parent_col, visited, found, layers, mate_row,
                            mate_col, n: int):
     """Batched ``single.trace_and_flip``: lockstep backtrace with per-column
@@ -349,23 +362,28 @@ def trace_and_flip_batched(parent_col, visited, found, layers, mate_row,
 def mcm_batched(row, col, val, n: int, mate_row, mate_col):
     """Batched MCM: one masked phase loop over the flat-batched
     BFS + trace/flip bodies (``single.mcm_phase`` re-expressed on the
-    offset-segment primitives). Returns (mate_row, mate_col).
+    offset-segment primitives). Returns (mate_row, mate_col), its counters
+    added to the solve in progress.
 
     Traced under x64 so each BFS layer's winner reduction runs as a single
     packed-key pass (bit-identical to the two-pass reference); no-op under
     an outer jit (see ``single._x64_scope``)."""
     with single._x64_scope(row):
-        return _mcm_batched(row, col, val, n, mate_row, mate_col)
+        mate_row, mate_col, counters = telemetry.call(
+            "repro.mcm", _mcm_batched, row, col, val, n, mate_row, mate_col)
+    telemetry.count(counters)
+    return mate_row, mate_col
 
 
 def mcm_loop(n: int, b: int, mate_row, mate_col, parents_fn):
     """Masked MCM phase loop over the batched BFS + trace/flip bodies,
     parameterized by the per-layer parent selection (``parents_fn``, see
     ``mcm_bfs_loop``) so the distributed-batched engine shares every mask
-    and commit verbatim. Returns (mate_row, mate_col)."""
+    and commit verbatim. Returns (mate_row, mate_col, counters): per
+    instance the BFS layers of all its phases (``telemetry``)."""
 
     def body(carry):
-        mr, mc, active = carry
+        mr, mc, active, total = carry
         parent_col, visited, found, layers = mcm_bfs_loop(
             n, b, mr, mc, parents_fn)
         # frozen instances trace nothing: zero their layer counts + found
@@ -377,16 +395,16 @@ def mcm_loop(n: int, b: int, mate_row, mate_col, parents_fn):
         mr = jnp.where(keep, mr2, mr)
         mc = jnp.where(keep, mc2, mc)
         active = active & found & (mr[:, :n] == n).any(axis=1)
-        return mr, mc, active
+        return mr, mc, active, total + layers
 
     def cond(carry):
         return carry[2].any()
 
     active0 = (mate_row[:, :n] == n).any(axis=1)
-    mate_row, mate_col, _ = jax.lax.while_loop(
-        cond, body, (mate_row, mate_col, active0)
+    mate_row, mate_col, _, layers = jax.lax.while_loop(
+        cond, body, (mate_row, mate_col, active0, jnp.zeros((b,), jnp.int32))
     )
-    return mate_row, mate_col
+    return mate_row, mate_col, {"mcm_bfs_layers": layers}
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -479,7 +497,9 @@ def awac_loop(n: int, state: MatchState, max_iter: int, min_gain,
     ``active0`` ([B] bool) masks instances out of the loop from round 0
     (the infeasible-instance short-circuit: an imperfect matching can never
     become perfect through 4-cycle rotations). ``aux0`` overrides the aux
-    accumulator's initial value/shape. Returns (state, iters [B], aux)."""
+    accumulator's initial value/shape. Returns (state, iters [B], aux,
+    counters): per instance the 4-cycles its rounds augmented
+    (``telemetry``)."""
     b = state.mate_row.shape[0]
     select = jax.vmap(
         lambda Cg, Ci, Cw1, Cw2, mr, mc, u, v: single.select_and_augment(
@@ -487,9 +507,11 @@ def awac_loop(n: int, state: MatchState, max_iter: int, min_gain,
     )
 
     def body(carry):
-        state, iters, active, aux = carry
-        Cgain, Ci, Cw1, Cw2, a = cwinners_fn(state)
+        state, iters, active, aux, augmented = carry
+        with jax.named_scope("awac_sweep"):
+            Cgain, Ci, Cw1, Cw2, a = cwinners_fn(state)
         new_state, n_surv = select(Cgain, Ci, Cw1, Cw2, *state)
+        augmented = augmented + jnp.where(active, n_surv, 0).astype(jnp.int32)
         keep = active[:, None]
         state = MatchState(
             *(jnp.where(keep, ns, s) for ns, s in zip(new_state, state)))
@@ -497,7 +519,7 @@ def awac_loop(n: int, state: MatchState, max_iter: int, min_gain,
         active = active & (n_surv > 0) & (iters < max_iter)
         if _CONVERGENCE_TAP is not None:
             active = _CONVERGENCE_TAP(active, iters)
-        return state, iters, active, aux + a
+        return state, iters, active, aux + a, augmented
 
     def cond(carry):
         return carry[2].any()
@@ -506,12 +528,13 @@ def awac_loop(n: int, state: MatchState, max_iter: int, min_gain,
     go0 = jnp.full((b,), max_iter > 0)
     if active0 is not None:
         go0 = go0 & active0
-    state, iters, _, aux = jax.lax.while_loop(
+    state, iters, _, aux, augmented = jax.lax.while_loop(
         cond, body,
         (state, jnp.zeros((b,), jnp.int32), go0,
-         jnp.array(0, jnp.int32) if aux0 is None else aux0),
+         jnp.array(0, jnp.int32) if aux0 is None else aux0,
+         jnp.zeros((b,), jnp.int32)),
     )
-    return state, iters, aux
+    return state, iters, aux, {"awac_augmented": augmented}
 
 
 @functools.partial(
@@ -527,9 +550,9 @@ def _awac_loop_batched(row, col, val, row_ptr, n: int, state: MatchState,
         return (*out, jnp.array(0, jnp.int32))
 
     active0 = is_perfect_batched(state, n) if degrade_infeasible else None
-    state, iters, _ = awac_loop(n, state, max_iter, min_gain, cwinners,
-                                active0=active0)
-    return state, iters
+    state, iters, _, counters = awac_loop(n, state, max_iter, min_gain,
+                                          cwinners, active0=active0)
+    return state, iters, counters
 
 
 def _resolve_window_steps_batched(row, n, window_steps):
@@ -544,14 +567,17 @@ def awac_batched(row, col, val, n: int, state: MatchState,
                  backend: str = "auto", row_ptr=None,
                  window_steps: int | None = None,
                  degrade_infeasible: bool = False):
-    """Batched AWAC loop over [B, cap] instances. Returns (state, iters [B]).
+    """Batched AWAC loop over [B, cap] instances. Returns (state, iters [B]),
+    its counters added to the solve in progress.
 
     Same backend contract as ``single.awac``; every instance's result and
     iteration count are bit-identical to its own single-instance run."""
     backend = single.resolve_backend(backend, n=n, batch=row.shape[0])
-    window_steps = _resolve_window_steps_batched(row, n, window_steps)
+    with telemetry.span("repro.window_depth"):
+        window_steps = _resolve_window_steps_batched(row, n, window_steps)
     if row_ptr is None:
-        row_ptr = batched_row_ptr_from_sorted(row, n)
+        row_ptr = telemetry.call("repro.row_ptr", batched_row_ptr_from_sorted,
+                                 row, n)
     if backend == "pallas_persistent":
         # Local import: core must stay importable without the kernel package.
         from repro.kernels.cycle_gain.ops import awac_persistent_loop_batched
@@ -559,22 +585,24 @@ def awac_batched(row, col, val, n: int, state: MatchState,
         b = row.shape[0]
         go0 = is_perfect_batched(state, n) if degrade_infeasible \
             else jnp.ones((b,), bool)
-        mr, mc, u, v, iters = awac_persistent_loop_batched(
+        mr, mc, u, v, iters = telemetry.call(
+            "repro.awac", awac_persistent_loop_batched,
             row, col, val, row_ptr, state.mate_row, state.mate_col, state.u,
             state.v, min_gain, go0, n=n, window_steps=window_steps,
             max_iter=max_iter)
+        telemetry.count({"awac_augmented": None})
         return MatchState(mr, mc, u, v), iters
-    if backend == "xla":
-        # Same x64 trace context as single.awac: Step C runs as one
-        # packed-key uint64 segment_max over the whole batch (no-op under
-        # an outer jit, see single._x64_scope).
-        with single._x64_scope(row):
-            return _awac_loop_batched(row, col, val, row_ptr, n, state,
-                                      max_iter, min_gain, backend,
-                                      window_steps, degrade_infeasible)
-    return _awac_loop_batched(row, col, val, row_ptr, n, state, max_iter,
-                              min_gain, backend, window_steps,
-                              degrade_infeasible)
+    # Same x64 trace context as single.awac: with "xla", Step C runs as one
+    # packed-key uint64 segment_max over the whole batch (no-op under an
+    # outer jit, see single._x64_scope).
+    with single._x64_scope(row) if backend == "xla" \
+            else contextlib.nullcontext():
+        state, iters, counters = telemetry.call(
+            "repro.awac", _awac_loop_batched, row, col, val, row_ptr, n,
+            state, max_iter, min_gain, backend, window_steps,
+            degrade_infeasible)
+    telemetry.count(counters)
+    return state, iters
 
 
 # --------------------------------------------------------------------------
@@ -641,6 +669,7 @@ def warm_mates_batched(row, col, val, row_ptr, n: int, mate_row, mate_col,
     either matches a free row or stops) — an intact seed runs ZERO phases,
     which is where warm-start rematching earns its keep on mostly-stable
     streams. Returns (mate_row, mate_col)."""
+    telemetry.copied(row, col, val, row_ptr, mate_row, mate_col)
     mate_row, mate_col = repair_mates_batched(
         row, col, val, row_ptr, n, mate_row, mate_col, window_steps)
     return mcm_batched(row, col, val, n, mate_row, mate_col)
@@ -659,13 +688,16 @@ def _awpm_batched_from_state(row, col, val, n: int, mate_row, mate_col,
     previous result of an unchanged problem), repair keeps every pair, the
     top-up runs zero phases, and AWAC converges on its first round —
     returning the seed matching (mates, duals, weight) bit-identically."""
-    window_steps = _resolve_window_steps_batched(row, n, window_steps)
+    with telemetry.span("repro.window_depth"):
+        window_steps = _resolve_window_steps_batched(row, n, window_steps)
     if row_ptr is None:
-        row_ptr = batched_row_ptr_from_sorted(row, n)
+        row_ptr = telemetry.call("repro.row_ptr", batched_row_ptr_from_sorted,
+                                 row, n)
     mate_row, mate_col = _normalize_mates_batched(
         mate_row, mate_col, row.shape[0], n)
     mate_row, mate_col = warm_mates_batched(
         row, col, val, row_ptr, n, mate_row, mate_col, window_steps)
+    telemetry.copied(row, col, val, row_ptr, mate_row, mate_col)
     state = _state_from_mates_windowed(row, col, val, row_ptr, n, mate_row,
                                        mate_col, window_steps)
     return awac_batched(row, col, val, n, state, max_iter=max_iter,
@@ -697,11 +729,14 @@ def _awpm_batched(row, col, val, n: int, max_iter: int = 1000,
 
     Internal engine behind ``repro.core.api.solve`` (the batched dispatch
     target) and the deprecated ``awpm_batched`` shim."""
-    window_steps = _resolve_window_steps_batched(row, n, window_steps)
+    with telemetry.span("repro.window_depth"):
+        window_steps = _resolve_window_steps_batched(row, n, window_steps)
     if row_ptr is None:
-        row_ptr = batched_row_ptr_from_sorted(row, n)
+        row_ptr = telemetry.call("repro.row_ptr", batched_row_ptr_from_sorted,
+                                 row, n)
     mate_row, mate_col = greedy_maximal_batched(row, col, val, n)
     mate_row, mate_col = mcm_batched(row, col, val, n, mate_row, mate_col)
+    telemetry.copied(row, col, val, row_ptr, mate_row, mate_col)
     state = _state_from_mates_windowed(row, col, val, row_ptr, n, mate_row,
                                        mate_col, window_steps)
     return awac_batched(row, col, val, n, state, max_iter=max_iter,

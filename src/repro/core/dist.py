@@ -29,7 +29,7 @@ import numpy as np
 from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro.core import batch, single
+from repro.core import batch, single, telemetry
 from repro.core._compat import warn_legacy
 from repro.core.single import MIN_GAIN, NEG, MatchState
 from repro.sparse.csr import max_row_nnz, window_depth
@@ -90,6 +90,7 @@ def _search_depth(cap: int) -> int:
     return window_depth(cap)
 
 
+@jax.named_scope("a2a_exchange")
 def a2a_bucketed(arrays, fills, dest, valid, n_peers: int, cap_out: int,
                  axis_name, packed: bool = False):
     """Fixed-capacity bucketed all_to_all (the MPI_Alltoallv replacement).
@@ -581,6 +582,7 @@ def _conserved(arrays, valid):
     return cnt, chk
 
 
+@jax.named_scope("a2a_exchange")
 def a2a_bucketed_batched(arrays, fills, dest, valid, n_peers: int,
                          cap_out: int, axis_name, packed: bool = False):
     """Batched ``a2a_bucketed``: arrays/dest/valid are [B, L] and ONE
@@ -684,7 +686,8 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
     needed.
 
     Returns jitted ``run(brow, bcol, bval) -> (MatchState, iters [B],
-    dropped)`` over [Pr, Pc, B, cap] blocks. With ``from_state=True`` the
+    dropped, counters)`` over [Pr, Pc, B, cap] blocks, the counters those
+    of the batched skeletons (``telemetry``). With ``from_state=True`` the
     runner instead takes a replicated initial MatchState ([B, n + 1]
     fields) and runs the AWAC phase only — ``run(brow, bcol, bval,
     mate_row, mate_col, u, v)`` — the distributed analogue of
@@ -884,13 +887,15 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
                 return (*out, zero)
 
         # ---- the pipeline: shared batched loop skeletons, dist winners ----
+        counters = {}
         if from_state:
             state0 = MatchState(*state_args)
         else:
-            mr, mc = batch.greedy_loop(n, b, greedy_propose)
-            mr, mc = batch.mcm_loop(n, b, mr, mc, mcm_parents)
+            mr, mc, greedy_counts = batch.greedy_loop(n, b, greedy_propose)
+            mr, mc, mcm_counts = batch.mcm_loop(n, b, mr, mc, mcm_parents)
+            counters = {**greedy_counts, **mcm_counts}
             state0 = uv_state(mr, mc)
-        state, iters, aux = batch.awac_loop(
+        state, iters, aux, awac_counts = batch.awac_loop(
             n, state0, max_iter, min_gain, cwinners,
             active0=(batch.is_perfect_batched(state0, n)
                      if degrade_infeasible else None),
@@ -900,20 +905,21 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
             # inside cwinners; the plain dropped counter is not
             aux = jax.lax.psum(aux, all_axes)
         return (state.mate_row, state.mate_col, state.u, state.v, iters,
-                aux)
+                aux, {**counters, **awac_counts})
 
     blk = spec.block_spec_batched()
     state_specs = (P(), P(), P(), P()) if from_state else ()
     fn = _shard_map(
         block_fn, mesh=spec.mesh,
         in_specs=(blk, blk, blk) + state_specs,
-        out_specs=(P(), P(), P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), P(), P(), P()),
     )
 
     @jax.jit
     def run(brow, bcol, bval, *state_args):
-        mr, mc, u, v, iters, dropped = fn(brow, bcol, bval, *state_args)
-        return MatchState(mr, mc, u, v), iters, dropped
+        mr, mc, u, v, iters, dropped, counters = fn(brow, bcol, bval,
+                                                    *state_args)
+        return MatchState(mr, mc, u, v), iters, dropped, counters
 
     return run
 
@@ -942,23 +948,28 @@ class _DistBatchedAWPM:
     def partition(self, row, col, val):
         """[B, cap] padded COO -> device-sharded [Pr, Pc, B, cap_blk] blocks
         (plus the partition and the measured windowed-search depth)."""
-        part = partition_coo_2d_batched(
-            row, col, val, self.n, self.spec.pr, self.spec.pc, cap=self.cap)
+        with telemetry.span("repro.partition"):
+            part = partition_coo_2d_batched(
+                row, col, val, self.n, self.spec.pr, self.spec.pc,
+                cap=self.cap)
         sharding = jax.sharding.NamedSharding(
             self.spec.mesh, self.spec.block_spec_batched())
-        brow = jax.device_put(part.row, sharding)
-        bcol = jax.device_put(part.col, sharding)
-        bval = jax.device_put(part.val, sharding)
-        ws = window_depth(max_row_nnz(part.row.reshape(-1, part.cap), self.n))
+        brow, bcol, bval = telemetry.call(
+            "repro.device_put", jax.device_put, (part.row, part.col, part.val),
+            sharding)
+        with telemetry.span("repro.window_depth"):
+            ws = window_depth(max_row_nnz(part.row.reshape(-1, part.cap),
+                                          self.n))
         return part, brow, bcol, bval, ws
 
     def run(self, row, col, val, state: MatchState | None = None):
         """row/col/val: padded [B, cap] lex-sorted COO sharing n (see
         ``core.batch.stack_graphs``). Returns (MatchState with [B, n + 1]
         fields, awac_iters [B], dropped) — per instance bit-identical to
-        ``core.batch.awpm_batched(row, col, val, n)``. An explicit
-        replicated ``state`` skips greedy/MCM and runs the AWAC phase only
-        (the distributed ``core.batch.awac_batched``)."""
+        ``core.batch.awpm_batched(row, col, val, n)``; the engine's counters
+        are added to the solve in progress. An explicit replicated
+        ``state`` skips greedy/MCM and runs the AWAC phase only (the
+        distributed ``core.batch.awac_batched``)."""
         part, brow, bcol, bval, ws = self.partition(row, col, val)
         caps = self.a2a_caps or safe_a2a_caps(
             part.cap, self.spec.pr, self.spec.pc)
@@ -979,9 +990,10 @@ class _DistBatchedAWPM:
         # x64 trace context: every winner reduction collapses to the
         # packed-key single pass (repro.sparse.ops), as in core.batch.
         with jax.enable_x64(True):
-            if state is not None:
-                return fn(brow, bcol, bval, *state)
-            return fn(brow, bcol, bval)
+            state, iters, dropped, counters = fn(
+                brow, bcol, bval, *(() if state is None else state))
+        telemetry.count(counters)
+        return state, iters, dropped
 
 
 @dataclasses.dataclass
@@ -1004,9 +1016,14 @@ def make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
     ``repro.core.api.plan`` (the ``Matcher`` handle pins capacities and the
     compiled engine at plan time)."""
     warn_legacy("repro.core.dist.make_awpm_dist_batched", "plan()")
-    return _make_awpm_dist_batched(
+    engine = _make_awpm_dist_batched(
         spec, n, b, cap, a2a_caps, max_iter, min_gain, packed=packed,
         backend=backend, window_steps=window_steps, from_state=from_state)
+
+    def run(*args):
+        return engine(*args)[:3]
+
+    return run
 
 
 def _awpm_dist_batched(row, col, val, n: int, spec, *,

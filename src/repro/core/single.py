@@ -26,6 +26,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import telemetry
 from repro.core._compat import warn_legacy
 from repro.core.constants import MIN_GAIN
 from repro.sparse.csr import max_row_nnz, row_ptr_from_sorted, window_depth
@@ -95,11 +96,26 @@ def is_perfect(state: MatchState, n: int) -> jnp.ndarray:
     return (state.mate_row[:n] < n).all()
 
 
+def _jit_named(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` compiled as the module ``jit_<name>``. Each counted
+    engine core below returns its counters beside the phase's result and
+    compiles under the public phase's name, the name a device trace finds
+    the phase by; the public function is a plain wrapper over it."""
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **jit_kwargs)
+
+
 # --------------------------------------------------------------------------
 # Phase 1: greedy weighted maximal matching (proposal rounds)
 # --------------------------------------------------------------------------
 
 
+@jax.named_scope("greedy_round")
 def greedy_round(row, col, val, n: int, mate_row, mate_col):
     """One proposal round of the greedy weighted maximal matching. The
     batched engine (core/batch.py) re-expresses this body on flat
@@ -127,20 +143,36 @@ def greedy_round(row, col, val, n: int, mate_row, mate_col):
     return mate_row, mate_col, ok.any()
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def greedy_maximal(row, col, val, n: int) -> MatchState:
+def _greedy_rounds(row, col, val, n: int):
+    """Proposal rounds until one matches nothing. Returns (MatchState,
+    counters): the rounds run and the pairs matched (``telemetry``)."""
+
     def round_body(carry):
-        mate_row, mate_col, _ = carry
-        return greedy_round(row, col, val, n, mate_row, mate_col)
+        mate_row, mate_col, _, rounds = carry
+        return (*greedy_round(row, col, val, n, mate_row, mate_col),
+                rounds + 1)
 
     def cond(carry):
         return carry[2]
 
     st0 = empty_state(n)
-    mate_row, mate_col, _ = jax.lax.while_loop(
-        cond, round_body, (st0.mate_row, st0.mate_col, jnp.array(True))
+    mate_row, mate_col, _, rounds = jax.lax.while_loop(
+        cond, round_body, (st0.mate_row, st0.mate_col, jnp.array(True),
+                           jnp.array(0, jnp.int32))
     )
-    return state_from_mates(row, col, val, n, mate_row, mate_col)
+    # pairs counted on the rows' side: a count of ``mate_row`` makes the TPU
+    # compiler place the loop's mate arrays differently (a slower round)
+    counters = {"greedy_rounds": rounds,
+                "greedy_matched": (mate_col[:n] < n).sum(dtype=jnp.int32)}
+    return state_from_mates(row, col, val, n, mate_row, mate_col), counters
+
+
+_greedy_counted = _jit_named("greedy_maximal", _greedy_rounds,
+                             static_argnames=("n",))
+
+
+def greedy_maximal(row, col, val, n: int) -> MatchState:
+    return _greedy_counted(row, col, val, n)[0]
 
 
 # --------------------------------------------------------------------------
@@ -148,6 +180,7 @@ def greedy_maximal(row, col, val, n: int) -> MatchState:
 # --------------------------------------------------------------------------
 
 
+@jax.named_scope("mcm_trace_flip")
 def trace_and_flip(parent_col, visited, found, layers, mate_row, mate_col, n):
     """Lockstep backtrace with per-column claims (winner = smallest endpoint
     row id), then flip the surviving vertex-disjoint augmenting paths.
@@ -226,12 +259,13 @@ def _mcm_bfs(row, col, val, n: int, mate_row, mate_col):
         _, _, _, found, layers, progressed = carry
         return (~found) & progressed & (layers <= n)
 
-    frontier, parent_col, visited, found, layers, _ = jax.lax.while_loop(
-        bfs_cond,
-        bfs_body,
-        (frontier, parent_col, visited, jnp.array(False), jnp.array(0, jnp.int32),
-         jnp.array(True)),
-    )
+    with jax.named_scope("mcm_bfs_layer"):
+        frontier, parent_col, visited, found, layers, _ = jax.lax.while_loop(
+            bfs_cond,
+            bfs_body,
+            (frontier, parent_col, visited, jnp.array(False),
+             jnp.array(0, jnp.int32), jnp.array(True)),
+        )
     return parent_col, visited, found, layers
 
 
@@ -241,26 +275,28 @@ def mcm_phase(row, col, val, n: int, mate_row, mate_col):
     offset-segment primitives (``batch._mcm_bfs_batched`` /
     ``batch.trace_and_flip_batched``) — changes here must be mirrored there
     to keep per-instance bit-exactness. Returns (mate_row, mate_col,
-    found)."""
+    found, layers), ``layers`` the BFS's."""
     parent_col, visited, found, layers = _mcm_bfs(row, col, val, n, mate_row,
                                                  mate_col)
     mate_row, mate_col = trace_and_flip(
         parent_col, visited, found, layers, mate_row, mate_col, n
     )
-    return mate_row, mate_col, found
+    return mate_row, mate_col, found, layers
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def mcm(row, col, val, n: int, mate_row, mate_col) -> MatchState:
-    """Maximum cardinality matching from an initial matching, with the paper's
-    weight-aware tie-breaking (heaviest eligible edge chosen as BFS parent)."""
+def _mcm_phases(row, col, val, n: int, mate_row, mate_col):
+    """MCM phases until one finds no augmenting path or every row is
+    matched. Returns (MatchState, counters): the BFS layers of all phases
+    (``telemetry``)."""
 
     def phase_body(carry):
-        mate_row, mate_col, _ = carry
-        return mcm_phase(row, col, val, n, mate_row, mate_col)
+        mate_row, mate_col, _, layers = carry
+        mate_row, mate_col, found, phase_layers = mcm_phase(
+            row, col, val, n, mate_row, mate_col)
+        return mate_row, mate_col, found, layers + phase_layers
 
     def phase_cond(carry):
-        mate_row, _, go = carry
+        mate_row, _, go, _ = carry
         return go & (mate_row[:n] == n).any()
 
     if mate_row.shape[0] == n:
@@ -268,10 +304,21 @@ def mcm(row, col, val, n: int, mate_row, mate_col) -> MatchState:
                                     jnp.array([n], jnp.int32)])
         mate_col = jnp.concatenate([jnp.asarray(mate_col, jnp.int32),
                                     jnp.array([n], jnp.int32)])
-    mate_row, mate_col, _ = jax.lax.while_loop(
-        phase_cond, phase_body, (mate_row, mate_col, jnp.array(True))
+    mate_row, mate_col, _, layers = jax.lax.while_loop(
+        phase_cond, phase_body, (mate_row, mate_col, jnp.array(True),
+                                 jnp.array(0, jnp.int32))
     )
-    return state_from_mates(row, col, val, n, mate_row, mate_col)
+    return (state_from_mates(row, col, val, n, mate_row, mate_col),
+            {"mcm_bfs_layers": layers})
+
+
+_mcm_counted = _jit_named("mcm", _mcm_phases, static_argnames=("n",))
+
+
+def mcm(row, col, val, n: int, mate_row, mate_col) -> MatchState:
+    """Maximum cardinality matching from an initial matching, with the paper's
+    weight-aware tie-breaking (heaviest eligible edge chosen as BFS parent)."""
+    return _mcm_counted(row, col, val, n, mate_row, mate_col)[0]
 
 
 # --------------------------------------------------------------------------
@@ -279,6 +326,7 @@ def mcm(row, col, val, n: int, mate_row, mate_col) -> MatchState:
 # --------------------------------------------------------------------------
 
 
+@jax.named_scope("awac_select_augment")
 def select_and_augment(n, Cgain, Ci, Cw1, Cw2, state: MatchState, min_gain):
     """Steps D + survivor selection + augmentation, given global per-column
     Step-C winners. O(n) dense compute, replicated verbatim on every device in
@@ -516,32 +564,53 @@ def _resolve_window_steps(row, n, window_steps):
     return window_depth(max_row_nnz(row, n))
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n", "max_iter", "backend", "window_steps",
-                              "degrade_infeasible")
-)
-def _awac_loop(row, col, val, row_ptr, n: int, state: MatchState,
-               max_iter: int, min_gain, backend: str, window_steps: int,
-               degrade_infeasible: bool = False):
+def _awac_rounds(row, col, val, row_ptr, n: int, state: MatchState,
+                 max_iter: int, min_gain, backend: str, window_steps: int,
+                 degrade_infeasible: bool = False):
+    """AWAC rounds until one augments nothing or ``max_iter`` have run.
+    Returns (state, iters, counters): the 4-cycles augmented (``telemetry``)."""
+
     def body(carry):
-        state, it, _ = carry
-        Cgain, Ci, Cw1, Cw2 = _cwinners(
-            backend, row, col, val, row_ptr, n, state, min_gain, window_steps
-        )
-        state, n_surv = select_and_augment(n, Cgain, Ci, Cw1, Cw2, state, min_gain)
-        return state, it + 1, n_surv > 0
+        state, it, _, augmented = carry
+        with jax.named_scope("awac_sweep"):
+            Cgain, Ci, Cw1, Cw2 = _cwinners(
+                backend, row, col, val, row_ptr, n, state, min_gain,
+                window_steps
+            )
+        state, n_surv = select_and_augment(n, Cgain, Ci, Cw1, Cw2, state,
+                                           min_gain)
+        return state, it + 1, n_surv > 0, augmented + n_surv.astype(jnp.int32)
 
     def cond(carry):
-        _, it, go = carry
+        _, it, go, _ = carry
         return go & (it < max_iter)
 
     # AWAC rotates 4-cycles — cardinality never changes — so on an
     # imperfect (infeasible-instance) matching every round is pure waste:
     # skip the loop outright when asked to degrade
     go0 = is_perfect(state, n) if degrade_infeasible else jnp.array(True)
-    state, iters, _ = jax.lax.while_loop(
-        cond, body, (state, jnp.array(0, jnp.int32), go0)
+    state, iters, _, augmented = jax.lax.while_loop(
+        cond, body, (state, jnp.array(0, jnp.int32), go0,
+                     jnp.array(0, jnp.int32))
     )
+    return state, iters, {"awac_augmented": augmented}
+
+
+_awac_counted = _jit_named(
+    "_awac_loop", _awac_rounds,
+    static_argnames=("n", "max_iter", "backend", "window_steps",
+                     "degrade_infeasible"))
+
+
+def _awac_loop(row, col, val, row_ptr, n: int, state: MatchState,
+               max_iter: int, min_gain, backend: str, window_steps: int,
+               degrade_infeasible: bool = False):
+    """The AWAC loop (jitted as ``jit__awac_loop``): (state, iters), its
+    counters added to the solve in progress (``telemetry``)."""
+    state, iters, counters = _awac_counted(
+        row, col, val, row_ptr, n, state, max_iter, min_gain, backend,
+        window_steps, degrade_infeasible)
+    telemetry.count(counters)
     return state, iters
 
 
@@ -559,29 +628,29 @@ def awac(row, col, val, n: int, state: MatchState, max_iter: int = 1000,
     iteration counts.
     """
     backend = resolve_backend(backend, n=n)
-    window_steps = _resolve_window_steps(row, n, window_steps)
+    with telemetry.span("repro.window_depth"):
+        window_steps = _resolve_window_steps(row, n, window_steps)
     if row_ptr is None:
-        row_ptr = row_ptr_from_sorted(row, n)
+        row_ptr = telemetry.call("repro.row_ptr", row_ptr_from_sorted, row, n)
     if backend == "pallas_persistent":
         # Local import: core must stay importable without the kernel package.
         from repro.kernels.cycle_gain.ops import awac_persistent_loop
 
         go0 = is_perfect(state, n) if degrade_infeasible else jnp.array(True)
-        mr, mc, u, v, iters = awac_persistent_loop(
+        mr, mc, u, v, iters = telemetry.call(
+            "repro.awac", awac_persistent_loop,
             row, col, val, row_ptr, state.mate_row, state.mate_col, state.u,
             state.v, min_gain, go0, n=n, window_steps=window_steps,
             max_iter=max_iter)
+        telemetry.count({"awac_augmented": None})
         return MatchState(mr, mc, u, v), iters
-    if backend == "xla":
-        # x64-enabled trace context lets Step C run as ONE packed-key uint64
-        # segment_max (see repro.sparse.ops); inputs/outputs stay f32/i32.
-        # Under an outer jit the scope is a no-op (see _x64_scope).
-        with _x64_scope(row):
-            return _awac_loop(row, col, val, row_ptr, n, state, max_iter,
-                              min_gain, backend, window_steps,
-                              degrade_infeasible)
-    return _awac_loop(row, col, val, row_ptr, n, state, max_iter, min_gain,
-                      backend, window_steps, degrade_infeasible)
+    # With "xla", an x64-enabled trace context lets Step C run as ONE
+    # packed-key uint64 segment_max (see repro.sparse.ops); inputs/outputs
+    # stay f32/i32. Under an outer jit the scope is a no-op (see _x64_scope).
+    with _x64_scope(row) if backend == "xla" else contextlib.nullcontext():
+        return telemetry.call(
+            "repro.awac", _awac_loop, row, col, val, row_ptr, n, state,
+            max_iter, min_gain, backend, window_steps, degrade_infeasible)
 
 
 def _awpm(row, col, val, n: int, max_iter: int = 1000,
@@ -593,8 +662,12 @@ def _awpm(row, col, val, n: int, max_iter: int = 1000,
     Internal engine behind ``repro.core.api.solve`` (the single-instance
     dispatch target) and the deprecated ``awpm`` shim.
     """
-    st = greedy_maximal(row, col, val, n)
-    st = mcm(row, col, val, n, st.mate_row, st.mate_col)
+    st, counters = telemetry.call("repro.greedy", _greedy_counted, row, col,
+                                  val, n)
+    telemetry.count(counters)
+    st, counters = telemetry.call("repro.mcm", _mcm_counted, row, col, val, n,
+                                  st.mate_row, st.mate_col)
+    telemetry.count(counters)
     return awac(row, col, val, n, st, max_iter=max_iter, min_gain=min_gain,
                 backend=backend, window_steps=window_steps,
                 degrade_infeasible=degrade_infeasible)

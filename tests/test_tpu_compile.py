@@ -70,16 +70,17 @@ def _shapes(sharding, shape, n):
 
 def _lower_phase(mod, phase, n, edges, row_ptr, st):
     """Lower one phase of ``mod``'s local XLA route (``single`` or
-    ``batch``); AWAC runs under x64, as ``single.awac`` runs it."""
+    ``batch``), counters included, as ``solve()`` runs it; AWAC runs under
+    x64, as ``single.awac`` runs it."""
     row, col, val = edges
     if phase == "greedy":
-        fn = single.greedy_maximal if mod is single \
+        fn = single._greedy_counted if mod is single \
             else batch._greedy_maximal_batched
         return fn.lower(row, col, val, n=n)
     if phase == "mcm":
-        fn = single.mcm if mod is single else batch._mcm_batched
+        fn = single._mcm_counted if mod is single else batch._mcm_batched
         return fn.lower(row, col, val, n, st.mate_row, st.mate_col)
-    fn = single._awac_loop if mod is single else batch._awac_loop_batched
+    fn = single._awac_counted if mod is single else batch._awac_loop_batched
     with jax.enable_x64(True):
         return fn.lower(row, col, val, row_ptr, n, st, 1000, MIN_GAIN,
                         "xla", window_depth(n))
