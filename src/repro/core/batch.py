@@ -404,7 +404,10 @@ def mcm_loop(n: int, b: int, mate_row, mate_col, parents_fn):
     mate_row, mate_col, _, layers = jax.lax.while_loop(
         cond, body, (mate_row, mate_col, active0, jnp.zeros((b,), jnp.int32))
     )
-    return mate_row, mate_col, {"mcm_bfs_layers": layers}
+    # the per-layer parent choice here runs the flat scatter reductions,
+    # not the sorted-segment scan of ``single._mcm_bfs``
+    return mate_row, mate_col, {"mcm_bfs_layers": layers,
+                                "mcm_sorted_layers": None}
 
 
 @functools.partial(jax.jit, static_argnames=("n",))

@@ -30,7 +30,12 @@ from repro.core import telemetry
 from repro.core._compat import warn_legacy
 from repro.core.constants import MIN_GAIN
 from repro.sparse.csr import max_row_nnz, row_ptr_from_sorted, window_depth
-from repro.sparse.ops import lex_searchsorted, searchsorted_in_window, segment_max_with_payload
+from repro.sparse.ops import (
+    lex_searchsorted,
+    searchsorted_in_window,
+    segment_max_with_payload,
+    sorted_segment_max_with_payload,
+)
 
 NEG = -jnp.inf
 
@@ -230,24 +235,29 @@ def trace_and_flip(parent_col, visited, found, layers, mate_row, mate_col, n):
     return mate_row, mate_col
 
 
-def _mcm_bfs(row, col, val, n: int, mate_row, mate_col):
+def _mcm_bfs(row, col, val, row_ptr, n: int, mate_row, mate_col,
+             levels: int):
     """One layered BFS from all free rows with weight-aware parent selection.
+    Each row's parent is its heaviest edge into the frontier, reduced over
+    the row's contiguous run of edges (``sorted_segment_max_with_payload``,
+    ``levels`` covering the longest row); a row already visited keeps its
+    parent. Within a row the edges are sorted by column, so the smallest
+    column among the heaviest is the edge of smallest index, the tie-break
+    of every other reduction: the reduction carries the column itself.
     Returns (parent_col, visited, found, layers)."""
-    cap = row.shape[0]
-    eidx = jnp.arange(cap, dtype=jnp.int32)
     frontier = jnp.zeros((n + 1,), bool).at[:n].set(mate_row[:n] == n)
     parent_col = jnp.full((n + 1,), n, jnp.int32)
     visited = jnp.zeros((n + 1,), bool)
 
     def bfs_body(carry):
         frontier, parent_col, visited, found, layers, _ = carry
-        elig = (row < n) & frontier[col] & (~visited[row])
-        score = jnp.where(elig, val, NEG)
-        seg = jnp.where(elig, row, n)
-        _, re = segment_max_with_payload(score, eidx, seg, n + 1)
-        new = re[:n] >= 0
-        pc = jnp.where(new, col[jnp.clip(re[:n], 0)], parent_col[:n])
-        parent_col = parent_col.at[:n].set(pc.astype(jnp.int32))
+        # padding edges lie past row_ptr[n], in no row that is read
+        score = jnp.where(frontier[col], val, NEG)
+        _, best = sorted_segment_max_with_payload(score, col, row,
+                                                  row_ptr[:n + 1], levels)
+        new = (best >= 0) & ~visited[:n]
+        parent_col = parent_col.at[:n].set(
+            jnp.where(new, best, parent_col[:n]))
         visited = visited.at[:n].set(visited[:n] | new)
         free_new = new & (mate_col[:n] == n)
         found = free_new.any()
@@ -269,30 +279,32 @@ def _mcm_bfs(row, col, val, n: int, mate_row, mate_col):
     return parent_col, visited, found, layers
 
 
-def mcm_phase(row, col, val, n: int, mate_row, mate_col):
+def mcm_phase(row, col, val, row_ptr, n: int, mate_row, mate_col,
+              levels: int):
     """One MCM phase: layered BFS + trace/flip of the augmenting paths it
     found. The batched engine re-expresses this phase on flat
     offset-segment primitives (``batch._mcm_bfs_batched`` /
     ``batch.trace_and_flip_batched``) — changes here must be mirrored there
     to keep per-instance bit-exactness. Returns (mate_row, mate_col,
     found, layers), ``layers`` the BFS's."""
-    parent_col, visited, found, layers = _mcm_bfs(row, col, val, n, mate_row,
-                                                 mate_col)
+    parent_col, visited, found, layers = _mcm_bfs(
+        row, col, val, row_ptr, n, mate_row, mate_col, levels)
     mate_row, mate_col = trace_and_flip(
         parent_col, visited, found, layers, mate_row, mate_col, n
     )
     return mate_row, mate_col, found, layers
 
 
-def _mcm_phases(row, col, val, n: int, mate_row, mate_col):
+def _mcm_phases(row, col, val, row_ptr, n: int, mate_row, mate_col,
+                levels: int):
     """MCM phases until one finds no augmenting path or every row is
-    matched. Returns (MatchState, counters): the BFS layers of all phases
-    (``telemetry``)."""
+    matched. Returns (MatchState, counters): the BFS layers of all phases,
+    each of which ran the sorted-segment reduction (``telemetry``)."""
 
     def phase_body(carry):
         mate_row, mate_col, _, layers = carry
         mate_row, mate_col, found, phase_layers = mcm_phase(
-            row, col, val, n, mate_row, mate_col)
+            row, col, val, row_ptr, n, mate_row, mate_col, levels)
         return mate_row, mate_col, found, layers + phase_layers
 
     def phase_cond(carry):
@@ -309,16 +321,29 @@ def _mcm_phases(row, col, val, n: int, mate_row, mate_col):
                                  jnp.array(0, jnp.int32))
     )
     return (state_from_mates(row, col, val, n, mate_row, mate_col),
-            {"mcm_bfs_layers": layers})
+            {"mcm_bfs_layers": layers, "mcm_sorted_layers": layers})
 
 
-_mcm_counted = _jit_named("mcm", _mcm_phases, static_argnames=("n",))
+_mcm_counted = _jit_named("mcm", _mcm_phases,
+                          static_argnames=("n", "levels"))
+
+
+def _scan_levels(row, n: int, window_steps: int) -> int:
+    """Levels of MCM's sorted-segment scan, ceil(log2(max row degree)),
+    from the windowed-search depth resolved for the same rows
+    (``_resolve_window_steps``: one round more than that), capped at the
+    bound for rows of min(cap, n) entries."""
+    cap = int(row.shape[-1])
+    return min(window_steps, window_depth(min(cap, n))) - 1
 
 
 def mcm(row, col, val, n: int, mate_row, mate_col) -> MatchState:
     """Maximum cardinality matching from an initial matching, with the paper's
-    weight-aware tie-breaking (heaviest eligible edge chosen as BFS parent)."""
-    return _mcm_counted(row, col, val, n, mate_row, mate_col)[0]
+    weight-aware tie-breaking (heaviest eligible edge chosen as BFS parent).
+    Builds ``row_ptr`` and measures the scan's levels from ``row``."""
+    levels = _scan_levels(row, n, _resolve_window_steps(row, n, None))
+    return _mcm_counted(row, col, val, row_ptr_from_sorted(row, n), n,
+                        mate_row, mate_col, levels)[0]
 
 
 # --------------------------------------------------------------------------
@@ -627,11 +652,19 @@ def awac(row, col, val, n: int, state: MatchState, max_iter: int = 1000,
     bit-exactness oracle). All backends produce identical results and
     iteration counts.
     """
-    backend = resolve_backend(backend, n=n)
     with telemetry.span("repro.window_depth"):
         window_steps = _resolve_window_steps(row, n, window_steps)
     if row_ptr is None:
         row_ptr = telemetry.call("repro.row_ptr", row_ptr_from_sorted, row, n)
+    return _awac(row, col, val, row_ptr, n, state, max_iter, min_gain,
+                 backend, window_steps, degrade_infeasible)
+
+
+def _awac(row, col, val, row_ptr, n: int, state: MatchState, max_iter: int,
+          min_gain, backend: str, window_steps: int,
+          degrade_infeasible: bool):
+    """:func:`awac` with ``row_ptr`` built and ``window_steps`` resolved."""
+    backend = resolve_backend(backend, n=n)
     if backend == "pallas_persistent":
         # Local import: core must stay importable without the kernel package.
         from repro.kernels.cycle_gain.ops import awac_persistent_loop
@@ -660,17 +693,22 @@ def _awpm(row, col, val, n: int, max_iter: int = 1000,
     """Full pipeline: greedy maximal -> MCM -> AWAC. Returns (state, awac_iters).
 
     Internal engine behind ``repro.core.api.solve`` (the single-instance
-    dispatch target) and the deprecated ``awpm`` shim.
+    dispatch target) and the deprecated ``awpm`` shim. The rows' max degree
+    is measured once, while the device runs greedy; it sets both MCM's scan
+    levels and AWAC's search depth, and ``row_ptr`` serves both phases.
     """
     st, counters = telemetry.call("repro.greedy", _greedy_counted, row, col,
                                   val, n)
     telemetry.count(counters)
-    st, counters = telemetry.call("repro.mcm", _mcm_counted, row, col, val, n,
-                                  st.mate_row, st.mate_col)
+    with telemetry.span("repro.window_depth"):
+        window_steps = _resolve_window_steps(row, n, window_steps)
+    row_ptr = telemetry.call("repro.row_ptr", row_ptr_from_sorted, row, n)
+    st, counters = telemetry.call(
+        "repro.mcm", _mcm_counted, row, col, val, row_ptr, n, st.mate_row,
+        st.mate_col, _scan_levels(row, n, window_steps))
     telemetry.count(counters)
-    return awac(row, col, val, n, st, max_iter=max_iter, min_gain=min_gain,
-                backend=backend, window_steps=window_steps,
-                degrade_infeasible=degrade_infeasible)
+    return _awac(row, col, val, row_ptr, n, st, max_iter, min_gain, backend,
+                 window_steps, degrade_infeasible)
 
 
 def awpm(row, col, val, n: int, max_iter: int = 1000, min_gain: float = MIN_GAIN,

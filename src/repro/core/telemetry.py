@@ -21,6 +21,10 @@ Counters (summed over a batch's instances):
   greedy_rounds    greedy proposal rounds, the last of which matches nothing
   greedy_matched   pairs matched when greedy ends
   mcm_bfs_layers   BFS layers over all MCM phases
+  mcm_sorted_layers  BFS layers whose parent choice ran the sorted-segment
+                   scan (``single._mcm_bfs``): all of them on the
+                   single-instance route, None on the batched and grid
+                   engines, whose reductions scatter
   awac_augmented   4-cycles augmented over all AWAC rounds (None where the
                    persistent Pallas loop ran: it does not count them)
   h2d_bytes        bytes of the host arrays handed to the engines' jitted
@@ -41,7 +45,7 @@ CAPACITY = 64
 
 #: The counters a solve record holds; each starts at 0.
 COUNTERS = ("greedy_rounds", "greedy_matched", "mcm_bfs_layers",
-            "awac_augmented", "h2d_bytes")
+            "mcm_sorted_layers", "awac_augmented", "h2d_bytes")
 
 _records: collections.deque = collections.deque(maxlen=CAPACITY)
 _solve_ids = itertools.count()

@@ -22,6 +22,7 @@ from repro.sparse.ops import (
     segment_argmax,
     segment_max_with_payload,
     segment_softmax,
+    sorted_segment_max_with_payload,
     x64_available,
 )
 from repro.sparse.partition import (
@@ -37,6 +38,7 @@ __all__ = [
     "segment_argmax",
     "segment_max_with_payload",
     "segment_softmax",
+    "sorted_segment_max_with_payload",
     "coo_spmm",
     "coo_sddmm",
     "lex_searchsorted",

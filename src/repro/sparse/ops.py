@@ -96,6 +96,49 @@ def segment_max_with_payload(values, payload, segment_ids, num_segments):
     return seg_max, seg_payload
 
 
+def _shift(x, s: int, fill):
+    """``x`` moved ``s`` entries toward the end, the first ``s`` set to
+    ``fill``: entry e holds what entry e - s held."""
+    return jnp.concatenate([jnp.full((s,), fill, x.dtype), x[:-s]])
+
+
+def sorted_segment_max_with_payload(values, payload, segment_ids, segment_ptr,
+                                    levels: int):
+    """``segment_max_with_payload`` for segments that are contiguous runs:
+    ``segment_ids`` [m] never decrease, and segment i is
+    ``[segment_ptr[i], segment_ptr[i + 1])`` (CSR ``row_ptr``; entries past
+    ``segment_ptr[-1]``, such as a padding tail, belong to no segment read).
+    ``levels`` must cover the longest segment: ``2 ** levels >= max length``.
+
+    A windowed segmented scan (Hillis-Steele) with no scatter and no
+    entry-wide gather: at shift s = 1, 2, ..., 2 ** (levels - 1) each entry
+    takes in entry e - s when both lie in one segment. The combine keeps the
+    larger value (``jnp.maximum``, as ``segment_max`` does) and, among values
+    equal by float ``==`` (so +0 and -0 tie), the smaller payload. It is
+    associative and commutative, so each segment's last entry ends with the
+    result of the two-pass reduction, bit for bit; one gather of the
+    segments' last entries reads it. Values must not be NaN.
+
+    Returns (seg_max [k], seg_payload [k]) for the k =
+    ``len(segment_ptr) - 1`` segments; (-inf, -1) for a segment that is
+    empty or whose max is -inf."""
+    m = values.shape[0]
+    for level in range(levels):
+        s = 1 << level
+        if s >= m:
+            break
+        same = _shift(segment_ids, s, -1) == segment_ids
+        pv = _shift(values, s, NEG)
+        pp = _shift(payload, s, 0)
+        take = same & ((pv > values) | ((pv == values) & (pp < payload)))
+        values = jnp.where(same, jnp.maximum(pv, values), values)
+        payload = jnp.where(take, pp, payload)
+    start, end = segment_ptr[:-1], segment_ptr[1:]
+    last = jnp.clip(end - 1, 0, m - 1)
+    seg_max = jnp.where(end > start, values[last], NEG)
+    return seg_max, jnp.where(seg_max == NEG, -1, payload[last])
+
+
 def segment_argmax_tie(values, tie, segment_ids, num_segments):
     """Per-segment argmax with an explicit tie-break key (smallest ``tie``
     wins; a second tie falls back to smallest index). Returns

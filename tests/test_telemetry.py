@@ -96,6 +96,8 @@ def test_counters_equal_the_numpy_count(kind, n, degree, seed, min_phases):
     for name in ("greedy_rounds", "greedy_matched", "mcm_bfs_layers",
                  "awac_augmented"):
         assert counters[name] == want[name], (name, counters, want)
+    # every BFS layer of the single route picks parents by the sorted scan
+    assert counters["mcm_sorted_layers"] == counters["mcm_bfs_layers"]
     iters = int(result.awac_iters)
     assert (counters["awac_augmented"] > 0) == (iters > 1), (counters, iters)
     # numpy row, col, val: each engine call copies them anew — greedy, MCM
@@ -126,7 +128,8 @@ def counters(result):
     rec = telemetry.recent(1)[0]["counters"]
     return {k: rec[k] for k in ("greedy_rounds", "greedy_matched",
                                 "mcm_bfs_layers", "awac_augmented")}, \
-        np.asarray(result.mate_row).reshape(-1).tolist()
+        np.asarray(result.mate_row).reshape(-1).tolist(), \
+        rec["mcm_sorted_layers"]
 
 out = {}
 cold = api.solve(p)
@@ -166,17 +169,23 @@ print(json.dumps([out, scopes]))
 @pytest.mark.parametrize("start", ["cold", "warm"])
 def test_counters_identical_on_every_route(start):
     """Cold: the single route, the B = 1 batched route and the 2x2 grid;
-    warm: the warm route and the grid. A warm solve runs no greedy."""
+    warm: the warm route and the grid. A warm solve runs no greedy. Only
+    the cold single route runs the sorted-segment scan
+    (``mcm_sorted_layers``); the warm route, lifted to the batched engine,
+    and the others report None."""
     import json
 
     out, scopes = json.loads(run_with_devices(
         f"START = {start!r}\n" + ROUTES_SCRIPT, 4)
         .strip().splitlines()[-1])
-    first_counters, first_mates = out.pop("single")
-    for route, (counters, mates) in out.items():
+    first_counters, first_mates, first_sorted = out.pop("single")
+    for route, (counters, mates, sorted_layers) in out.items():
         assert counters == first_counters, (route, counters, first_counters)
         assert mates == first_mates, route
+        assert sorted_layers is None, route
     assert first_counters["mcm_bfs_layers"] > 0
+    assert first_sorted == (first_counters["mcm_bfs_layers"]
+                            if start == "cold" else None)
     if start == "cold":
         assert scopes == ["greedy_round", "mcm_bfs_layer", "mcm_trace_flip",
                           "awac_sweep", "awac_select_augment", "a2a_exchange"]
@@ -251,8 +260,8 @@ def test_named_scopes_in_the_lowered_phases():
     row_ptr = jax.ShapeDtypeStruct((n + 2,), jnp.int32)
     lowered = {
         "greedy_maximal": single._greedy_counted.lower(row, col, val, n),
-        "mcm": single._mcm_counted.lower(row, col, val, n, st.mate_row,
-                                         st.mate_col),
+        "mcm": single._mcm_counted.lower(row, col, val, row_ptr, n,
+                                         st.mate_row, st.mate_col, 6),
     }
     with jax.enable_x64(True):
         lowered["_awac_loop"] = single._awac_counted.lower(

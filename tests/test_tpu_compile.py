@@ -12,6 +12,7 @@ module is imported: only one process may load the TPU library, and every
 test worker imports this file.
 """
 import importlib.util
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,9 @@ from repro.core.constants import MIN_GAIN
 from repro.sparse.csr import window_depth
 
 DEG = 16
+# MCM's sorted-segment scan levels for rows of up to 64 entries: the
+# benchmark's degree-16 instance at n = 65,536 has 36 at most
+MCM_LEVELS = 6
 
 
 @pytest.fixture(scope="module")
@@ -77,9 +81,13 @@ def _lower_phase(mod, phase, n, edges, row_ptr, st):
         fn = single._greedy_counted if mod is single \
             else batch._greedy_maximal_batched
         return fn.lower(row, col, val, n=n)
+    if phase == "mcm" and mod is single:
+        return single._mcm_counted.lower(row, col, val, row_ptr, n,
+                                         st.mate_row, st.mate_col,
+                                         MCM_LEVELS)
     if phase == "mcm":
-        fn = single._mcm_counted if mod is single else batch._mcm_batched
-        return fn.lower(row, col, val, n, st.mate_row, st.mate_col)
+        return batch._mcm_batched.lower(row, col, val, n, st.mate_row,
+                                        st.mate_col)
     fn = single._awac_counted if mod is single else batch._awac_loop_batched
     with jax.enable_x64(True):
         return fn.lower(row, col, val, row_ptr, n, st, 1000, MIN_GAIN,
@@ -92,6 +100,34 @@ def test_local_xla_route_compiles(one_chip, phase):
     edges, row_ptr, st = _shapes(one_chip, (n * DEG,), n)
     compiled = _lower_phase(single, phase, n, edges, row_ptr, st).compile()
     assert compiled.memory_analysis() is not None
+
+
+def _shape_of(text, name):
+    """The result shape of instruction ``%name`` in HLO ``text``."""
+    return re.search(rf"%{re.escape(name)} = (\S+) ", text).group(1)
+
+
+def test_mcm_bfs_layer_reads_the_edges_once(one_chip):
+    """MCM's BFS layer, compiled for the chip at the benchmark's size,
+    holds one edge-wide gather, ``frontier[col]``, and no scatter whose
+    updates span the edges: each row's parent comes from a scan over its
+    contiguous run of edges, not from scatters into the rows."""
+    n = 65_536
+    cap = n * DEG
+    edges, row_ptr, st = _shapes(one_chip, (cap,), n)
+    text = _lower_phase(single, "mcm", n, edges, row_ptr, st).compile() \
+        .as_text()
+    layer = [ln for ln in text.splitlines() if "mcm_bfs_layer/" in ln]
+    gathers = [ln for ln in layer if " gather(" in ln]
+    edge_gathers = [ln for ln in gathers
+                    if re.search(rf"= \w+\[{cap}\]", ln)]
+    assert len(gathers) >= 2 and len(edge_gathers) == 1, edge_gathers
+    assert edge_gathers[0].split("=")[1].strip().startswith("pred["), \
+        edge_gathers[0]  # the frontier's flags, read at each edge's column
+    scatters = [ln for ln in layer if " scatter(" in ln]
+    for ln in scatters:
+        updates = re.search(r" scatter\(%[^,]+, %[^,]+, %([^,)]+)", ln)
+        assert f"[{cap}]" not in _shape_of(text, updates.group(1)), ln
 
 
 @pytest.mark.parametrize("phase", ["greedy", "mcm", "awac"])
