@@ -490,10 +490,11 @@ _CONVERGENCE_TAP = None
 def awac_loop(n: int, state: MatchState, max_iter: int, min_gain,
               cwinners_fn, active0=None, aux0=None):
     """Masked batched AWAC loop. ``cwinners_fn(state) -> (Cgain, Ci, Cw1,
-    Cw2, aux)`` supplies each round's Step A+B+C winners plus an int32
-    value accumulated across rounds (scalar 0 for the local backends; the
-    dropped-candidate count — or the [dropped, integrity] pair under
-    exchange checking — for the distributed engine's bucketed exchanges).
+    Cw2, aux)`` supplies each round's Step A+B+C winners plus a value (a
+    pytree of arrays) accumulated across rounds: scalar int32 0 for the
+    local backends; for the distributed engine, the dropped-candidate count
+    (or the [dropped, integrity] pair under exchange checking) with the
+    slots its bucketed exchanges carried and filled.
     Step D + augmentation is the vmapped ``single.select_and_augment`` —
     shared verbatim with every other engine.
 
@@ -522,7 +523,8 @@ def awac_loop(n: int, state: MatchState, max_iter: int, min_gain,
         active = active & (n_surv > 0) & (iters < max_iter)
         if _CONVERGENCE_TAP is not None:
             active = _CONVERGENCE_TAP(active, iters)
-        return state, iters, active, aux + a, augmented
+        return state, iters, active, jax.tree.map(jnp.add, aux, a), \
+            augmented
 
     def cond(carry):
         return carry[2].any()

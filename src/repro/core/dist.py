@@ -590,8 +590,11 @@ def a2a_bucketed_batched(arrays, fills, dest, valid, n_peers: int,
     instance's buckets as [n_peers, B, cap_out(, k)] — per-message latency
     amortizes across the whole batch instead of paying B exchanges.
 
-    Returns (out arrays list of [B, n_peers * cap_out], out_valid, dropped
-    int32 scalar summed over instances)."""
+    Returns (out arrays list of [B, n_peers * cap_out], out_valid, dropped,
+    filled): ``dropped`` the valid entries no bucket had room for and
+    ``filled`` the slots holding a valid entry, int32 scalars summed over
+    instances. Each call carries ``B * n_peers * cap_out`` slots, of
+    ``a2a_slot_bytes(len(arrays), packed)`` bytes each."""
     b, L = dest.shape
     bix = jnp.arange(b, dtype=jnp.int32)[:, None]
     d = jnp.where(valid, dest, n_peers)
@@ -605,7 +608,8 @@ def a2a_bucketed_batched(arrays, fills, dest, valid, n_peers: int,
     ok = (ds < n_peers) & (posin < cap_out)
     slot = jnp.where(ok, ds.astype(jnp.int32) * cap_out + posin,
                      n_peers * cap_out)
-    dropped = ((ds < n_peers).sum() - ok.sum()).astype(jnp.int32)
+    filled = ok.sum(dtype=jnp.int32)
+    dropped = (ds < n_peers).sum(dtype=jnp.int32) - filled
 
     def fill_buf(a, fv):
         src = jnp.take_along_axis(a, order, axis=1)
@@ -635,13 +639,19 @@ def a2a_bucketed_batched(arrays, fills, dest, valid, n_peers: int,
             outs.append(c)
         # validity from the first array's sentinel (mate ids use fill = n)
         outs, vrecv = _tapped(axis_name, outs, outs[0] != fills[0])
-        return outs, vrecv, dropped
+        return outs, vrecv, dropped, filled
 
     outs = [exchange(fill_buf(a, fv)) for a, fv in zip(arrays, fills)]
     vbuf = jnp.zeros((b, n_peers * cap_out + 1), jnp.int8).at[bix, slot].set(
         ok.astype(jnp.int8))[:, :-1]
     outs, vrecv = _tapped(axis_name, outs, exchange(vbuf).astype(bool))
-    return outs, vrecv, dropped
+    return outs, vrecv, dropped, filled
+
+
+def a2a_slot_bytes(k: int, packed: bool) -> int:
+    """Bytes one bucket slot of ``a2a_bucketed_batched`` carries: ``k``
+    4-byte payloads, plus the int8 validity buffer on the unpacked path."""
+    return 4 * k + (0 if packed else 1)
 
 
 def safe_a2a_caps(cap_blk: int, pr: int, pc: int) -> tuple[int, int]:
@@ -687,7 +697,10 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
 
     Returns jitted ``run(brow, bcol, bval) -> (MatchState, iters [B],
     dropped, counters)`` over [Pr, Pc, B, cap] blocks, the counters those
-    of the batched skeletons (``telemetry``). With ``from_state=True`` the
+    of the batched skeletons plus the AWAC exchange's (``telemetry``):
+    ``a2a_slots``, ``a2a_filled`` and ``a2a_bytes``, int64 [Pr * Pc], one
+    entry per device, summed over both stages and every AWAC round (the
+    x64 context is what gives them 64 bits). With ``from_state=True`` the
     runner instead takes a replicated initial MatchState ([B, n + 1]
     fields) and runs the AWAC phase only — ``run(brow, bcol, bval,
     mate_row, mate_col, u, v)`` — the distributed analogue of
@@ -709,6 +722,10 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
     all_axes = tuple(spec.row_axes) + (spec.col_axis,)
     if window_steps is None:
         window_steps = _search_depth(cap)
+    # bucket slots one device's two all-to-alls carry each AWAC round (its
+    # own bucket included), and the bytes of a slot: i, j and w
+    round_slots = b * (pc * cap1 + pr * cap2)
+    slot_bytes = a2a_slot_bytes(3, packed)
 
     def block_fn(brow, bcol, bval, *state_args):
         brow = brow.reshape(b, cap)
@@ -805,13 +822,13 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
             if exchange_check:
                 cnt_in, chk_in = _conserved([i2, j2, bval], valid)
             # stage 1: route to owning grid column (by j2)
-            (o_i, o_j, o_w), v1, d1 = a2a_bucketed_batched(
+            (o_i, o_j, o_w), v1, d1, f1 = a2a_bucketed_batched(
                 [i2, j2, bval],
                 [_int_fill(n), _int_fill(n), jnp.float32(0)],
                 j2 // bc, valid, pc, cap1, col_axis, packed=packed,
             )
             # stage 2: route to owning grid row (by o_i)
-            (qi, qj, qw2), qvalid, d2 = a2a_bucketed_batched(
+            (qi, qj, qw2), qvalid, d2, f2 = a2a_bucketed_batched(
                 [o_i, o_j, o_w],
                 [_int_fill(n), _int_fill(n), jnp.float32(0)],
                 o_i // br, v1, pr, cap2, row_axes, packed=packed,
@@ -869,7 +886,9 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
             Cw1 = gather_n(w1_0, col_axis)
             Cw2 = gather_n(w2_0, col_axis)
             Ci = jnp.where(Cgain > NEG, Ci, n).astype(jnp.int32)
-            return Cgain, Ci, Cw1, Cw2, aux
+            moved = jnp.stack([jnp.int64(round_slots),
+                               (f1 + f2).astype(jnp.int64)])
+            return Cgain, Ci, Cw1, Cw2, (aux, moved)
 
         if backend in ("xla", "pallas"):
             # 1x1 grid: the block IS the instance — Steps A+B+C run through
@@ -884,7 +903,7 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
                     window_steps)
                 zero = jnp.zeros((2,), jnp.int32) if exchange_check \
                     else jnp.array(0, jnp.int32)
-                return (*out, zero)
+                return (*out, (zero, jnp.zeros((2,), jnp.int64)))
 
         # ---- the pipeline: shared batched loop skeletons, dist winners ----
         counters = {}
@@ -895,31 +914,36 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
             mr, mc, mcm_counts = batch.mcm_loop(n, b, mr, mc, mcm_parents)
             counters = {**greedy_counts, **mcm_counts}
             state0 = uv_state(mr, mc)
-        state, iters, aux, awac_counts = batch.awac_loop(
+        state, iters, (aux, moved), awac_counts = batch.awac_loop(
             n, state0, max_iter, min_gain, cwinners,
             active0=(batch.is_perfect_batched(state0, n)
                      if degrade_infeasible else None),
-            aux0=(jnp.zeros((2,), jnp.int32) if exchange_check else None))
+            aux0=(jnp.zeros((2,), jnp.int32) if exchange_check
+                  else jnp.array(0, jnp.int32), jnp.zeros((2,), jnp.int64)))
         if not exchange_check:
             # the per-round [dropped, integrity] pair is already psum'd
             # inside cwinners; the plain dropped counter is not
             aux = jax.lax.psum(aux, all_axes)
+        # this device's exchange, in 64 bits: the host sums the devices
+        exchange = {"a2a_slots": moved[:1], "a2a_filled": moved[1:],
+                    "a2a_bytes": moved[:1] * slot_bytes}
         return (state.mate_row, state.mate_col, state.u, state.v, iters,
-                aux, {**counters, **awac_counts})
+                aux, {**counters, **awac_counts}, exchange)
 
     blk = spec.block_spec_batched()
     state_specs = (P(), P(), P(), P()) if from_state else ()
     fn = _shard_map(
         block_fn, mesh=spec.mesh,
         in_specs=(blk, blk, blk) + state_specs,
-        out_specs=(P(), P(), P(), P(), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), P(), P(), P(), P(all_axes)),
     )
 
     @jax.jit
     def run(brow, bcol, bval, *state_args):
-        mr, mc, u, v, iters, dropped, counters = fn(brow, bcol, bval,
-                                                    *state_args)
-        return MatchState(mr, mc, u, v), iters, dropped, counters
+        mr, mc, u, v, iters, dropped, counters, exchange = fn(
+            brow, bcol, bval, *state_args)
+        return MatchState(mr, mc, u, v), iters, dropped, {**counters,
+                                                          **exchange}
 
     return run
 

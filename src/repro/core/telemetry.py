@@ -29,6 +29,15 @@ Counters (summed over a batch's instances):
                    persistent Pallas loop ran: it does not count them)
   h2d_bytes        bytes of the host arrays handed to the engines' jitted
                    calls, each of which copies them to the device
+  a2a_slots        bucket slots the grid engine's AWAC all-to-alls carried,
+                   over both stages, every round and every device (each
+                   device's own bucket included)
+  a2a_filled       those of them that held a candidate
+  a2a_bytes        the bytes those slots carried (``dist.a2a_slot_bytes``:
+                   the payloads, and the validity byte on the unpacked path)
+
+The ``a2a_*`` counters are the grid engine's alone: a solve that ran no
+grid exchange reads None there, not 0.
 """
 from __future__ import annotations
 
@@ -43,9 +52,12 @@ import numpy as np
 #: Solves whose records the buffer keeps.
 CAPACITY = 64
 
-#: The counters a solve record holds; each starts at 0.
+#: Counters only the grid engine keeps; None where it did not run.
+GRID_COUNTERS = ("a2a_slots", "a2a_filled", "a2a_bytes")
+#: The counters a solve record holds; each other one starts at 0.
 COUNTERS = ("greedy_rounds", "greedy_matched", "mcm_bfs_layers",
-            "mcm_sorted_layers", "awac_augmented", "h2d_bytes")
+            "mcm_sorted_layers", "awac_augmented",
+            "h2d_bytes") + GRID_COUNTERS
 
 _records: collections.deque = collections.deque(maxlen=CAPACITY)
 _solve_ids = itertools.count()
@@ -152,8 +164,9 @@ def call(name: str, fn, *args, **kwargs):
         return fn(*args, **kwargs)
 
 
-def _total(values):
-    if any(v is None for v in values):
+def _total(name, values):
+    if any(v is None for v in values) or (
+            not values and name in GRID_COUNTERS):
         return None
     return int(sum(int(np.asarray(v).sum()) for v in values))
 
@@ -169,6 +182,6 @@ def recent(k: int) -> list[dict]:
              "spans": [{"solve_id": r.solve_id, "name": name,
                         "start_ns": start, "end_ns": end, "parent": parent}
                        for name, start, end, parent in r.spans],
-             "counters": {name: _total(values)
+             "counters": {name: _total(name, values)
                           for name, values in r.counts.items()}}
             for r in recs]

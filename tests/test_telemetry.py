@@ -129,7 +129,8 @@ def counters(result):
     return {k: rec[k] for k in ("greedy_rounds", "greedy_matched",
                                 "mcm_bfs_layers", "awac_augmented")}, \
         np.asarray(result.mate_row).reshape(-1).tolist(), \
-        rec["mcm_sorted_layers"]
+        rec["mcm_sorted_layers"], \
+        [rec[k] for k in telemetry.GRID_COUNTERS]
 
 out = {}
 cold = api.solve(p)
@@ -172,17 +173,24 @@ def test_counters_identical_on_every_route(start):
     warm: the warm route and the grid. A warm solve runs no greedy. Only
     the cold single route runs the sorted-segment scan
     (``mcm_sorted_layers``); the warm route, lifted to the batched engine,
-    and the others report None."""
+    and the others report None. Only the grid keeps the exchange's
+    counters (``a2a_*``); every other route reports None there."""
     import json
 
     out, scopes = json.loads(run_with_devices(
         f"START = {start!r}\n" + ROUTES_SCRIPT, 4)
         .strip().splitlines()[-1])
-    first_counters, first_mates, first_sorted = out.pop("single")
-    for route, (counters, mates, sorted_layers) in out.items():
+    first_counters, first_mates, first_sorted, first_a2a = out.pop("single")
+    assert first_a2a == [None, None, None]
+    for route, (counters, mates, sorted_layers, a2a) in out.items():
         assert counters == first_counters, (route, counters, first_counters)
         assert mates == first_mates, route
         assert sorted_layers is None, route
+        if route == "grid 2x2":
+            slots, filled, nbytes = a2a
+            assert 0 < filled <= slots and nbytes == 13 * slots, a2a
+        else:
+            assert a2a == [None, None, None], route
     assert first_counters["mcm_bfs_layers"] > 0
     assert first_sorted == (first_counters["mcm_bfs_layers"]
                             if start == "cold" else None)
@@ -192,6 +200,92 @@ def test_counters_identical_on_every_route(start):
     else:
         assert first_counters["greedy_rounds"] == 0
         assert first_counters["greedy_matched"] == 0
+
+
+EXCHANGE_SCRIPT = r"""
+import json, sys
+import numpy as np
+from repro.core import api, dist, graph, telemetry
+
+kind, packed = sys.argv[1], sys.argv[2] == "packed"
+n = 128
+g = graph.generate(n, avg_degree=6.0, kind=kind, seed=9)
+opts = api.SolveOptions(grid=dist.make_mesh((2, 2)), packed=packed)
+api.solve(api.MatchingProblem.from_graph(g), opts)
+rec = telemetry.recent(1)[0]["counters"]
+# the partition and the caps `_DistBatchedAWPM` plans for this instance
+drv = dist._DistBatchedAWPM(opts.grid, n)
+part = drv.partition(g.row[None], g.col[None], g.val[None])[0]
+print(json.dumps({
+    "counters": {k: rec[k] for k in telemetry.GRID_COUNTERS},
+    "row": part.row.tolist(), "col": part.col.tolist(),
+    "caps": dist.safe_a2a_caps(part.cap, 2, 2)}))
+"""
+
+
+def numpy_exchange(row, col, n, caps, states):
+    """Slots and filled slots of the two-stage bucketed exchange, counted
+    by routing each round's candidates in numpy: ``row``/``col`` the
+    [pr, pc, 1, cap] blocks, ``states`` the (mate_row, mate_col) each AWAC
+    round starts from."""
+    pr, pc = row.shape[:2]
+    br, bc = -(-n // pr), -(-n // pc)
+    cap1, cap2 = caps
+    slots = filled = 0
+    for mate_row, mate_col in states:
+        slots += pr * pc * (pc * cap1 + pr * cap2)
+        received = [[[] for _ in range(pc)] for _ in range(pr)]
+        for a in range(pr):
+            for b in range(pc):
+                r, c = row[a, b, 0], col[a, b, 0]
+                i2, j2 = mate_row[c], mate_col[r]
+                ok = (r < n) & (i2 < n) & (j2 < n)
+                for d in range(pc):  # stage 1: to the grid column of j2
+                    sent = i2[ok & (j2 // bc == d)][:cap1]
+                    filled += sent.size
+                    received[a][d].append(sent)
+        for a in range(pr):
+            for b in range(pc):  # stage 2: to the grid row of i2
+                i2 = np.concatenate(received[a][b])
+                filled += sum(min(int((i2 // br == d).sum()), cap2)
+                              for d in range(pr))
+    return slots, filled
+
+
+@pytest.mark.parametrize("kind,packed", [("uniform", "unpacked"),
+                                         ("banded", "packed")])
+def test_exchange_counters_equal_the_numpy_count(kind, packed):
+    """``a2a_slots`` and ``a2a_filled`` on the 2x2 grid equal a numpy
+    routing of every AWAC round's candidates through the same partition and
+    the same bucket capacities; ``a2a_bytes`` is the slots times 12 bytes
+    of payload, plus 1 of validity on the unpacked path."""
+    import json
+
+    out = json.loads(run_with_devices(
+        f"import sys; sys.argv[1:] = [{kind!r}, {packed!r}]\n"
+        + EXCHANGE_SCRIPT, 4).strip().splitlines()[-1])
+    n = 128
+    g = graph.generate(n, avg_degree=6.0, kind=kind, seed=9)
+    real = g.row < n
+    row, col = g.row[real].astype(np.int64), g.col[real].astype(np.int64)
+    val = g.val[real]
+    mate_row, mate_col = reference.greedy(row, col, val, n)
+    mate_row, mate_col = reference.mcm(row, col, val, n, mate_row, mate_col)
+    key = row * (n + 1) + col
+    u, v = reference._matched_weights(key, val, n, mate_row, mate_col,
+                                      np.float32)
+    states, go = [], True
+    while go:  # reference.solve's AWAC rounds, each one's starting state
+        states.append((mate_row.copy(), mate_col.copy()))
+        go = reference.awac_round(row, col, val, key, n, mate_row, mate_col,
+                                  u, v, np.float32(reference.MIN_GAIN)) > 0
+    slots, filled = numpy_exchange(np.array(out["row"]), np.array(out["col"]),
+                                   n, out["caps"], states)
+    got = out["counters"]
+    assert (got["a2a_slots"], got["a2a_filled"]) == (slots, filled), \
+        (got, slots, filled)
+    assert 0 < got["a2a_filled"] <= got["a2a_slots"]
+    assert got["a2a_bytes"] == (12 if packed == "packed" else 13) * slots
 
 
 def test_span_tree():
