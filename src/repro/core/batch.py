@@ -375,12 +375,16 @@ def mcm_batched(row, col, val, n: int, mate_row, mate_col):
     return mate_row, mate_col
 
 
-def mcm_loop(n: int, b: int, mate_row, mate_col, parents_fn):
+def mcm_loop(n: int, b: int, mate_row, mate_col, parents_fn,
+             sorted_scan: bool = False):
     """Masked MCM phase loop over the batched BFS + trace/flip bodies,
     parameterized by the per-layer parent selection (``parents_fn``, see
     ``mcm_bfs_loop``) so the distributed-batched engine shares every mask
-    and commit verbatim. Returns (mate_row, mate_col, counters): per
-    instance the BFS layers of all its phases (``telemetry``)."""
+    and commit verbatim. ``sorted_scan`` says that ``parents_fn`` picks
+    each row's parent with the sorted-segment scan. Returns (mate_row,
+    mate_col, counters): per instance the BFS layers of all its phases,
+    and the same layers as ``mcm_sorted_layers`` where they scanned, None
+    where they scattered (``telemetry``)."""
 
     def body(carry):
         mr, mc, active, total = carry
@@ -404,10 +408,9 @@ def mcm_loop(n: int, b: int, mate_row, mate_col, parents_fn):
     mate_row, mate_col, _, layers = jax.lax.while_loop(
         cond, body, (mate_row, mate_col, active0, jnp.zeros((b,), jnp.int32))
     )
-    # the per-layer parent choice here runs the flat scatter reductions,
-    # not the sorted-segment scan of ``single._mcm_bfs``
-    return mate_row, mate_col, {"mcm_bfs_layers": layers,
-                                "mcm_sorted_layers": None}
+    return mate_row, mate_col, {
+        "mcm_bfs_layers": layers,
+        "mcm_sorted_layers": layers if sorted_scan else None}
 
 
 @functools.partial(jax.jit, static_argnames=("n",))

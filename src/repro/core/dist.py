@@ -36,6 +36,7 @@ from repro.sparse.csr import max_row_nnz, window_depth
 from repro.sparse.ops import (
     batched_searchsorted_in_window,
     batched_segment_argmax_tie,
+    batched_sorted_segment_max_with_payload,
     lex_searchsorted,
     searchsorted_in_window,
     segment_argmax_tie,
@@ -722,6 +723,9 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
     all_axes = tuple(spec.row_axes) + (spec.col_axis,)
     if window_steps is None:
         window_steps = _search_depth(cap)
+    # MCM's sorted-segment scan levels, from the same measured depth as
+    # ``single._scan_levels``: a block's row holds at most min(cap, bc)
+    scan_levels = min(window_steps, window_depth(min(cap, bc))) - 1
     # bucket slots one device's two all-to-alls carry each AWAC round (its
     # own bucket included), and the bytes of a slot: i, j and w
     round_slots = b * (pc * cap1 + pr * cap2)
@@ -740,6 +744,10 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
         bptr = jax.vmap(
             lambda r: jnp.searchsorted(r, targets, side="left"))(brow
         ).astype(jnp.int32)
+        # this device's global rows, clipped to n (never visited, no edges)
+        grows = jnp.broadcast_to(
+            jnp.clip(adev * br + jnp.arange(br, dtype=jnp.int32), 0, n)[None],
+            (b, br))
 
         def gather_n(x, axis):
             """all_gather [B, k] along ``axis`` -> replicated [B, n]
@@ -769,18 +777,20 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
 
         # ---- MCM phase: per-row BFS parents from 2D blocks ----
         def mcm_parents(frontier, visited):
-            elig = (brow < n) & jnp.take_along_axis(frontier, bcol, axis=1) \
-                & (~jnp.take_along_axis(visited, brow, axis=1))
-            li = jnp.where(elig, brow - adev * br, br).astype(jnp.int32)
-            score = jnp.where(elig, bval, NEG)
-            Rg, Ridx = batched_segment_argmax_tie(score, bcol, li, br + 1)
-            sel = jnp.clip(Ridx[:, :br], 0)
-            has = Ridx[:, :br] >= 0
-            rc_loc = jnp.where(
-                has, jnp.take_along_axis(bcol, sel, axis=1), n
-            ).astype(jnp.int32)
+            # each local row's edges are one run [bptr[r], bptr[r + 1]),
+            # sorted by column, so the smallest column among the heaviest
+            # is the scatter reductions' tie-break; a visited row keeps its
+            # parent, so it is dropped after the scan, not edge by edge
+            score = jnp.where(
+                (brow < n) & jnp.take_along_axis(frontier, bcol, axis=1),
+                bval, NEG)
+            Rg, best = batched_sorted_segment_max_with_payload(
+                score, bcol, brow, bptr, scan_levels)
+            new = (best >= 0) & ~jnp.take_along_axis(visited, grows, axis=1)
+            Rg = jnp.where(new, Rg, NEG)
+            rc_loc = jnp.where(new, best, n).astype(jnp.int32)
             # a row's edges live in ONE grid row, spread over grid columns
-            G = jax.lax.all_gather(Rg[:, :br], col_axis)
+            G = jax.lax.all_gather(Rg, col_axis)
             C = jax.lax.all_gather(rc_loc, col_axis)
             g0, c0, _ = _lex_pick(G, C, [], jnp.int32(n))
             pval = gather_n(g0, row_axes)
@@ -789,22 +799,17 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
 
         # ---- dual build: u, v from the mates (windowed block lookup) ----
         def uv_state(mate_row, mate_col):
-            gi = jnp.broadcast_to(
-                (adev * br + jnp.arange(br, dtype=jnp.int32))[None, :],
-                (b, br))
-            gis = jnp.clip(gi, 0, n)
-            q = jnp.take_along_axis(mate_col, gis, axis=1)
+            q = jnp.take_along_axis(mate_col, grows, axis=1)
             pos, found = batched_searchsorted_in_window(
                 bcol, q, bptr[:, :br], bptr[:, 1:], n_steps=window_steps)
             w = jnp.where(
-                found & (gi < n),
+                found & (grows < n),
                 jnp.take_along_axis(bval, jnp.clip(pos, 0, cap - 1), axis=1),
                 0.0)
             bix = jnp.arange(b, dtype=jnp.int32)[:, None]
             # each matched edge (i, mate_col[i]) lives in exactly one block,
             # so the psum replicates the one found weight (plus exact zeros)
-            uu = jnp.zeros((b, n + 1), jnp.float32).at[
-                bix, jnp.where(gi < n, gis, n)].set(w)
+            uu = jnp.zeros((b, n + 1), jnp.float32).at[bix, grows].set(w)
             u = jax.lax.psum(uu, all_axes).at[:, n].set(0.0)
             v = jnp.zeros((b, n + 1), jnp.float32).at[:, :n].set(
                 jnp.where(mate_row[:, :n] < n,
@@ -911,7 +916,8 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
             state0 = MatchState(*state_args)
         else:
             mr, mc, greedy_counts = batch.greedy_loop(n, b, greedy_propose)
-            mr, mc, mcm_counts = batch.mcm_loop(n, b, mr, mc, mcm_parents)
+            mr, mc, mcm_counts = batch.mcm_loop(n, b, mr, mc, mcm_parents,
+                                                sorted_scan=True)
             counters = {**greedy_counts, **mcm_counts}
             state0 = uv_state(mr, mc)
         state, iters, (aux, moved), awac_counts = batch.awac_loop(

@@ -22,9 +22,10 @@ Counters (summed over a batch's instances):
   greedy_matched   pairs matched when greedy ends
   mcm_bfs_layers   BFS layers over all MCM phases
   mcm_sorted_layers  BFS layers whose parent choice ran the sorted-segment
-                   scan (``single._mcm_bfs``): all of them on the
-                   single-instance route, None on the batched and grid
-                   engines, whose reductions scatter
+                   scan: all of them on the single-instance route
+                   (``single._mcm_bfs``) and on the grid engine (over each
+                   device's edge block), None on the batched engine, whose
+                   reduction scatters, and so on every warm solve
   awac_augmented   4-cycles augmented over all AWAC rounds (None where the
                    persistent Pallas loop ran: it does not count them)
   h2d_bytes        bytes of the host arrays handed to the engines' jitted

@@ -139,6 +139,18 @@ def sorted_segment_max_with_payload(values, payload, segment_ids, segment_ptr,
     return seg_max, jnp.where(seg_max == NEG, -1, payload[last])
 
 
+def batched_sorted_segment_max_with_payload(values, payload, segment_ids,
+                                            segment_ptr, levels: int):
+    """``sorted_segment_max_with_payload`` per instance: ``values``,
+    ``payload`` and ``segment_ids`` are [B, m], each instance's ids never
+    decreasing, and ``segment_ptr`` [B, k + 1] holds each instance's own
+    CSR pointer. ``levels`` must cover the longest segment of any instance.
+    Returns (seg_max [B, k], seg_payload [B, k])."""
+    return jax.vmap(functools.partial(sorted_segment_max_with_payload,
+                                      levels=levels))(
+        values, payload, segment_ids, segment_ptr)
+
+
 def segment_argmax_tie(values, tie, segment_ids, num_segments):
     """Per-segment argmax with an explicit tie-break key (smallest ``tie``
     wins; a second tie falls back to smallest index). Returns

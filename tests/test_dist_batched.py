@@ -17,7 +17,7 @@ raises instead of silently truncating edges.
 import numpy as np
 import pytest
 
-from _subproc import run_with_devices
+from _subproc import REPO, run_with_devices
 
 HEADER = r"""
 import numpy as np, jax, jax.numpy as jnp
@@ -71,6 +71,89 @@ def test_dist_batched_bit_identical(mesh_shape, backends):
     script = MESH_SCRIPT.format(mesh_shape=mesh_shape, backends=backends)
     out = run_with_devices(script, 8)
     assert "OK" in out
+
+
+# --------------------------------------------------------------------------
+# the grid MCM's sorted-segment parent scan against the plain reference
+# --------------------------------------------------------------------------
+
+GRIDS = ("1x1", "1x2", "2x1", "2x2", "pod 2x2x2")
+
+SCAN_SCRIPT = HEADER + r"""
+import json, sys
+sys.path.insert(0, {repo!r})
+from chipbench import gen, reference
+from repro.core import api, telemetry
+
+n = 256
+# the benchmark's degree-16 instance; a row joined to every column, so one
+# block row holds bc entries, heaviest at its first columns, so the scan
+# needs all its levels; and a
+# sparse antigreedy instance: three sizes, one batch
+u = gen.generate(n, 16.0, "uniform", seed=2**31 + 5, capacity=n * 16)
+dense = np.r_[u.col[:u.nnz], np.arange(n)]
+drow = np.r_[u.row[:u.nnz], np.full(n, 7)]
+dval = np.r_[u.val[:u.nnz], 1.0 - np.arange(n) / (2 * n)]
+_, first = np.unique(drow.astype(np.int64) * n + dense, return_index=True)
+ag = graph.generate(n, avg_degree=3.0, kind="antigreedy", seed=4)
+gs = [graph.from_coo(u.row[:u.nnz], u.col[:u.nnz], u.val[:u.nnz], n),
+      graph.from_coo(drow[first], dense[first], dval[first], n), ag]
+assert len({{g.nnz for g in gs}}) == 3
+row, col, val = batch.stack_graphs(gs)
+stB, itB = batch.awpm_batched(row, col, val, n)
+refs = [reference.solve(np.asarray(g.row), np.asarray(g.col),
+                        np.asarray(g.val), n) for g in gs]
+meshes = {{
+    "1x1": GridSpec(make_mesh((1, 1))), "1x2": GridSpec(make_mesh((1, 2))),
+    "2x1": GridSpec(make_mesh((2, 1))), "2x2": GridSpec(make_mesh((2, 2))),
+    "pod 2x2x2": GridSpec(jax.make_mesh(
+        (2, 2, 2), ("pod", "data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 3),
+        ("pod", "data"), "model")}}
+out = {{}}
+for name, spec in meshes.items():
+    got = api.solve(api.MatchingProblem.stack(gs), api.SolveOptions(grid=spec))
+    counters = telemetry.recent(1)[0]["counters"]
+    mr = np.asarray(got.mate_row)
+    out[name] = {{
+        "batch_mates_off": int((mr != np.asarray(stB.mate_row)).sum()),
+        "batch_rounds": [np.asarray(got.awac_iters).tolist(),
+                         np.asarray(itB).tolist()],
+        "ref_mates_off": [int((mr[k, :n] != r.mate_row[:n]).sum())
+                          for k, r in enumerate(refs)],
+        "ref_rounds": [[int(got.awac_iters[k]), r.awac_rounds]
+                       for k, r in enumerate(refs)],
+        "ref_weight_bits": [
+            [np.asarray(got.weight[k], np.float32).tobytes().hex(),
+             np.float32(r.weight).tobytes().hex()]
+            for k, r in enumerate(refs)],
+        "layers": [counters["mcm_bfs_layers"], counters["mcm_sorted_layers"]]}}
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def grid_scan_runs():
+    import json
+
+    out = run_with_devices(SCAN_SCRIPT.format(repo=str(REPO)), 8)
+    return json.loads(out.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("grid", GRIDS)
+def test_grid_scan_equals_reference_and_batch(grid_scan_runs, grid):
+    """On every grid shape, a batch of three instances of different sizes
+    (one with a row of degree n) gives ``chipbench/reference.py``'s mates,
+    AWAC rounds and weight bits, and ``core.batch``'s mates and rounds;
+    every BFS layer picked its parents with the sorted-segment scan."""
+    case = grid_scan_runs[grid]
+    assert case["batch_mates_off"] == 0, case
+    assert case["batch_rounds"][0] == case["batch_rounds"][1], case
+    assert case["ref_mates_off"] == [0, 0, 0], case
+    for got, want in case["ref_rounds"] + case["ref_weight_bits"]:
+        assert got == want, case
+    layers, sorted_layers = case["layers"]
+    assert layers > 0 and sorted_layers == layers, case
 
 
 # --------------------------------------------------------------------------

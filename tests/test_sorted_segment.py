@@ -18,6 +18,8 @@ from chipbench import reference
 from repro.core import batch, graph, single
 from repro.sparse.csr import row_ptr_from_sorted
 from repro.sparse.ops import (
+    batched_segment_argmax_tie,
+    batched_sorted_segment_max_with_payload,
     segment_max_with_payload,
     sorted_segment_max_with_payload,
 )
@@ -108,6 +110,89 @@ def test_sorted_segment_matches_two_pass(name):
         _, short_p = sorted_segment_max_with_payload(v, p, ids, ptr,
                                                      levels - 1)
         assert int(short_p[1]) != int(want_p[1]) == 3
+
+
+def _block(lengths, rng, r0, bc, tie_values=False):
+    """One instance's edge block as the grid partition leaves it: rows
+    ``r0, r0 + 1, ...`` of ``lengths`` entries, each row's columns distinct
+    and sorted, in ``[0, bc)``; (row, col, val) numpy arrays."""
+    rows, cols = [], []
+    for k, length in enumerate(lengths):
+        rows.append(np.full(length, r0 + k))
+        cols.append(np.sort(rng.choice(bc, length, replace=False)))
+    m = int(sum(lengths))
+    val = rng.integers(0, 3, m) / 2 if tie_values else rng.random(m)
+    return (np.concatenate(rows + [np.zeros(0)]).astype(np.int32),
+            np.concatenate(cols + [np.zeros(0)]).astype(np.int32),
+            val.astype(np.float32))
+
+
+def _block_case(name):
+    """Per-instance row lengths, whether values tie, and the rows whose
+    values are all -inf."""
+    rng = np.random.default_rng(11)
+    if name == "value ties, other columns":
+        return [list(rng.integers(1, 12, 30))], True, ()
+    if name == "rows all -inf":
+        return [[3, 5, 1, 4, 6]], False, (0, 2, 4)
+    if name == "empty rows and blocks":
+        return [[0, 4, 0, 0, 2, 0], [0] * 6, [1, 0, 3, 0, 0, 2]], False, ()
+    if name == "a row of 2^L":
+        return [[3, 16, 5]], False, ()
+    if name == "a row of 2^L + 1":
+        return [[3, 17, 5]], False, ()
+    if name == "instances of other sizes":
+        return [list(rng.integers(0, 9, 20)), list(rng.integers(5, 33, 20)),
+                [1] * 20], True, (3, 7)
+    raise ValueError(name)
+
+
+BLOCK_CASES = ["value ties, other columns", "rows all -inf",
+               "empty rows and blocks", "a row of 2^L", "a row of 2^L + 1",
+               "instances of other sizes"]
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["f32", "x64"])
+@pytest.mark.parametrize("name", BLOCK_CASES)
+def test_batched_scan_over_blocks_matches_the_scatter(name, x64):
+    """The grid's parent pick: the batched scan over [B, cap] row-sorted
+    blocks with a padding tail (rows == n) gives
+    ``batched_segment_argmax_tie``'s value bits, and the column of its
+    edge, with and without the packed 64-bit keys."""
+    per_instance, ties, dead = _block_case(name)
+    rng = np.random.default_rng(5)
+    n, bc, r0 = 200, 40, 64  # this block's rows are [r0, r0 + k)
+    k = len(per_instance[0])
+    blocks = [_block(lengths, rng, r0, bc, ties) for lengths in per_instance]
+    cap = max(r.size for r, _, _ in blocks) + 5  # a padding tail in each
+    b = len(blocks)
+    row = np.full((b, cap), n, np.int32)
+    col = np.full((b, cap), n, np.int32)
+    val = np.zeros((b, cap), np.float32)
+    for i, (r, c, v) in enumerate(blocks):
+        row[i, :r.size], col[i, :r.size], val[i, :r.size] = r, c, v
+    score = np.where(row < n, val, -np.inf).astype(np.float32)
+    score[np.isin(row, [r0 + d for d in dead])] = -np.inf
+    ptr = np.stack([np.searchsorted(r, r0 + np.arange(k + 1))
+                    for r in row]).astype(np.int32)
+    levels = _levels([length for lengths in per_instance
+                      for length in lengths])
+    li = np.where(row < n, row - r0, k).astype(np.int32)
+    with jax.enable_x64(x64):
+        want_v, want_i = batched_segment_argmax_tie(
+            jnp.asarray(score), jnp.asarray(col), jnp.asarray(li), k + 1)
+        got_v, got_c = jax.jit(batched_sorted_segment_max_with_payload,
+                               static_argnames="levels")(
+            jnp.asarray(score), jnp.asarray(col), jnp.asarray(row),
+            jnp.asarray(ptr), levels)
+    want_v, want_i = np.asarray(want_v)[:, :k], np.asarray(want_i)[:, :k]
+    want_c = np.where(want_i >= 0, np.take_along_axis(
+        col, np.clip(want_i, 0, None), axis=1), -1)
+    np.testing.assert_array_equal(np.asarray(got_v).view(np.uint32),
+                                  want_v.view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(got_c), want_c)
+    assert (want_c >= 0).any()
+    assert (want_c < 0).any() == bool(dead or name.startswith("empty"))
 
 
 def _skewed(n=1200, seed=3):

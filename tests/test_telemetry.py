@@ -170,11 +170,13 @@ print(json.dumps([out, scopes]))
 @pytest.mark.parametrize("start", ["cold", "warm"])
 def test_counters_identical_on_every_route(start):
     """Cold: the single route, the B = 1 batched route and the 2x2 grid;
-    warm: the warm route and the grid. A warm solve runs no greedy. Only
-    the cold single route runs the sorted-segment scan
-    (``mcm_sorted_layers``); the warm route, lifted to the batched engine,
-    and the others report None. Only the grid keeps the exchange's
-    counters (``a2a_*``); every other route reports None there."""
+    warm: the warm route and the grid. A warm solve runs no greedy. The
+    cold single route and the cold grid pick BFS parents with the
+    sorted-segment scan, so their ``mcm_sorted_layers`` equal their
+    ``mcm_bfs_layers``; the batched route and every warm solve, whose MCM
+    runs on the batched engine, report None. Only the grid keeps the
+    exchange's counters (``a2a_*``); every other route reports None
+    there."""
     import json
 
     out, scopes = json.loads(run_with_devices(
@@ -185,7 +187,9 @@ def test_counters_identical_on_every_route(start):
     for route, (counters, mates, sorted_layers, a2a) in out.items():
         assert counters == first_counters, (route, counters, first_counters)
         assert mates == first_mates, route
-        assert sorted_layers is None, route
+        scanned = start == "cold" and route == "grid 2x2"
+        assert sorted_layers == (counters["mcm_bfs_layers"] if scanned
+                                 else None), (route, sorted_layers)
         if route == "grid 2x2":
             slots, filled, nbytes = a2a
             assert 0 < filled <= slots and nbytes == 13 * slots, a2a

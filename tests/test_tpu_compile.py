@@ -137,10 +137,12 @@ def test_batched_route_compiles(one_chip, phase):
     _lower_phase(batch, phase, n, edges, row_ptr, st).compile()
 
 
-def test_grid_engine_compiles_on_2x2(topo):
+@pytest.fixture(scope="module")
+def grid_2x2(topo):
     """The whole distributed-batched engine (greedy, MCM and AWAC in one
-    ``shard_map``) over the four described chips. Its program is large,
-    so the instance is small: the compile takes about half a minute."""
+    ``shard_map``) compiled over the four described chips, and its block
+    capacity. Its program is large, so the instance is small: the compile
+    takes about half a minute."""
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
                 axis_types=(AxisType.Auto,) * 2)
     spec = dist.GridSpec(mesh)
@@ -154,8 +156,39 @@ def test_grid_engine_compiles_on_2x2(topo):
     args = [jax.ShapeDtypeStruct((2, 2, b, cap), dt, sharding=blocks)
             for dt in (jnp.int32, jnp.int32, jnp.float32)]
     with jax.enable_x64(True):
-        compiled = run.lower(*args).compile()
-    assert "all-gather" in compiled.as_text()
+        return cap, run.lower(*args).compile().as_text()
+
+
+def test_grid_engine_compiles_on_2x2(grid_2x2):
+    _, text = grid_2x2
+    assert "all-gather" in text
+
+
+def _dims(shape):
+    """Every dimension of an HLO shape (a tuple's too)."""
+    return [int(d) for dims in re.findall(r"\[([\d,]*)\]", shape)
+            for d in dims.split(",") if d]
+
+
+def test_grid_mcm_bfs_layer_scatters_no_edge_block(grid_2x2):
+    """The grid's BFS layer, compiled for the described 2x2, reads the edge
+    block once, in the ``frontier[bcol]`` gather, and holds no scatter with
+    an operand that spans the block: each row's parent comes from the
+    sorted-segment scan over its run of edges, not from a scatter of the
+    block's edges into the rows."""
+    cap, text = grid_2x2
+    layer = [ln for ln in text.splitlines() if "mcm_bfs_layer/" in ln]
+    edge_gathers = [ln for ln in layer if " gather(" in ln
+                    and cap in _dims(ln.split("=")[1].split(" gather(")[0])]
+    assert len(edge_gathers) == 1, edge_gathers
+    assert edge_gathers[0].split("=")[1].strip().startswith("pred["), \
+        edge_gathers[0]  # the frontier's flags, read at each edge's column
+    scatters = [ln for ln in layer if " scatter(" in ln]
+    assert scatters  # bfs_commit's frontier, over the rows
+    for ln in scatters:
+        operands = re.search(r" scatter\(([^)]*)\)", ln).group(1)
+        for name in re.findall(r"%([\w.\-]+)", operands):
+            assert cap not in _dims(_shape_of(text, name)), (name, ln)
 
 
 def _sweep_args(one_chip, n=2048, cap=16_384):
