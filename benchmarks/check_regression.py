@@ -17,18 +17,8 @@ Two checks, per row name present in BOTH files:
 Rows that exist only on one side are reported but never fail the gate
 (benches grow new rows every PR). Exits 1 on any violation.
 
-A third, optional check gates the dispatch table that ``backend="auto"``
-consults (``repro.kernels.dispatch``): given the committed table and a
-freshly measured one (``--dispatch BASELINE FRESH``), every committed
-winner must (a) actually be the argmin of its own committed measurements —
-a table whose winner contradicts its numbers is corrupt — and (b) still be
-within ``--dispatch-factor`` (default 1.2x) of the freshly measured best
-for that shape class. A committed winner losing by more than that means
-``auto`` is demonstrably mis-routing and the table must be regenerated.
-
 Usage: python benchmarks/check_regression.py \
-           --baseline /tmp/baseline.json --fresh BENCH_kernels.json \
-           [--dispatch /tmp/dispatch.baseline.json BENCH_dispatch.json]
+           --baseline /tmp/baseline.json --fresh BENCH_kernels.json
 """
 from __future__ import annotations
 
@@ -71,44 +61,6 @@ def check(baseline: dict[str, dict], fresh: dict[str, dict],
     return failures
 
 
-def _dispatch_entries(path: str) -> dict[str, dict]:
-    with open(path) as f:
-        return json.load(f).get("entries", {})
-
-
-def check_dispatch(committed: dict[str, dict], fresh: dict[str, dict],
-                   factor: float) -> list[str]:
-    """Gate the committed auto-dispatch table against fresh measurements."""
-    failures = []
-    for key in sorted(committed):
-        entry = committed[key]
-        winner, us = entry.get("winner"), entry.get("us_per_iter", {})
-        if not winner or not us:
-            failures.append(f"dispatch {key}: malformed entry {entry!r}")
-            continue
-        if winner not in us:
-            failures.append(
-                f"dispatch {key}: winner {winner!r} has no measurement")
-            continue
-        best = min(us, key=us.get)
-        if us[winner] > factor * us[best]:
-            failures.append(
-                f"dispatch {key}: committed winner {winner!r} "
-                f"({us[winner]:.1f}us) contradicts its own measurements "
-                f"(best {best!r} at {us[best]:.1f}us, > {factor}x)")
-        f_us = fresh.get(key, {}).get("us_per_iter", {})
-        if not f_us or winner not in f_us:
-            continue  # class not re-measured here: report-only
-        f_best = min(f_us, key=f_us.get)
-        if f_us[winner] > factor * f_us[f_best]:
-            failures.append(
-                f"dispatch {key}: 'auto' would route to {winner!r} "
-                f"({f_us[winner]:.1f}us fresh) but {f_best!r} measures "
-                f"{f_us[f_best]:.1f}us (> {factor}x loss) — regenerate "
-                f"BENCH_dispatch.json")
-    return failures
-
-
 def _derived_value(derived: str, key: str) -> float | None:
     m = re.search(rf"\b{re.escape(key)}=([0-9.eE+-]+)", derived or "")
     try:
@@ -119,8 +71,8 @@ def _derived_value(derived: str, key: str) -> float | None:
 
 def check_serving(rows: dict[str, dict], min_rps: float, max_p99_us: float,
                   min_speedup: float) -> list[str]:
-    """Absolute SLO gates for a fresh ``BENCH_serving.json`` (fourth,
-    optional check — ``--serving FRESH``).
+    """Absolute SLO gates for a fresh ``BENCH_serving.json`` (optional
+    check — ``--serving FRESH``).
 
     Unlike the baseline-relative throughput gate, serving is gated on
     *absolute* floors/ceilings: the stream runs on a simulated arrival
@@ -234,11 +186,6 @@ def main() -> None:
     ap.add_argument("--baseline")
     ap.add_argument("--fresh")
     ap.add_argument("--factor", type=float, default=2.5)
-    ap.add_argument("--dispatch", nargs=2,
-                    metavar=("COMMITTED", "FRESH"),
-                    help="gate the committed dispatch table against a "
-                         "freshly measured one")
-    ap.add_argument("--dispatch-factor", type=float, default=1.2)
     ap.add_argument("--serving", metavar="FRESH",
                     help="gate a fresh BENCH_serving.json on absolute "
                          "SLOs (throughput floor, p99 ceiling, warm-start "
@@ -270,10 +217,6 @@ def main() -> None:
             print(f"# new rows (not gated yet): {only_f}")
         n = len(set(baseline) & set(fresh))
         failures += check(baseline, fresh, args.factor)
-    if args.dispatch:
-        failures += check_dispatch(
-            _dispatch_entries(args.dispatch[0]),
-            _dispatch_entries(args.dispatch[1]), args.dispatch_factor)
     if args.serving:
         failures += check_serving(
             _rows(args.serving), args.serving_min_rps,
@@ -289,9 +232,6 @@ def main() -> None:
     if args.baseline:
         parts.append(f"{n} shared rows within {args.factor}x, all "
                      f"correctness flags True")
-    if args.dispatch:
-        parts.append(f"dispatch winners within {args.dispatch_factor}x of "
-                     f"fresh best")
     if args.serving:
         parts.append(f"serving SLOs met (>= {args.serving_min_rps:.0f} rps, "
                      f"p99 <= {args.serving_max_p99_us:.0f}us, warm >= "

@@ -23,7 +23,7 @@ def main():
                     choices=list(graph.SUITE_KINDS))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--backend", default="auto",
-                    choices=["auto", "reference", "xla", "pallas"])
+                    choices=["auto", "reference", "xla"])
     args = ap.parse_args()
 
     g = graph.generate(args.n, avg_degree=args.degree, kind=args.kind,

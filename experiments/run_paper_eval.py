@@ -1,5 +1,5 @@
 """Paper-eval runner (DESIGN.md §8): sweep the Matrix Market fixtures +
-synthetic-suite matrices across backends (reference / xla / pallas) and
+synthetic-suite matrices across backends (reference / xla) and
 device grids (1x1 / 2x2) through the ``solve()``/``Matcher`` facade;
 certify every result with LP-dual potentials; fail loudly on unsound
 bounds, backend disagreement, or imperfect matchings.
@@ -9,7 +9,7 @@ Outputs: ``results/paper_eval.md`` (per-matrix table) and
 ``benchmarks/check_regression.py``).
 
   PYTHONPATH=src python experiments/run_paper_eval.py [--quick]
-      [--backends reference,xla,pallas] [--grids 1x1,2x2]
+      [--backends reference,xla] [--grids 1x1,2x2]
       [--suite-count 10] [--suite-n 96] [--transform log2_scaled_nonneg]
       [--no-persist] [--download [--instances Freescale1,rajat31]
       [--cache-dir DIR]]
@@ -55,9 +55,7 @@ def main() -> None:
                     help="CI smoke: fixtures + 3 small synthetic matrices, "
                          "reference/xla, 1x1 grid")
     ap.add_argument("--backends", default=None,
-                    help="comma list from reference,xla,pallas "
-                         "(default: those the platform compiles, all three "
-                         "on the CPU; --quick: reference,xla)")
+                    help="comma list from reference,xla (default: both)")
     ap.add_argument("--grids", default=None,
                     help="comma list of PRxPC grids (default: 1x1,2x2; "
                          "--quick: 1x1). On the CPU, grids beyond the "
@@ -110,8 +108,7 @@ def main() -> None:
         print(f"# suitesparse: {len(fetched)} instance(s) cached under "
               f"{suitesparse.cache_dir(args.cache_dir)}")
     backends = (args.backends.split(",") if args.backends
-                else (["reference", "xla"] if args.quick
-                      else list(paper_eval.default_backends())))
+                else list(paper_eval.LOCAL_BACKENDS))
     grids = _parse_grids(args.grids) if args.grids \
         else ([(1, 1)] if args.quick else list(paper_eval.GRIDS))
 

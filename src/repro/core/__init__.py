@@ -10,7 +10,6 @@ the ``Dist*`` driver zoo) remain as bit-identical deprecation shims.
 from repro.core import api, batch, dual, graph, pivot, preflight, ref, single
 from repro.core.api import (
     BACKENDS,
-    BackendUnavailableError,
     ON_INVALID,
     Matcher,
     MatchingProblem,
@@ -35,7 +34,6 @@ __all__ = [
     "ref",
     "single",
     "BACKENDS",
-    "BackendUnavailableError",
     "MIN_GAIN",
     "ON_INVALID",
     "DualCertificate",

@@ -58,26 +58,21 @@ from repro.core import preflight as _preflight
 from repro.core import single as _single
 from repro.core import telemetry
 from repro.core.constants import MIN_GAIN
-from repro.core.single import BackendUnavailableError, MatchState
+from repro.core.single import MatchState
 from repro.sparse.csr import window_depth
 
-#: every backend ``SolveOptions`` accepts. "auto" resolves locally via the
-#: MEASURED dispatch table (``repro.kernels.dispatch``, refreshed by the
-#: kernels bench job) — the winner for this platform and shape class, not a
-#: hard-coded platform rule; on a grid it resolves to the "fused"
-#: exchange+windowed-join engine. "reference" is the seed bit-exactness
-#: oracle. "pallas_persistent" runs the whole AWAC loop in one persistent
-#: kernel and is local-only; "fused" is distributed-only; "xla"/"pallas"
-#: with a grid require the 1x1 grid (the block is the whole instance).
-BACKENDS = ("auto", "reference", "xla", "pallas", "pallas_persistent",
-            "fused")
+#: every backend ``SolveOptions`` accepts. "auto" is the fused XLA sweep
+#: ("xla") locally and the "fused" exchange+windowed-join engine on a grid.
+#: "reference" is the seed bit-exactness oracle. "fused" is
+#: distributed-only; "xla" with a grid requires the 1x1 grid (the block is
+#: the whole instance).
+BACKENDS = ("auto", "reference", "xla", "fused")
 
 #: ``SolveOptions.on_invalid`` policies (see ``core.preflight``).
 ON_INVALID = ("raise", "sanitize", "degrade")
 
 __all__ = [
     "BACKENDS",
-    "BackendUnavailableError",
     "MIN_GAIN",
     "ON_INVALID",
     "MatchResult",
@@ -270,8 +265,6 @@ class SolveOptions:
     max_iter      AWAC round budget (>= 0; 0 skips refinement entirely).
     min_gain      minimum 4-cycle gain to count as augmenting (paper eps).
     backend       one of :data:`BACKENDS`; "auto" picks per dispatch target.
-                  A Pallas backend that the platform cannot compile
-                  raises :class:`BackendUnavailableError`.
     window_steps  windowed-search depth override (None = measured/derived;
                   extra depth never changes results, and an undersized
                   override is clamped up to the measured need).
@@ -316,9 +309,6 @@ class SolveOptions:
             raise ValueError(
                 f"unknown backend {self.backend!r}: expected one of "
                 f"{BACKENDS}")
-        # a backend the platform's compiler refuses fails here, typed,
-        # not deep inside lowering
-        _single.check_backend_available(self.backend)
         if self.on_invalid not in ON_INVALID:
             raise ValueError(
                 f"unknown on_invalid policy {self.on_invalid!r}: expected "
@@ -355,13 +345,7 @@ class SolveOptions:
         if self.grid is not None:
             spec = _as_grid_spec(self.grid)
             object.__setattr__(self, "grid", spec)
-            if self.backend == "pallas_persistent":
-                raise ValueError(
-                    "backend 'pallas_persistent' runs the whole AWAC loop "
-                    "inside one local kernel and cannot participate in the "
-                    "distributed exchange — drop SolveOptions.grid")
-            if self.backend in ("xla", "pallas") and \
-                    (spec.pr, spec.pc) != (1, 1):
+            if self.backend == "xla" and (spec.pr, spec.pc) != (1, 1):
                 raise ValueError(
                     f"backend {self.backend!r} routes through the local "
                     f"fused sweep and needs the 1x1 grid, got "
@@ -399,20 +383,12 @@ class ExecutionInfo:
     """How a solve actually executed — the honest dispatch record.
 
     ``backend``: the concrete engine that ran (never "auto").
-    ``source``: how it was chosen — "explicit" (user-pinned), "table" (the
-    measured dispatch table, ``repro.kernels.dispatch``), "heuristic"
-    (the fused XLA sweep, when the table has no winner that can run on
-    this platform), or "grid-default" (the distributed route's fused
-    engine).
-    ``ran_interpreted``: whether the solve ran a Pallas kernel in the
-    Pallas interpreter (True only for a Pallas backend on a platform
-    without a compiled lowering). Interpreter execution is
-    correctness-grade, never performance-grade.
+    ``source``: how it was chosen — "explicit" (user-pinned) or "default"
+    (``"auto"``: the fused XLA sweep locally, the fused engine on a grid).
     """
 
     backend: str
     source: str
-    ran_interpreted: bool = False
     #: True when the solve was seeded from previous mates (warm-start
     #: rematching) instead of running greedy + MCM from scratch.
     warm_started: bool = False
@@ -431,7 +407,7 @@ class MatchResult:
     preflight found issues worth surfacing — always present on a degraded
     (``perfect=False``) result, never on a clean solve. ``execution`` is an
     :class:`ExecutionInfo` recording the engine that actually ran (resolved
-    backend, dispatch source, interpreter flag). Both ride as pytree
+    backend and how it was chosen). Both ride as pytree
     aux_data (static).
     """
 
@@ -545,26 +521,14 @@ def _finish(problem: MatchingProblem, result: MatchResult,
     return dataclasses.replace(result, diagnosis=report)
 
 
-def _execution_info(problem: MatchingProblem,
-                    options: SolveOptions) -> ExecutionInfo:
-    """Resolve what will actually run, for ``MatchResult.execution``.
-
-    Mirrors the engines' own resolution (``core.single.resolve_backend`` /
-    the kernel wrappers' ``interpret=None`` auto-detection) so the record
-    matches the dispatch decision made inside the solve."""
-    if options.grid is not None:
-        backend = options._dist_backend()
-        source = "explicit" if options.backend != "auto" else "grid-default"
-    else:
-        backend, source = _single.resolve_backend_source(
-            options.backend, n=problem.n, batch=problem.batch_size)
-    interpreted = False
-    if backend.startswith("pallas"):
-        from repro.kernels.backend import resolve_execution
-
-        interpreted = resolve_execution(None).interpret
-    return ExecutionInfo(backend=backend, source=source,
-                         ran_interpreted=interpreted)
+def _execution_info(options: SolveOptions) -> ExecutionInfo:
+    """What will actually run, for ``MatchResult.execution``: the pinned
+    backend, or for ``"auto"`` the fused XLA sweep locally and the fused
+    engine on a grid."""
+    backend = _single.resolve_backend(options.backend) \
+        if options.grid is None else options._dist_backend()
+    return ExecutionInfo(backend=backend, source="explicit"
+                         if options.backend != "auto" else "default")
 
 
 def _warm_mates(problem: MatchingProblem, warm_start):
@@ -665,7 +629,7 @@ def _solve(problem: MatchingProblem, options: SolveOptions,
         result = _result(state, iters, problem.n, batched=False)
     result = dataclasses.replace(
         result, execution=dataclasses.replace(
-            _execution_info(problem, options), warm_started=warm is not None))
+            _execution_info(options), warm_started=warm is not None))
     return _finish(problem, result, options, report)
 
 

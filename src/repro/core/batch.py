@@ -22,8 +22,7 @@ shared depth preserves per-instance bit-identity.
 Backends mirror ``core.single.awac``:
   reference — vmapped seed oracle (global lex search per instance)
   xla       — flat batched fused sweep: one offset-segment reduction and one
-              offset-window search over all B * cap edges (production CPU)
-  pallas    — batch-grid ``awac_sweep`` kernel, batch as leading grid axis
+              offset-window search over all B * cap edges
 """
 from __future__ import annotations
 
@@ -472,14 +471,6 @@ def _cwinners_batched(backend, row, col, val, row_ptr, n, state, min_gain,
     if backend == "xla":
         return awac_cwinners_fused_batched(row, col, val, row_ptr, n, state,
                                            min_gain, window_steps)
-    if backend == "pallas":
-        # Local import: core must stay importable without the kernel package.
-        from repro.kernels.cycle_gain.ops import awac_sweep_winners_batched
-
-        return awac_sweep_winners_batched(
-            row, col, val, row_ptr, state.mate_row, state.mate_col, state.u,
-            state.v, min_gain, n=n, window_steps=window_steps,
-        )
     raise ValueError(f"unknown AWAC backend {backend!r}")
 
 
@@ -515,7 +506,7 @@ def awac_loop(n: int, state: MatchState, max_iter: int, min_gain,
 
     def body(carry):
         state, iters, active, aux, augmented = carry
-        with jax.named_scope("awac_sweep"):
+        with jax.named_scope("awac_winners"):
             Cgain, Ci, Cw1, Cw2, a = cwinners_fn(state)
         new_state, n_surv = select(Cgain, Ci, Cw1, Cw2, *state)
         augmented = augmented + jnp.where(active, n_surv, 0).astype(jnp.int32)
@@ -580,26 +571,12 @@ def awac_batched(row, col, val, n: int, state: MatchState,
 
     Same backend contract as ``single.awac``; every instance's result and
     iteration count are bit-identical to its own single-instance run."""
-    backend = single.resolve_backend(backend, n=n, batch=row.shape[0])
+    backend = single.resolve_backend(backend)
     with telemetry.span("repro.window_depth"):
         window_steps = _resolve_window_steps_batched(row, n, window_steps)
     if row_ptr is None:
         row_ptr = telemetry.call("repro.row_ptr", batched_row_ptr_from_sorted,
                                  row, n)
-    if backend == "pallas_persistent":
-        # Local import: core must stay importable without the kernel package.
-        from repro.kernels.cycle_gain.ops import awac_persistent_loop_batched
-
-        b = row.shape[0]
-        go0 = is_perfect_batched(state, n) if degrade_infeasible \
-            else jnp.ones((b,), bool)
-        mr, mc, u, v, iters = telemetry.call(
-            "repro.awac", awac_persistent_loop_batched,
-            row, col, val, row_ptr, state.mate_row, state.mate_col, state.u,
-            state.v, min_gain, go0, n=n, window_steps=window_steps,
-            max_iter=max_iter)
-        telemetry.count({"awac_augmented": None})
-        return MatchState(mr, mc, u, v), iters
     # Same x64 trace context as single.awac: with "xla", Step C runs as one
     # packed-key uint64 segment_max over the whole batch (no-op under an
     # outer jit, see single._x64_scope).

@@ -665,7 +665,7 @@ def safe_a2a_caps(cap_blk: int, pr: int, pc: int) -> tuple[int, int]:
     return cap_blk, pc * cap_blk
 
 
-DIST_BATCHED_BACKENDS = ("fused", "reference", "xla", "pallas")
+DIST_BATCHED_BACKENDS = ("fused", "reference", "xla")
 
 
 @functools.lru_cache(maxsize=None)
@@ -691,10 +691,9 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
     backend: "fused" (default) joins Step A/B candidates against the local
     block through the batched CSR-windowed search (the fused sweep
     substrate, sparse/ops.py); "reference" keeps the per-block global lex
-    search. On the 1x1 grid, "xla"/"pallas" route Steps A+B+C through
-    ``core.batch``'s fused batched sweep directly (incl. the batch-grid
-    Pallas kernel) — the block is the whole instance, so no exchange is
-    needed.
+    search. On the 1x1 grid, "xla" routes Steps A+B+C through
+    ``core.batch``'s fused batched sweep directly — the block is the whole
+    instance, so no exchange is needed.
 
     Returns jitted ``run(brow, bcol, bval) -> (MatchState, iters [B],
     dropped, counters)`` over [Pr, Pc, B, cap] blocks, the counters those
@@ -710,11 +709,10 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
     pr, pc = spec.pr, spec.pc
     if backend not in DIST_BATCHED_BACKENDS:
         raise ValueError(f"unknown dist AWAC backend {backend!r}")
-    if backend in ("xla", "pallas") and (pr, pc) != (1, 1):
+    if backend == "xla" and (pr, pc) != (1, 1):
         raise ValueError(
             f"backend {backend!r} routes through core.batch's local sweep "
             f"and needs the 1x1 grid, got {pr}x{pc}")
-    single.check_backend_available(backend)
     br = -(-n // pr)
     bc = -(-n // pc)
     cap1, cap2 = a2a_caps
@@ -895,9 +893,9 @@ def _make_awpm_dist_batched(spec: GridSpec, n: int, b: int, cap: int,
                                (f1 + f2).astype(jnp.int64)])
             return Cgain, Ci, Cw1, Cw2, (aux, moved)
 
-        if backend in ("xla", "pallas"):
+        if backend == "xla":
             # 1x1 grid: the block IS the instance — Steps A+B+C run through
-            # the batched fused sweep (incl. the batch-grid Pallas kernel).
+            # the batched fused sweep.
             rptr = jax.vmap(lambda r: jnp.searchsorted(
                 r, jnp.arange(n + 2, dtype=r.dtype), side="left"))(brow
             ).astype(jnp.int32)

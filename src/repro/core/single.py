@@ -406,8 +406,7 @@ def awac_candidates(row, col, val, n, state: MatchState, min_gain):
     """Steps A+B on the full edge list: per-edge completion lookup + gain.
 
     Reference path: global log2(m)-round lex search per edge. The fused sweep
-    (``awac_cwinners_fused`` / the Pallas ``awac_sweep`` kernel) replaces this
-    with a CSR-windowed lookup and never materializes these O(m) arrays."""
+    (``awac_cwinners_fused``) replaces this with a CSR-windowed lookup."""
     mate_row, mate_col, u, v = state
     qr = mate_row[col]  # m_j for each edge's column
     qc = mate_col[row]  # m_i for each edge's row
@@ -422,7 +421,7 @@ def awac_cwinners(row, col, val, n, state: MatchState, min_gain):
     """Step C on the full edge list: per-column winner (gain, i, w1, w2).
 
     Reference (seed) implementation — kept as the bit-exactness oracle for
-    the fused backends and still used via ``backend="reference"``."""
+    the fused sweep and still used via ``backend="reference"``."""
     cand, gain, w2 = awac_candidates(row, col, val, n, state, min_gain)
     cap = row.shape[0]
     eidx = jnp.arange(cap, dtype=jnp.int32)
@@ -482,78 +481,13 @@ def _cwinners(backend, row, col, val, row_ptr, n, state, min_gain,
     if backend == "xla":
         return awac_cwinners_fused(row, col, val, row_ptr, n, state, min_gain,
                                    window_steps)
-    if backend == "pallas":
-        # Local import: core must stay importable without the kernel package.
-        from repro.kernels.cycle_gain.ops import awac_sweep_winners
-
-        return awac_sweep_winners(
-            row, col, val, row_ptr, state.mate_row, state.mate_col, state.u,
-            state.v, min_gain, n=n, window_steps=window_steps,
-        )
     raise ValueError(f"unknown AWAC backend {backend!r}")
 
 
-#: Local Pallas backends that the TPU compiler (Mosaic) refuses today, with
-#: the refusal it gives for v5e. On TPU, ``"auto"`` never resolves to them
-#: and an explicit request raises :class:`BackendUnavailableError` before
-#: anything is lowered.
-NO_TPU_LOWERING = {
-    "pallas": ("the awac_sweep kernel's 1-D gathers have no Mosaic lowering "
-               "(NotImplementedError: Only 2D gather is supported)"),
-    "pallas_persistent": ("the persistent kernel's tile loop has no Mosaic "
-                          "lowering (Unimplemented primitive in Pallas TPU "
-                          "lowering: dynamic_slice)"),
-}
-
-
-class BackendUnavailableError(ValueError):
-    """The requested AWAC backend cannot be compiled on this platform."""
-
-
-def backend_available(backend: str) -> bool:
-    """False when ``backend`` has no compiled lowering on the current
-    platform."""
-    return backend not in NO_TPU_LOWERING or jax.default_backend() != "tpu"
-
-
-def check_backend_available(backend: str) -> None:
-    """Raise :class:`BackendUnavailableError` naming the missing lowering
-    when ``backend`` cannot be compiled on the current platform."""
-    if not backend_available(backend):
-        raise BackendUnavailableError(
-            f"backend {backend!r} cannot run on {jax.default_backend()}: "
-            f"{NO_TPU_LOWERING[backend]}. Use backend='auto' or 'xla'.")
-
-
-def resolve_backend_source(backend: str, n: int | None = None,
-                           batch: int | None = None) -> tuple[str, str]:
-    """Resolve ``backend`` to (concrete local AWAC backend, source).
-
-    An explicit backend is checked against the platform and returned with
-    source "explicit". ``"auto"`` takes the measured dispatch table's winner
-    for this platform and shape class (``BENCH_dispatch.json``, see
-    ``repro.kernels.dispatch``; source "table") when that winner can run
-    here, and otherwise the fused XLA sweep (source "heuristic") — the one
-    route that compiles on every platform.
-    """
-    if backend != "auto":
-        check_backend_available(backend)
-        return backend, "explicit"
-    try:
-        from repro.kernels.dispatch import choose_backend
-
-        winner = choose_backend(n=n, batch=batch)
-    except ImportError:  # core stays usable without the kernel package
-        winner = None
-    if winner is not None and backend_available(winner):
-        return winner, "table"
-    return "xla", "heuristic"
-
-
-def resolve_backend(backend: str, n: int | None = None,
-                    batch: int | None = None) -> str:
-    """The backend half of :func:`resolve_backend_source`."""
-    return resolve_backend_source(backend, n=n, batch=batch)[0]
+def resolve_backend(backend: str) -> str:
+    """The local AWAC sweep ``backend`` names: ``"auto"`` is the fused XLA
+    sweep, any other name stands for itself."""
+    return "xla" if backend == "auto" else backend
 
 
 def _x64_scope(row):
@@ -597,7 +531,7 @@ def _awac_rounds(row, col, val, row_ptr, n: int, state: MatchState,
 
     def body(carry):
         state, it, _, augmented = carry
-        with jax.named_scope("awac_sweep"):
+        with jax.named_scope("awac_winners"):
             Cgain, Ci, Cw1, Cw2 = _cwinners(
                 backend, row, col, val, row_ptr, n, state, min_gain,
                 window_steps
@@ -645,12 +579,9 @@ def awac(row, col, val, n: int, state: MatchState, max_iter: int = 1000,
          degrade_infeasible: bool = False):
     """Full AWAC loop. Returns (state, iters).
 
-    backend: "auto" (measured dispatch-table winner, see
-    ``resolve_backend``) | "xla" (fused sweep) | "pallas" (fused
-    ``awac_sweep`` kernel, one launch per iteration) | "pallas_persistent"
-    (whole loop in one persistent kernel) | "reference" (seed jnp path, the
-    bit-exactness oracle). All backends produce identical results and
-    iteration counts.
+    backend: "auto" (= "xla") | "xla" (fused sweep) | "reference" (seed
+    jnp path, the bit-exactness oracle). Both produce identical results
+    and iteration counts.
     """
     with telemetry.span("repro.window_depth"):
         window_steps = _resolve_window_steps(row, n, window_steps)
@@ -664,19 +595,7 @@ def _awac(row, col, val, row_ptr, n: int, state: MatchState, max_iter: int,
           min_gain, backend: str, window_steps: int,
           degrade_infeasible: bool):
     """:func:`awac` with ``row_ptr`` built and ``window_steps`` resolved."""
-    backend = resolve_backend(backend, n=n)
-    if backend == "pallas_persistent":
-        # Local import: core must stay importable without the kernel package.
-        from repro.kernels.cycle_gain.ops import awac_persistent_loop
-
-        go0 = is_perfect(state, n) if degrade_infeasible else jnp.array(True)
-        mr, mc, u, v, iters = telemetry.call(
-            "repro.awac", awac_persistent_loop,
-            row, col, val, row_ptr, state.mate_row, state.mate_col, state.u,
-            state.v, min_gain, go0, n=n, window_steps=window_steps,
-            max_iter=max_iter)
-        telemetry.count({"awac_augmented": None})
-        return MatchState(mr, mc, u, v), iters
+    backend = resolve_backend(backend)
     # With "xla", an x64-enabled trace context lets Step C run as ONE
     # packed-key uint64 segment_max (see repro.sparse.ops); inputs/outputs
     # stay f32/i32. Under an outer jit the scope is a no-op (see _x64_scope).

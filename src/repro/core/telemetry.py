@@ -26,8 +26,7 @@ Counters (summed over a batch's instances):
                    (``single._mcm_bfs``) and on the grid engine (over each
                    device's edge block), None on the batched engine, whose
                    reduction scatters, and so on every warm solve
-  awac_augmented   4-cycles augmented over all AWAC rounds (None where the
-                   persistent Pallas loop ran: it does not count them)
+  awac_augmented   4-cycles augmented over all AWAC rounds
   h2d_bytes        bytes of the host arrays handed to the engines' jitted
                    calls, each of which copies them to the device
   a2a_slots        bucket slots the grid engine's AWAC all-to-alls carried,

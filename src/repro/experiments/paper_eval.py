@@ -8,10 +8,10 @@ module is that experiment's harness:
     loaded through ``repro.data.mtx`` with a per-fixture weight transform)
     plus instances of the synthetic ``core.graph.matrix_suite``;
   - sweep: every case through the ``solve()``/``Matcher`` facade across
-    local backends (reference / xla / pallas, those the platform compiles)
-    and device grids (in process where the attached devices hold them; on
-    the CPU, larger grids in a subprocess with fake host devices, the
-    tests/_subproc.py constraint; on an accelerator, skipped);
+    local backends (reference / xla) and device grids (in process where
+    the attached devices hold them; on the CPU, larger grids in a
+    subprocess with fake host devices, the tests/_subproc.py constraint;
+    on an accelerator, skipped);
   - evidence per (case, engine): matching weight, AWAC iterations, wall
     time, the LP-dual certified ratio bound (``core.dual``), the exact
     ratio when the ``ref.exact_mwpm`` oracle is tractable, and
@@ -57,16 +57,8 @@ FIXTURE_TRANSFORMS = {
 
 # engines swept: local backends + device grids (grid rows use the Matcher
 # plan()-once path with backend "auto")
-LOCAL_BACKENDS = ("reference", "xla", "pallas")
+LOCAL_BACKENDS = ("reference", "xla")
 GRIDS = ((1, 1), (2, 2))
-
-
-def default_backends() -> tuple[str, ...]:
-    """The :data:`LOCAL_BACKENDS` the current platform can compile (on a
-    TPU, the Pallas sweep has no Mosaic lowering)."""
-    from repro.core.single import backend_available
-
-    return tuple(b for b in LOCAL_BACKENDS if backend_available(b))
 
 _ROW_MARK = "PAPER_EVAL_ROW "  # subprocess -> parent protocol
 
@@ -89,7 +81,7 @@ class EvalRecord:
     name: str
     source: str
     transform: str
-    engine: str  # "reference" | "xla" | "pallas" | "grid1x1" | "grid2x2"
+    engine: str  # "reference" | "xla" | "grid1x1" | "grid2x2"
     n: int
     nnz: int
     weight: float
@@ -376,10 +368,10 @@ def run_eval(spec: dict | None = None,
              oracle_max_n: int = 256) -> list[EvalRecord]:
     """The full sweep: every case in ``spec`` (see :func:`_cases_from_spec`;
     default :data:`DEFAULT_SPEC`) x (local ``backends``, default
-    :func:`default_backends`, + device ``grids``). Raises on any soundness
+    :data:`LOCAL_BACKENDS`, + device ``grids``). Raises on any soundness
     / bit-identity / perfection violation (see module docstring)."""
     if backends is None:
-        backends = default_backends()
+        backends = LOCAL_BACKENDS
     spec = dict(DEFAULT_SPEC if spec is None else spec)
     cases = _cases_from_spec(spec)
     aux_by_name = {c.name: _case_aux(c, oracle_max_n) for c in cases}

@@ -5,15 +5,10 @@ platform's Pallas compiler wherever one exists (Mosaic on TPU, Triton on
 GPU), and fall back to the Pallas interpreter only where none does (CPU CI,
 unit tests).
 
-A compiler existing does not mean a given kernel lowers. On TPU, Mosaic
-refuses both AWAC sweep kernels (``awac_sweep`` and the persistent loop);
-``core.single.NO_TPU_LOWERING`` records the refusals, and the solve path
-routes around them instead of staging them here.
-
 Every resolution returns/records an :class:`ExecutionMode` carrying the
 explicit ``interpret`` flag, and the kernel wrappers thread it into bench
-rows and ``MatchResult.execution`` so an interpreter timing can never
-masquerade as a compiled-kernel timing.
+rows so an interpreter timing can never masquerade as a compiled-kernel
+timing.
 """
 from __future__ import annotations
 
@@ -39,10 +34,6 @@ class ExecutionMode:
     interpret: bool
     platform: str
     forced: bool = False
-
-    @property
-    def ran_interpreted(self) -> bool:
-        return self.interpret
 
     def describe(self) -> str:
         """Bench-row annotation, e.g. ``interpret=True``."""

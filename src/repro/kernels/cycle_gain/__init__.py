@@ -1,17 +1,8 @@
-from repro.kernels.cycle_gain.awac_sweep import awac_sweep, awac_sweep_batched
 from repro.kernels.cycle_gain.cycle_gain import cycle_gain
-from repro.kernels.cycle_gain.ops import (
-    awac_sweep_winners,
-    awac_sweep_winners_batched,
-    cycle_gain_padded,
-)
+from repro.kernels.cycle_gain.ops import cycle_gain_padded
 from repro.kernels.cycle_gain.ref import cycle_gain_ref
 
 __all__ = [
-    "awac_sweep",
-    "awac_sweep_batched",
-    "awac_sweep_winners",
-    "awac_sweep_winners_batched",
     "cycle_gain",
     "cycle_gain_padded",
     "cycle_gain_ref",

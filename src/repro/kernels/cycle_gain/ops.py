@@ -1,5 +1,5 @@
-"""jit'd public wrappers for the cycle_gain kernel package (padding +
-dispatch): the dense tile kernel and the fused sparse AWAC sweep."""
+"""jit'd public wrappers for the cycle_gain kernel package: padding +
+dispatch of the dense tile kernel."""
 from __future__ import annotations
 
 import functools
@@ -8,41 +8,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.backend import resolve_interpret
-from repro.kernels.cycle_gain.awac_sweep import awac_sweep_batched
 from repro.kernels.cycle_gain.cycle_gain import cycle_gain
-from repro.kernels.cycle_gain.persistent import awac_persistent_batched
 from repro.kernels.cycle_gain.ref import cycle_gain_ref
-
-NEG = float("-inf")
 
 
 def _round_up(x, m):
     return (x + m - 1) // m * m
-
-
-def _edge_tile(cap: int, n: int, te: int | None) -> tuple[int, int]:
-    """(te, padded cap) for the sweep kernels: an explicit ``te`` keeps the
-    seed padding rule; ``te=None`` asks the VMEM roofline planner
-    (``roofline.analysis.plan_edge_tile``), which clamps undersized
-    instances UP to one legal 128-lane tile instead of failing the kernels'
-    divisibility check."""
-    if te is not None:
-        return te, max(_round_up(cap, te), te)
-    from repro.roofline.analysis import plan_edge_tile
-
-    plan = plan_edge_tile(cap, n)
-    return plan.te, plan.cap_padded
-
-
-def _pad_edges(row, col, val, n, capp):
-    b, cap = row.shape
-    if capp == cap:
-        return row, col, val
-    pad = capp - cap
-    row = jnp.concatenate([row, jnp.full((b, pad), n, row.dtype)], axis=1)
-    col = jnp.concatenate([col, jnp.full((b, pad), n, col.dtype)], axis=1)
-    val = jnp.concatenate([val, jnp.zeros((b, pad), val.dtype)], axis=1)
-    return row, col, val
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tn", "use_kernel", "interpret"))
@@ -65,107 +36,6 @@ def cycle_gain_padded(a, a2, u, v, *, tm: int = 256, tn: int = 256,
     g, r = cycle_gain(a_p, a2_p, u_p, v_p, tm=tm, tn=tn,
                       interpret=resolve_interpret(interpret))
     return g[:n], r[:n]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n", "te", "window_steps", "interpret")
-)
-def awac_sweep_winners(row, col, val, row_ptr, mate_row, mate_col, u, v,
-                       min_gain, *, n: int, window_steps: int,
-                       te: int | None = None,
-                       interpret: bool | None = None):
-    """Fused Steps A+B+C via the ``awac_sweep`` Pallas kernel.
-
-    Same contract as ``repro.core.single.awac_cwinners``: returns
-    (Cgain [n], Ci [n] (sentinel n if no candidate), Cw1 [n], Cw2 [n]),
-    bit-identical to the jnp reference. A B=1 slice of
-    ``awac_sweep_winners_batched`` (one padding/sentinel path to maintain).
-    """
-    Cgain, Ci, Cw1, Cw2 = awac_sweep_winners_batched(
-        row[None], col[None], val[None], row_ptr[None], mate_row[None],
-        mate_col[None], u[None], v[None], min_gain,
-        n=n, window_steps=window_steps, te=te, interpret=interpret,
-    )
-    return Cgain[0], Ci[0], Cw1[0], Cw2[0]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n", "te", "window_steps", "interpret")
-)
-def awac_sweep_winners_batched(row, col, val, row_ptr, mate_row, mate_col, u,
-                               v, min_gain, *, n: int, window_steps: int,
-                               te: int | None = None,
-                               interpret: bool | None = None):
-    """Batched fused Steps A+B+C via the batch-grid ``awac_sweep_batched``
-    kernel. All operands carry a leading batch axis; returns per-instance
-    (Cgain [B, n], Ci [B, n] (sentinel n if no candidate), Cw1, Cw2),
-    bit-identical to running ``awac_sweep_winners`` per instance.
-    ``te=None`` sizes the edge tile from the VMEM roofline (small instances
-    clamp up to one 128-lane tile instead of failing)."""
-    b, cap = row.shape
-    te, capp = _edge_tile(cap, n, te)
-    row, col, val = _pad_edges(row, col, val, n, capp)
-    Cgain, Crow, Cw1, Cw2 = awac_sweep_batched(
-        row, col, val, row_ptr, mate_row, mate_col, u, v, min_gain,
-        n=n, te=te, window_steps=window_steps,
-        interpret=resolve_interpret(interpret),
-    )
-    Cgain, Crow, Cw1, Cw2 = (Cgain[:, :n], Crow[:, :n], Cw1[:, :n],
-                             Cw2[:, :n])
-    has = Cgain > NEG
-    Ci = jnp.where(has, Crow, n).astype(jnp.int32)
-    return Cgain, Ci, jnp.where(has, Cw1, 0.0), jnp.where(has, Cw2, 0.0)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n", "te", "window_steps", "max_iter", "interpret"))
-def awac_persistent_loop(row, col, val, row_ptr, mate_row, mate_col, u, v,
-                         min_gain, go0, *, n: int, window_steps: int,
-                         max_iter: int, te: int | None = None,
-                         interpret: bool | None = None):
-    """Whole AWAC loop (sweeps + select/augment + convergence) in one
-    persistent ``pallas_call`` — the ``backend="pallas_persistent"`` engine
-    behind ``core.single.awac``.
-
-    Same state contract as ``core.single._awac_loop``: returns
-    (mate_row, mate_col, u, v, iters) with state over [n + 1] and the
-    scalar iteration count, bit-identical to driving per-sweep kernels from
-    the host while_loop. ``go0`` is the round-0 gate (False = skip the loop,
-    the degrade-infeasible short-circuit)."""
-    mr, mc, uu, vv, it = awac_persistent_loop_batched(
-        row[None], col[None], val[None], row_ptr[None], mate_row[None],
-        mate_col[None], u[None], v[None], min_gain,
-        jnp.asarray(go0).reshape(1), n=n, window_steps=window_steps,
-        max_iter=max_iter, te=te, interpret=interpret)
-    return mr[0], mc[0], uu[0], vv[0], it[0]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n", "te", "window_steps", "max_iter", "interpret"))
-def awac_persistent_loop_batched(row, col, val, row_ptr, mate_row, mate_col,
-                                 u, v, min_gain, go0, *, n: int,
-                                 window_steps: int, max_iter: int,
-                                 te: int | None = None,
-                                 interpret: bool | None = None):
-    """Batched persistent AWAC loop: one kernel launch runs every
-    instance's full iteration loop (grid step b = instance b's loop; each
-    converges independently via its own in-kernel while condition).
-
-    Returns (mate_row, mate_col, u, v [B, n + 1], iters [B]); per instance
-    bit-identical — state and iteration counts — to the host-driven
-    while_loop over ``awac_sweep_winners_batched``. ``te=None`` sizes the
-    edge tile from the VMEM roofline."""
-    b, cap = row.shape
-    te, capp = _edge_tile(cap, n, te)
-    row, col, val = _pad_edges(row, col, val, n, capp)
-    mr, mc, uu, vv, it = awac_persistent_batched(
-        row, col, val, row_ptr, mate_row, mate_col, u, v, min_gain, go0,
-        n=n, te=te, window_steps=window_steps, max_iter=max_iter,
-        interpret=resolve_interpret(interpret))
-    return (mr[:, : n + 1], mc[:, : n + 1], uu[:, : n + 1], vv[:, : n + 1],
-            it)
 
 
 def swap_gains(affinity, assign_expert, tok_affinity, *, use_kernel=True,

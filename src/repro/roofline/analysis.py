@@ -21,7 +21,6 @@ V5E = {
     "peak_flops": 197e12,  # bf16 per chip
     "hbm_bw": 819e9,  # B/s
     "ici_bw": 50e9,  # B/s per link
-    "vmem_bytes": 16 * 2**20,  # per-core VMEM (Pallas working-set budget)
 }
 
 _DTYPE_BYTES = {
@@ -146,68 +145,6 @@ def roofline_terms(flops_per_device: float, bytes_per_device: float,
     dom = max(terms, key=terms.get)
     return Roofline(ct, mt, lt, dom, flops_per_device, bytes_per_device,
                     coll_bytes_per_device)
-
-
-_LANE = 128  # TPU lane width: every Pallas last-dim tile is a multiple
-
-
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
-
-
-@dataclasses.dataclass(frozen=True)
-class EdgeTilePlan:
-    """VMEM-budgeted edge tiling for the AWAC sweep kernels.
-
-    ``te``: edge-tile width (multiple of the 128 lane width).
-    ``cap_padded``: edge capacity after padding (a multiple of ``te``, so
-    the kernel grid / in-kernel tile loop divides evenly).
-    ``resident_bytes``: the per-instance VMEM-resident working set (full
-    col/val copies + O(n) state + winner blocks).
-    ``stream_bytes``: the double-buffered per-tile edge streams.
-    ``fits``: resident + stream within the budget (False only for
-    instances too large for single-core VMEM residency — the kernel still
-    runs, but spills; callers may prefer the XLA backend then).
-    """
-
-    te: int
-    cap_padded: int
-    resident_bytes: int
-    stream_bytes: int
-    fits: bool
-
-
-def plan_edge_tile(cap: int, n: int, *, target_te: int = 512,
-                   vmem_limit: int | None = None) -> EdgeTilePlan:
-    """Pick the AWAC sweep edge-tile width from the VMEM roofline.
-
-    Mirrors PR 4's clamp-up policy for ``window_steps``: undersized inputs
-    are padded UP to a legal tile (``cap < 128`` becomes one 128-lane tile)
-    rather than rejected, and the tile shrinks below ``target_te`` only when
-    the double-buffered streams would not fit next to the resident working
-    set (resident col/val dominates, so this matters only near the VMEM
-    roof). All returned sizes satisfy the kernels' divisibility contract:
-    ``te % 128 == 0`` and ``cap_padded % te == 0``.
-    """
-    if cap < 1 or n < 1:
-        raise ValueError(
-            f"roofline.plan_edge_tile: need cap >= 1 and n >= 1, got "
-            f"cap={cap}, n={n}")
-    budget = int(V5E["vmem_bytes"] if vmem_limit is None else vmem_limit)
-    nv = _round_up(n + 2, _LANE)
-    np_ = _round_up(n + 1, _LANE)
-    # full col/val copies (i32 + f32) + ptr/mate_row/mate_col (i32) +
-    # u/v (f32) + the four winner blocks
-    cap_lane = max(_round_up(cap, _LANE), _LANE)
-    resident = cap_lane * 8 + 5 * nv * 4 + 4 * np_ * 4
-    te = max(min(_round_up(target_te, _LANE), cap_lane), _LANE)
-    while te > _LANE and resident + 2 * 3 * te * 4 > budget:
-        te -= _LANE
-    cap_padded = max(_round_up(cap, te), te)
-    stream = 2 * 3 * te * 4
-    return EdgeTilePlan(te=te, cap_padded=cap_padded,
-                        resident_bytes=resident, stream_bytes=stream,
-                        fits=resident + stream <= budget)
 
 
 def useful_flops(arch: str, shape_name: str, mode: str, cfg, shape) -> float:

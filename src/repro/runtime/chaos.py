@@ -337,12 +337,12 @@ def run_chaos_matrix(pr: int = 2, pc: int = 4, n: int = 48,
 
     # ---- backend failures: transient (retry, same rung) and persistent
     # ---- (degrade down the chain), plus a dying grid engine ----
-    with failing_backend("xla", "pallas", fail_times=1):
+    with failing_backend("xla", fail_times=1):
         rr = resilient_solve(p)
         record("backend_transient", "survive",
                _bit_identical(rr.result, ref) and not rr.report.degraded,
                rr.report.summary())
-    with failing_backend("xla", "pallas"):
+    with failing_backend("xla"):
         rr = resilient_solve(p)
         ok = _bit_identical(rr.result, ref) \
             and rr.report.backend_used == "local reference"

@@ -2,7 +2,7 @@
 and post-solve verification over ``repro.core.api``.
 
 The serving tier the ROADMAP targets cannot call ``solve()`` naked: a
-Pallas kernel can miscompile on a new toolchain, a device can drop out
+compiler can refuse a program on a new toolchain, a device can drop out
 mid-exchange, a transient XLA runtime error can kill an otherwise healthy
 request, and a silently wrong matching poisons the downstream
 factorization it exists to stabilize. ``resilient_solve`` wraps the facade
@@ -14,9 +14,8 @@ with the standard serving guards:
     (``TransientFault``, XLA runtime errors) on the same rung;
   - **backend degradation chain** — the requested engine first, then each
     strictly-more-conservative rung: a grid engine falls back to the local
-    engines, ``pallas -> xla -> reference``, leaving out rungs the
-    platform cannot compile (no Pallas rung on TPU); the rung that finally
-    served the request is recorded, never hidden;
+    engines, ``xla -> reference``; the rung that finally served the
+    request is recorded, never hidden;
   - **device-loss recovery** — with a ``runtime.elastic.FleetState``, a
     dead device folds the grid down to ``surviving_mesh`` before the grid
     rung runs (and to the local chain when no full row survived);
@@ -261,11 +260,9 @@ def verify_result(problem: _api.MatchingProblem, result: _api.MatchResult,
 # --------------------------------------------------------------------------
 
 
-#: most-aggressive to most-conservative: the persistent whole-loop kernel
-#: degrades to the per-sweep kernel, then the fused XLA sweep, then the seed
-#: reference path. Rungs the platform cannot compile are left out
-#: (``core.single.backend_available``).
-_LOCAL_CHAIN = ("pallas_persistent", "pallas", "xla", "reference")
+#: most-aggressive to most-conservative: the fused XLA sweep degrades to the
+#: seed reference path.
+_LOCAL_CHAIN = ("xla", "reference")
 
 
 def _local_options(options: _api.SolveOptions,
@@ -299,20 +296,18 @@ def _build_rungs(options: _api.SolveOptions, fleet=None):
             rungs.append((
                 f"grid {grid.pr}x{grid.pc} ({options._dist_backend()})",
                 options))
-    start = _single.resolve_backend(options.backend) \
-        if options.backend == "auto" else options.backend
+    start = _single.resolve_backend(options.backend)
     if start not in _LOCAL_CHAIN:  # "fused" is grid-only: full local chain
         start = _LOCAL_CHAIN[0]
     for b in _LOCAL_CHAIN[_LOCAL_CHAIN.index(start):]:
-        if _single.backend_available(b):
-            rungs.append((f"local {b}", _local_options(options, b)))
+        rungs.append((f"local {b}", _local_options(options, b)))
     return rungs
 
 
 def _is_compiler_refusal(exc: BaseException) -> bool:
     """A lowering or compiler that refuses the program: the same rung will
-    refuse it again, however often it is retried. Mosaic raises both of
-    its known refusals of the sweep kernels as ``NotImplementedError``."""
+    refuse it again, however often it is retried. Mosaic raises its
+    refusals as ``NotImplementedError``."""
     return isinstance(exc, NotImplementedError)
 
 

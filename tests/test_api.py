@@ -36,7 +36,7 @@ from repro.core import (
     solve,
 )
 
-LOCAL_BACKENDS = ("reference", "xla", "pallas")
+LOCAL_BACKENDS = ("reference", "xla")
 
 
 def _graphs(n=32):
@@ -252,6 +252,9 @@ def test_legacy_dist_factories_warn():
 
 @pytest.mark.parametrize("kwargs,match", [
     (dict(backend="bogus"), "unknown backend"),
+    # the Pallas sweeps were deleted: their names are no backends any more
+    (dict(backend="pallas"), "unknown backend"),
+    (dict(backend="pallas_persistent"), "unknown backend"),
     (dict(backend="fused"), "requires SolveOptions.grid"),
     (dict(max_iter=-1), "max_iter"),
     (dict(max_iter=1.5), "max_iter"),
@@ -284,8 +287,40 @@ def test_options_bad_grid_shape():
     mesh = jax.make_mesh((1, 1), ("x", "y"))
     with pytest.raises(ValueError, match="bad grid shape"):
         SolveOptions(grid=mesh)
-    # (xla/pallas off the 1x1 grid is also rejected eagerly — covered in
-    # the multi-device subprocess script, which can build a 2x2 mesh)
+    # (xla off the 1x1 grid is also rejected eagerly — covered in the
+    # multi-device subprocess script, which can build a 2x2 mesh)
+
+
+# --------------------------------------------------------------------------
+# MatchResult.execution: the engine that ran and how it was chosen
+# --------------------------------------------------------------------------
+
+
+def test_solve_records_explicit_execution():
+    p = MatchingProblem.from_graph(_graphs()[0])
+    for backend in LOCAL_BACKENDS:
+        res = solve(p, SolveOptions(backend=backend))
+        assert res.execution.backend == backend
+        assert res.execution.source == "explicit"
+        assert res.execution.warm_started is False
+    res = solve(p, SolveOptions(backend="fused", grid=_mesh_1x1()))
+    assert (res.execution.backend, res.execution.source) == \
+        ("fused", "explicit")
+
+
+def test_solve_records_default_source():
+    # "auto" is the fused XLA sweep locally, single and batched, and the
+    # fused engine on a grid
+    gs = _graphs()
+    for problem in (MatchingProblem.from_graph(gs[0]),
+                    MatchingProblem.stack(gs)):
+        res = solve(problem)
+        assert (res.execution.backend, res.execution.source) == \
+            ("xla", "default")
+    res = solve(MatchingProblem.from_graph(gs[0]),
+                SolveOptions(grid=_mesh_1x1()))
+    assert (res.execution.backend, res.execution.source) == \
+        ("fused", "default")
 
 
 def test_dist_cap_too_small_refuses_to_truncate():

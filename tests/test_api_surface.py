@@ -17,7 +17,6 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 # then the submodules and the graph utilities that predate the facade
 EXPECTED_EXPORTS = [
     "BACKENDS",
-    "BackendUnavailableError",
     "BipartiteGraph",
     "DualCertificate",
     "InfeasibleProblemError",
@@ -49,7 +48,6 @@ EXPECTED_EXPORTS = [
 
 EXPECTED_API_EXPORTS = [
     "BACKENDS",
-    "BackendUnavailableError",
     "MIN_GAIN",
     "MatchResult",
     "Matcher",

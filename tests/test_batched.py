@@ -7,6 +7,11 @@ batches whose instances converge at very different speeds (one in 1 AWAC
 iteration, another in ~20), where the per-instance masks must freeze early
 finishers bit-exactly while the rest keep iterating.
 
+The "grid1x1" case runs the same batches through ``solve`` on the grid
+engine over an in-process 1x1 mesh (its "fused" sweep) and holds them to
+the single-instance "xla" engine: mates and AWAC rounds (the facade's
+result carries no duals).
+
 Also covers degenerate inputs (n=1, all-ties weights, single dense row) and
 error paths (unknown backend, explicit window_steps / precomputed row_ptr
 overrides) that previously had zero coverage.
@@ -15,20 +20,55 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import batch, graph, single
+from repro.core import MatchingProblem, SolveOptions, batch, graph, single
 from repro.core.single import MatchState
 from repro.sparse.csr import batched_row_ptr_from_sorted, row_ptr_from_sorted
 
-BACKENDS = ["reference", "xla", "pallas"]
+BACKENDS = ["reference", "xla", "grid1x1"]
+
+
+def _local(backend):
+    """The single-instance engine a batched route is held to."""
+    return "xla" if backend == "grid1x1" else backend
+
+
+def _awpm_batched(row, col, val, n, backend):
+    """``batch.awpm_batched`` on a local backend; for "grid1x1", ``solve``
+    on the grid engine over a 1x1 mesh. Returns (state, iters [B]); the
+    grid's state carries mates only (duals None)."""
+    if backend != "grid1x1":
+        return batch.awpm_batched(row, col, val, n, backend=backend)
+    return _solve_grid(MatchingProblem(row=row, col=col, val=val, n=n))
+
+
+def _awac_batched(row, col, val, n, state, backend):
+    """``batch.awac_batched`` from ``state``; for "grid1x1", the grid
+    engine warm-started from the state's mates (their duals are rebuilt
+    from the mates, as ``state`` holds them)."""
+    if backend != "grid1x1":
+        return batch.awac_batched(row, col, val, n, state, backend=backend)
+    return _solve_grid(MatchingProblem(row=row, col=col, val=val, n=n),
+                       warm_start=(state.mate_row, state.mate_col))
+
+
+def _solve_grid(problem, warm_start=None):
+    from repro.core import solve
+    from repro.core.dist import make_mesh
+
+    res = solve(problem, SolveOptions(grid=make_mesh((1, 1))),
+                warm_start=warm_start)
+    assert res.execution.backend == "fused"
+    return MatchState(res.mate_row, res.mate_col, None, None), \
+        res.awac_iters
 
 
 def _assert_instance_identical(stS, itS, stB, itB, i, msg):
     assert int(itS) == int(itB[i]), f"{msg}: iters {int(itS)} != {int(itB[i])}"
     names = ["mate_row", "mate_col", "u", "v"]
-    for nm, a, b in zip(names, stS, (stB.mate_row[i], stB.mate_col[i],
-                                     stB.u[i], stB.v[i])):
-        np.testing.assert_array_equal(np.array(a), np.array(b),
-                                      err_msg=f"{msg}: {nm}")
+    for nm, a, b in zip(names, stS, stB):
+        if b is not None:
+            np.testing.assert_array_equal(np.array(a), np.array(b[i]),
+                                          err_msg=f"{msg}: {nm}")
 
 
 def _heterogeneous_batch(n=48):
@@ -43,10 +83,11 @@ def _heterogeneous_batch(n=48):
 def test_awpm_batched_bit_identical_per_instance(backend):
     n = 48
     gs, (row, col, val) = _heterogeneous_batch(n)
-    stB, itB = batch.awpm_batched(row, col, val, n, backend=backend)
+    stB, itB = _awpm_batched(row, col, val, n, backend)
     assert bool(batch.is_perfect_batched(stB, n).all())
     for i in range(len(gs)):
-        stS, itS = single.awpm(row[i], col[i], val[i], n, backend=backend)
+        stS, itS = single.awpm(row[i], col[i], val[i], n,
+                               backend=_local(backend))
         _assert_instance_identical(stS, itS, stB, itB, i,
                                    f"{backend}/instance{i}")
 
@@ -88,11 +129,11 @@ def test_mixed_convergence_speeds_within_batch(backend):
     stacked = MatchState(*(jnp.stack([a, b]) for a, b in
                            zip(st_slow, st_fast)))
 
-    stB, itB = batch.awac_batched(row, col, val, n, stacked, backend=backend)
+    stB, itB = _awac_batched(row, col, val, n, stacked, backend)
     its = []
     for i, st_i in enumerate((st_slow, st_fast)):
         stS, itS = single.awac(row[i], col[i], val[i], n, st_i,
-                               backend=backend)
+                               backend=_local(backend))
         _assert_instance_identical(stS, itS, stB, itB, i,
                                    f"{backend}/mixed{i}")
         its.append(int(itS))
@@ -109,10 +150,11 @@ def test_degenerate_n1(backend):
     g = graph.from_coo(np.array([0]), np.array([0]),
                        np.array([0.7], np.float32), 1)
     row, col, val = batch.stack_graphs([g, g])
-    stB, itB = batch.awpm_batched(row, col, val, 1, backend=backend)
+    stB, itB = _awpm_batched(row, col, val, 1, backend)
     assert bool(batch.is_perfect_batched(stB, 1).all())
     np.testing.assert_array_equal(np.array(stB.mate_row[:, 0]), [0, 0])
-    stS, itS = single.awpm(row[0], col[0], val[0], 1, backend=backend)
+    stS, itS = single.awpm(row[0], col[0], val[0], 1,
+                           backend=_local(backend))
     _assert_instance_identical(stS, itS, stB, itB, 0, f"{backend}/n1")
 
 
@@ -132,9 +174,10 @@ def test_degenerate_all_ties(backend):
                                  np.full(int(real.sum()), 0.5, np.float32),
                                  n))
     row, col, val = batch.stack_graphs(gs)
-    stB, itB = batch.awpm_batched(row, col, val, n, backend=backend)
+    stB, itB = _awpm_batched(row, col, val, n, backend)
     for i in range(len(gs)):
-        stS, itS = single.awpm(row[i], col[i], val[i], n, backend=backend)
+        stS, itS = single.awpm(row[i], col[i], val[i], n,
+                               backend=_local(backend))
         _assert_instance_identical(stS, itS, stB, itB, i,
                                    f"{backend}/ties{i}")
 
@@ -153,9 +196,10 @@ def test_degenerate_single_dense_row(backend):
     dense_row = graph.from_coo(rows, cols, vals, n)
     sparse = graph.generate(n, avg_degree=3.0, kind="uniform", seed=1)
     row, col, val = batch.stack_graphs([dense_row, sparse])
-    stB, itB = batch.awpm_batched(row, col, val, n, backend=backend)
+    stB, itB = _awpm_batched(row, col, val, n, backend)
     for i in range(2):
-        stS, itS = single.awpm(row[i], col[i], val[i], n, backend=backend)
+        stS, itS = single.awpm(row[i], col[i], val[i], n,
+                               backend=_local(backend))
         _assert_instance_identical(stS, itS, stB, itB, i,
                                    f"{backend}/dense{i}")
 
@@ -183,8 +227,7 @@ def test_unknown_backend_raises():
 def test_resolve_backend_passthrough_and_auto():
     assert single.resolve_backend("reference") == "reference"
     assert single.resolve_backend("xla") == "xla"
-    assert single.resolve_backend("pallas") == "pallas"
-    assert single.resolve_backend("auto") in ("xla", "pallas")
+    assert single.resolve_backend("auto") == "xla"
 
 
 def test_explicit_window_steps_and_row_ptr_overrides():

@@ -11,14 +11,11 @@ import subprocess
 import sys
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _subproc import run_with_devices
-from repro.core import (BackendUnavailableError, MatchingProblem,
-                        SolveOptions, graph, single)
-from repro.kernels import dispatch as kdispatch
+from repro.core import MatchingProblem, SolveOptions, api, graph, single
 from repro.runtime import chaos
 from repro.runtime.resilient import _build_rungs, _classify, resilient_solve
 
@@ -44,7 +41,7 @@ def test_device_phase_refuses_the_cpu():
 def test_oracle_phase(kind, capsys):
     chip_smoke.phase_oracle(n=48, degree=6.0, kinds=(kind,))
     out = capsys.readouterr().out
-    assert "ran_interpreted=False" in out
+    assert "execution=ExecutionInfo(backend='xla', source='default'" in out
     assert f"{kind} n=48" in out and "equal to the CPU run and to the " \
         "AWAC-phase oracle awac_parallel_rule" in out
 
@@ -124,7 +121,7 @@ def test_a_failed_phase_fails_the_run(cache_env, monkeypatch, capsys):
 
 
 # --------------------------------------------------------------------------
-# routing on a TPU: never a Pallas backend Mosaic refuses
+# routing on a TPU: "auto" is the fused XLA sweep
 # --------------------------------------------------------------------------
 
 
@@ -133,52 +130,18 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def test_auto_resolves_to_xla_on_tpu(on_tpu, tmp_path, monkeypatch):
-    assert single.resolve_backend_source("auto", n=4096) == \
-        ("xla", "heuristic")
-    # a measured Pallas winner cannot be taken where it cannot compile
-    p = tmp_path / "t.json"
-    kdispatch.save_table(
-        {"tpu/single_large": {"winner": "pallas_persistent",
-                              "us_per_iter": {"pallas_persistent": 1.0}},
-         "tpu/batched_large": {"winner": "reference",
-                               "us_per_iter": {"reference": 1.0}}}, {}, p)
-    monkeypatch.setenv(kdispatch.TABLE_ENV_VAR, str(p))
-    kdispatch.clear_cache()
-    try:
-        assert single.resolve_backend("auto", n=4096) == "xla"
-        assert single.resolve_backend_source("auto", n=4096, batch=8) == \
-            ("reference", "table")
-    finally:
-        kdispatch.clear_cache()
+def test_auto_resolves_to_xla_on_tpu(on_tpu):
+    assert single.resolve_backend("auto") == "xla"
+    assert api._execution_info(SolveOptions()) == \
+        api.ExecutionInfo(backend="xla", source="default")
 
 
-@pytest.mark.parametrize("backend", ["pallas", "pallas_persistent"])
-def test_explicit_pallas_raises_typed_error_on_tpu(on_tpu, backend):
-    with pytest.raises(BackendUnavailableError, match="Mosaic"):
-        SolveOptions(backend=backend)
-    # the engines refuse before anything is lowered
-    g = graph.generate(16, avg_degree=3.0, seed=0)
-    row, col, val = (jnp.asarray(x) for x in (g.row, g.col, g.val))
-    st = single.empty_state(g.n)
-    with pytest.raises(BackendUnavailableError, match=backend):
-        single.awac(row, col, val, g.n, st, backend=backend)
-
-
-def test_resilient_chain_has_no_pallas_rung_on_tpu(on_tpu):
-    for opts in (SolveOptions(), SolveOptions(backend="xla"),
-                 SolveOptions(backend="reference")):
-        labels = [lbl for lbl, _ in _build_rungs(opts)]
-        assert labels and not any("pallas" in lbl for lbl in labels), labels
-    assert [lbl for lbl, _ in _build_rungs(SolveOptions())] == \
-        ["local xla", "local reference"]
-
-
-def test_resilient_chain_unchanged_on_cpu():
-    labels = [lbl for lbl, _ in
-              _build_rungs(SolveOptions(backend="pallas_persistent"))]
-    assert labels == ["local pallas_persistent", "local pallas",
-                      "local xla", "local reference"]
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_resilient_chain_is_xla_then_reference(monkeypatch, platform):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    for opts in (SolveOptions(), SolveOptions(backend="xla")):
+        assert [lbl for lbl, _ in _build_rungs(opts)] == \
+            ["local xla", "local reference"]
 
 
 def test_compiler_refusal_is_not_transient():
@@ -189,32 +152,21 @@ def test_compiler_refusal_is_not_transient():
         == "transient"
     assert _classify(RuntimeError("device preempted")) == "transient"
     problem = graph.generate(16, avg_degree=4.0, seed=0)
-    with chaos.failing_backend("pallas_persistent", "pallas",
+    with chaos.failing_backend("xla",
                                exc_type=NotImplementedError) as state:
         rr = resilient_solve(MatchingProblem.from_graph(problem),
-                             SolveOptions(backend="pallas_persistent"))
-    assert state["n"] == 2  # one attempt per refused rung, no retries
-    assert [a.outcome for a in rr.report.attempts] == \
-        ["unsupported", "unsupported", "ok"]
-    assert rr.report.backend_used == "local xla" and rr.report.degraded
+                             SolveOptions())
+    assert state["n"] == 1  # one attempt for the refused rung, no retries
+    assert [a.outcome for a in rr.report.attempts] == ["unsupported", "ok"]
+    assert rr.report.backend_used == "local reference" and rr.report.degraded
     assert bool(np.asarray(rr.result.perfect))
 
 
 def test_harness_defaults_skip_what_a_tpu_cannot_run(on_tpu, capsys):
-    from benchmarks import bench_kernels
     from repro.experiments import paper_eval
 
-    assert paper_eval.default_backends() == ("reference", "xla")
-    assert bench_kernels._backends() == ("reference", "xla")
+    assert paper_eval.LOCAL_BACKENDS == ("reference", "xla")
     # a grid beyond the attached devices is skipped, never a child process
     grid = (jax.device_count() + 1, 1)
     assert paper_eval._eval_grid([], {}, grid, 0, {}) == []
     assert f"skip grid {grid[0]}x1" in capsys.readouterr().out
-
-
-def test_harness_defaults_unchanged_on_cpu():
-    from benchmarks import bench_kernels
-    from repro.experiments import paper_eval
-
-    assert paper_eval.default_backends() == paper_eval.LOCAL_BACKENDS
-    assert bench_kernels._backends() == kdispatch.MEASURED_BACKENDS

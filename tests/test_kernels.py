@@ -154,3 +154,45 @@ def test_router_swap_mutual_best_consistency():
     # mutual-best pairs must be symmetric
     for i in np.nonzero(mutual)[0]:
         assert mutual[bp_np[i]]
+
+
+# ------------------- execution mode (non-TPU != interpreter) ----------------
+
+
+def test_resolve_execution_per_platform(monkeypatch):
+    from repro.kernels import backend as kbackend
+
+    for plat, expect in [("cpu", True), ("tpu", False), ("gpu", False),
+                         ("cuda", False), ("rocm", False)]:
+        monkeypatch.setattr(jax, "default_backend", lambda p=plat: p)
+        mode = kbackend.resolve_execution(None)
+        assert mode.interpret is expect, (plat, mode)
+        assert mode.platform == plat
+        assert mode.forced is False
+        assert mode.describe() == f"interpret={expect}"
+
+
+def test_resolve_execution_explicit_wins_and_is_recorded(monkeypatch):
+    from repro.kernels import backend as kbackend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kbackend.resolve_interpret(True) is True
+    last = kbackend.last_execution()
+    assert last.forced is True and last.interpret is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert kbackend.resolve_interpret(False) is False
+    assert kbackend.last_execution().forced is True
+
+
+def test_kernel_wrappers_record_last_execution(monkeypatch):
+    from repro.kernels import backend as kbackend
+
+    monkeypatch.setattr(kbackend, "_LAST_MODE", None)
+    # a shape no other test traces, so the wrapper resolves its mode anew
+    a = jnp.ones((24, 40), jnp.float32)
+    cycle_gain_padded(a, a, jnp.zeros(24), jnp.zeros(40))
+    last = kbackend.last_execution()
+    assert last is not None
+    assert last.platform == jax.default_backend()
+    expect = jax.default_backend() not in kbackend.COMPILED_PLATFORMS
+    assert last.interpret is expect

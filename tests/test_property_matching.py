@@ -13,7 +13,7 @@ from repro.core.api import MatchingProblem, SolveOptions, solve
 from repro.sparse.ops import lex_searchsorted
 
 SET = dict(max_examples=25, deadline=None)
-LOCAL_BACKENDS = ("reference", "xla", "pallas")
+LOCAL_BACKENDS = ("reference", "xla")
 
 
 @st.composite

@@ -104,7 +104,7 @@ def test_verify_result_batched_labels_instances():
 
 def test_transient_failure_retries_on_same_rung():
     p = _problem()
-    with chaos.failing_backend("xla", "pallas", fail_times=1):
+    with chaos.failing_backend("xla", fail_times=1):
         rr = resilient_solve(p)
     assert [a.outcome for a in rr.report.attempts] == ["transient", "ok"]
     assert not rr.report.degraded
@@ -114,7 +114,7 @@ def test_transient_failure_retries_on_same_rung():
 def test_persistent_failure_degrades_to_reference():
     p = _problem()
     ref = solve(p, SolveOptions(backend="reference"))
-    with chaos.failing_backend("xla", "pallas"):
+    with chaos.failing_backend("xla"):
         rr = resilient_solve(p)
     assert rr.report.backend_used == "local reference"
     assert rr.report.degraded
@@ -124,7 +124,7 @@ def test_persistent_failure_degrades_to_reference():
 
 def test_deadline_expires_with_report():
     p = _problem()
-    with chaos.failing_backend("xla", "pallas", "reference",
+    with chaos.failing_backend("xla", "reference",
                                exc_type=TransientFault):
         with pytest.raises(DeadlineExceededError) as exc:
             resilient_solve(p, resilience=ResilientOptions(
@@ -134,14 +134,14 @@ def test_deadline_expires_with_report():
 
 def test_every_rung_failing_raises_verification_error():
     p = _problem()
-    with chaos.failing_backend("xla", "pallas", "reference",
+    with chaos.failing_backend("xla", "reference",
                                exc_type=RuntimeError):
         with pytest.raises(VerificationError) as exc:
-            resilient_solve(p, SolveOptions(backend="pallas"),
+            resilient_solve(p, SolveOptions(backend="xla"),
                             resilience=ResilientOptions(
                                 max_retries=0, backoff_s=0.0))
-    # one transient attempt per local rung (pallas, xla, ref), no retries
-    assert len(exc.value.report.attempts) == 3
+    # one transient attempt per local rung (xla, ref), no retries
+    assert len(exc.value.report.attempts) == 2
 
 
 def test_request_errors_propagate_untouched():
@@ -162,10 +162,13 @@ def test_request_errors_propagate_untouched():
 
 
 def test_rung_labels_without_grid():
-    labels = [lbl for lbl, _ in _build_rungs(SolveOptions(backend="pallas"))]
-    assert labels == ["local pallas", "local xla", "local reference"]
+    labels = [lbl for lbl, _ in _build_rungs(SolveOptions())]
+    assert labels == ["local xla", "local reference"]
     labels = [lbl for lbl, _ in _build_rungs(SolveOptions(backend="xla"))]
     assert labels == ["local xla", "local reference"]
+    labels = [lbl for lbl, _ in
+              _build_rungs(SolveOptions(backend="reference"))]
+    assert labels == ["local reference"]
 
 
 def test_grid_rung_strips_distributed_knobs_on_fallback():
@@ -216,6 +219,6 @@ def test_resilient_matcher_serves_and_caches():
 
 def test_resilient_matcher_degrades_like_solve():
     p = _problem()
-    with chaos.failing_backend("xla", "pallas"):
+    with chaos.failing_backend("xla"):
         rr = ResilientMatcher(p)(p)
     assert rr.report.backend_used == "local reference"

@@ -148,7 +148,7 @@ if START == "cold":
     with jax.enable_x64(True):
         text = engine.lower(brow, bcol, bval).as_text(debug_info=True)
     scopes = [s for s in ("greedy_round", "mcm_bfs_layer", "mcm_trace_flip",
-                          "awac_sweep", "awac_select_augment", "a2a_exchange")
+                          "awac_winners", "awac_select_augment", "a2a_exchange")
               if s in text]
 else:
     # the same pattern with drifted values, warm-started from the cold
@@ -200,7 +200,7 @@ def test_counters_identical_on_every_route(start):
                             if start == "cold" else None)
     if start == "cold":
         assert scopes == ["greedy_round", "mcm_bfs_layer", "mcm_trace_flip",
-                          "awac_sweep", "awac_select_augment", "a2a_exchange"]
+                          "awac_winners", "awac_select_augment", "a2a_exchange"]
     else:
         assert first_counters["greedy_rounds"] == 0
         assert first_counters["greedy_matched"] == 0
@@ -367,7 +367,7 @@ def test_named_scopes_in_the_lowered_phases():
             window_depth(n))
     scopes = {"greedy_maximal": ["greedy_round"],
               "mcm": ["mcm_bfs_layer", "mcm_trace_flip"],
-              "_awac_loop": ["awac_sweep", "awac_select_augment"]}
+              "_awac_loop": ["awac_winners", "awac_select_augment"]}
     for name, low in lowered.items():
         text = low.as_text(debug_info=True)
         for scope in scopes[name]:

@@ -3,9 +3,8 @@
 The TPU compiler is installed with jax, so ``jit(...).lower(shapes)
 .compile()`` against a described ``v5e:2x2`` topology raises whatever the
 chip's compiler would raise, with no chip present. Covered: the local XLA
-route (greedy, MCM, the AWAC loop under x64), the batched route, the 2x2
-``shard_map`` grid engine, and the two Pallas sweeps, which Mosaic refuses
-today (strict xfails: the change that makes them compile must flip them).
+route (greedy, MCM, the AWAC loop under x64), the batched route and the
+2x2 ``shard_map`` grid engine.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and every
@@ -189,34 +188,3 @@ def test_grid_mcm_bfs_layer_scatters_no_edge_block(grid_2x2):
         operands = re.search(r" scatter\(([^)]*)\)", ln).group(1)
         for name in re.findall(r"%([\w.\-]+)", operands):
             assert cap not in _dims(_shape_of(text, name)), (name, ln)
-
-
-def _sweep_args(one_chip, n=2048, cap=16_384):
-    (row, col, val), row_ptr, st = _shapes(one_chip, (1, cap), n)
-    return (row, col, val, row_ptr, *st,
-            jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip))
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="Mosaic: 'Only 2D gather is supported' — the "
-                          "awac_sweep kernel's 1-D gathers have no TPU "
-                          "lowering")
-def test_pallas_sweep_compiles(one_chip):
-    from repro.kernels.cycle_gain.awac_sweep import awac_sweep_batched
-
-    jax.jit(lambda *a: awac_sweep_batched(
-        *a, n=2048, te=512, window_steps=5, interpret=False)).lower(
-        *_sweep_args(one_chip)).compile()
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="Mosaic: 'Unimplemented primitive in Pallas TPU "
-                          "lowering for KernelType.TC: dynamic_slice' — the "
-                          "persistent kernel's tile loop")
-def test_pallas_persistent_compiles(one_chip):
-    from repro.kernels.cycle_gain.persistent import awac_persistent_batched
-
-    go0 = jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=one_chip)
-    jax.jit(lambda *a: awac_persistent_batched(
-        *a, n=2048, te=512, window_steps=5, max_iter=10,
-        interpret=False)).lower(*_sweep_args(one_chip), go0).compile()
