@@ -975,11 +975,14 @@ class _DistBatchedAWPM:
 
     def partition(self, row, col, val):
         """[B, cap] padded COO -> device-sharded [Pr, Pc, B, cap_blk] blocks
-        (plus the partition and the measured windowed-search depth)."""
+        (plus the partition and the measured windowed-search depth). The
+        instances whose edges arrived lex-sorted, split into blocks by one
+        linear pass, are the solve's ``partition_sorted``."""
         with telemetry.span("repro.partition"):
             part = partition_coo_2d_batched(
                 row, col, val, self.n, self.spec.pr, self.spec.pc,
                 cap=self.cap)
+            telemetry.count({"partition_sorted": part.sorted_instances})
         sharding = jax.sharding.NamedSharding(
             self.spec.mesh, self.spec.block_spec_batched())
         brow, bcol, bval = telemetry.call(
@@ -992,8 +995,11 @@ class _DistBatchedAWPM:
 
     def run(self, row, col, val, state: MatchState | None = None):
         """row/col/val: padded [B, cap] lex-sorted COO sharing n (see
-        ``core.batch.stack_graphs``). Returns (MatchState with [B, n + 1]
-        fields, awac_iters [B], dropped) — per instance bit-identical to
+        ``core.batch.stack_graphs``); the host splits lex-sorted edges into
+        the grid's blocks in O(nnz), and any other input by a three-key
+        lexsort to the same blocks (``sparse.partition``). Returns
+        (MatchState with [B, n + 1] fields, awac_iters [B], dropped) — per
+        instance bit-identical to
         ``core.batch.awpm_batched(row, col, val, n)``; the engine's counters
         are added to the solve in progress. An explicit replicated
         ``state`` skips greedy/MCM and runs the AWAC phase only (the

@@ -35,9 +35,12 @@ Counters (summed over a batch's instances):
   a2a_filled       those of them that held a candidate
   a2a_bytes        the bytes those slots carried (``dist.a2a_slot_bytes``:
                    the payloads, and the validity byte on the unpacked path)
+  partition_sorted instances whose edges the grid's host partition found
+                   lex-sorted and split into blocks by one linear pass
+                   (``sparse.partition._block_order``), not the lexsort
 
-The ``a2a_*`` counters are the grid engine's alone: a solve that ran no
-grid exchange reads None there, not 0.
+The ``a2a_*`` counters and ``partition_sorted`` are the grid engine's
+alone: a solve that ran no grid exchange reads None there, not 0.
 """
 from __future__ import annotations
 
@@ -53,7 +56,7 @@ import numpy as np
 CAPACITY = 64
 
 #: Counters only the grid engine keeps; None where it did not run.
-GRID_COUNTERS = ("a2a_slots", "a2a_filled", "a2a_bytes")
+GRID_COUNTERS = ("a2a_slots", "a2a_filled", "a2a_bytes", "partition_sorted")
 #: The counters a solve record holds; each other one starts at 0.
 COUNTERS = ("greedy_rounds", "greedy_matched", "mcm_bfs_layers",
             "mcm_sorted_layers", "awac_augmented",

@@ -7,6 +7,14 @@ Per-block edge lists are padded to a common capacity so the stacked arrays
 
 Entries store GLOBAL indices (int32). Padding entries have row = col = n (the
 global sentinel) and val = 0; every consumer masks on ``row < n``.
+
+Each block holds its edges sorted by (row, col), in input order among equal
+pairs: the order ``np.lexsort((col, row, block))`` gives. Edges that arrive
+lex-sorted by (row, col), as the grid driver asks and
+``MatchingProblem.from_coo`` makes them, already hold that order inside each
+block, so one stable sort by block id alone gives it in O(nnz)
+(``_block_order``); any other input falls back to the three-key lexsort,
+with the same result.
 """
 from __future__ import annotations
 
@@ -51,10 +59,21 @@ class Partition2DBatched:
     row: np.ndarray  # [pr, pc, B, cap] int32 global rows, lex-sorted per block
     col: np.ndarray  # [pr, pc, B, cap] int32 global cols
     val: np.ndarray  # [pr, pc, B, cap] float32
+    sorted_instances: int  # instances whose edges arrived lex-sorted
 
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
+
+
+def _block_ids(row, col, n: int, pr: int, pc: int):
+    """One padded instance's real edges (``row < n``) and each one's block,
+    ``(row // br) * pc + col // bc``: (rows, cols, mask, block ids)."""
+    br = -(-n // pr)
+    bc = -(-n // pc)
+    m = row < n
+    r, c = row[m], col[m]
+    return r, c, m, (r // br) * pc + c // bc
 
 
 def block_occupancy(row, col, n: int, pr: int, pc: int) -> np.ndarray:
@@ -69,11 +88,34 @@ def block_occupancy(row, col, n: int, pr: int, pc: int) -> np.ndarray:
         return np.stack([
             block_occupancy(r, c, n, pr, pc) for r, c in zip(row, col)
         ])
-    br = -(-n // pr)
-    bc = -(-n // pc)
-    m = row < n
-    blk = (row[m] // br) * pc + col[m] // bc
+    blk = _block_ids(row, col, n, pr, pc)[3]
     return np.bincount(blk, minlength=pr * pc).reshape(pr, pc).astype(np.int32)
+
+
+def _block_order(r, c, blk, n: int) -> tuple[np.ndarray, bool]:
+    """The permutation that orders the edges by (block, row, col), stable
+    among equal pairs: ``np.lexsort((c, r, blk))``'s. Also whether it took
+    the linear pass: where the keys ``r * (n + 1) + c`` are non-decreasing
+    (checked in O(nnz)), a stable sort by block id alone keeps each block's
+    edges in that order, and numpy sorts a key of 16 bits or fewer by radix.
+    Any other input falls back to the lexsort."""
+    key = r.astype(np.int64) * (n + 1) + c
+    if bool((key[1:] >= key[:-1]).all()):
+        small = blk.astype(np.min_scalar_type(int(blk.max(initial=0))))
+        return np.argsort(small, kind="stable"), True
+    return np.lexsort((c, r, blk)), False
+
+
+def _fill_blocks(R, C, V, r, c, v, order, counts) -> None:
+    """Block p's edges, ``order``'s run p of ``counts[p]``, into R[p], C[p],
+    V[p] (the rest keeps its padding)."""
+    starts = np.zeros(len(counts) + 1, dtype=np.int64)
+    np.cumsum(counts, out=starts[1:])
+    for p in range(R.shape[0]):
+        idx = order[starts[p]:starts[p + 1]]
+        R[p, :idx.size] = r[idx]
+        C[p, :idx.size] = c[idx]
+        V[p, :idx.size] = v[idx]
 
 
 def plan_block_cap(row, col, n: int, pr: int, pc: int,
@@ -93,11 +135,8 @@ def partition_coo_2d(
     val = np.asarray(val, dtype=np.float32)
     br = -(-n // pr)
     bc = -(-n // pc)
-    a = row // br
-    b = col // bc
-    blk = a * pc + b
-    order = np.lexsort((col, row, blk))
-    row, col, val, blk = row[order], col[order], val[order], blk[order]
+    blk = (row // br) * pc + col // bc
+    order = _block_order(row, col, blk, n)[0]
     counts = np.bincount(blk, minlength=pr * pc)
     max_nnz = int(counts.max()) if counts.size else 0
     if cap is None:
@@ -110,13 +149,7 @@ def partition_coo_2d(
     R = np.full((pr * pc, cap), n, dtype=np.int32)
     C = np.full((pr * pc, cap), n, dtype=np.int32)
     V = np.zeros((pr * pc, cap), dtype=np.float32)
-    starts = np.zeros(pr * pc + 1, dtype=np.int64)
-    np.cumsum(counts, out=starts[1:])
-    for p in range(pr * pc):
-        s, e = starts[p], starts[p + 1]
-        R[p, : e - s] = row[s:e]
-        C[p, : e - s] = col[s:e]
-        V[p, : e - s] = val[s:e]
+    _fill_blocks(R, C, V, row, col, val, order, counts)
     return Partition2D(
         n=n,
         pr=pr,
@@ -153,7 +186,14 @@ def partition_coo_2d_batched(
     b = row.shape[0]
     br = -(-n // pr)
     bc = -(-n // pc)
-    occ = block_occupancy(row, col, n, pr, pc)  # [B, pr, pc]
+    edges, sorted_instances = [], 0
+    for i in range(b):
+        r, c, m, blk = _block_ids(row[i], col[i], n, pr, pc)
+        order, linear = _block_order(r, c, blk, n)
+        sorted_instances += linear
+        edges.append((r, c, val[i][m], order,
+                      np.bincount(blk, minlength=pr * pc)))
+    occ = np.stack([counts for *_, counts in edges]).reshape(b, pr, pc)
     max_occ = int(occ.max(initial=0))
     if cap is None:
         cap = max(_round_up(max_occ, pad_align), pad_align)
@@ -164,24 +204,13 @@ def partition_coo_2d_batched(
     R = np.full((pr * pc, b, cap), n, dtype=np.int32)
     C = np.full((pr * pc, b, cap), n, dtype=np.int32)
     V = np.zeros((pr * pc, b, cap), dtype=np.float32)
-    for i in range(b):
-        m = row[i] < n
-        r, c, v = row[i][m], col[i][m], val[i][m]
-        blk = (r // br) * pc + c // bc
-        order = np.lexsort((c, r, blk))
-        r, c, v, blk = r[order], c[order], v[order], blk[order]
-        counts = np.bincount(blk, minlength=pr * pc)
-        starts = np.zeros(pr * pc + 1, dtype=np.int64)
-        np.cumsum(counts, out=starts[1:])
-        for p in range(pr * pc):
-            s, e = starts[p], starts[p + 1]
-            R[p, i, : e - s] = r[s:e]
-            C[p, i, : e - s] = c[s:e]
-            V[p, i, : e - s] = v[s:e]
+    for i, (r, c, v, order, counts) in enumerate(edges):
+        _fill_blocks(R[:, i], C[:, i], V[:, i], r, c, v, order, counts)
     return Partition2DBatched(
         n=n, b=b, pr=pr, pc=pc, br=br, bc=bc, cap=cap,
         nnz=np.transpose(occ, (1, 2, 0)).astype(np.int32),
         row=R.reshape(pr, pc, b, cap),
         col=C.reshape(pr, pc, b, cap),
         val=V.reshape(pr, pc, b, cap),
+        sorted_instances=sorted_instances,
     )

@@ -421,3 +421,112 @@ def test_partition_refuses_to_truncate():
         partition_coo_2d(row[0][m], col[0][m], val[0][m], n, 2, 2, cap=8)
     with pytest.raises(ValueError, match="batched"):
         partition_coo_2d_batched(row[0], col[0], val[0], n, 2, 2)
+
+
+# --------------------------------------------------------------------------
+# in-process: the block order is the plain three-key lexsort's
+# --------------------------------------------------------------------------
+
+
+def _empty_batch(n=16, cap=8):
+    """One instance with no real edges: padding alone."""
+    return (np.full((1, cap), n, np.int32), np.full((1, cap), n, np.int32),
+            np.zeros((1, cap), np.float32))
+
+
+def _three_sizes(n=50):
+    """B = 3 lex-sorted instances of different nnz, padded to one
+    capacity; n = 50 leaves the last block row and column short."""
+    from repro.core import batch, graph
+
+    gs = [graph.generate(n, avg_degree=d, kind=k, seed=s) for d, k, s in
+          ((1.5, "uniform", 1), (4.0, "powerlaw", 2), (9.0, "banded", 3))]
+    assert len({g.nnz for g in gs}) == 3
+    return tuple(np.asarray(x) for x in batch.stack_graphs(gs))
+
+
+BATCHES = {"skewed": (_skewed_batch, 16), "no edges": (_empty_batch, 16),
+           "three sizes": (_three_sizes, 50)}
+
+
+def _shuffled(row, col, val):
+    """Each instance's slots, padding included, in a random order."""
+    rng = np.random.default_rng(11)
+    perm = np.stack([rng.permutation(row.shape[1]) for _ in row])
+    return tuple(np.take_along_axis(x, perm, axis=1) for x in (row, col, val))
+
+
+@pytest.mark.parametrize("order", ["sorted", "shuffled"])
+@pytest.mark.parametrize("grid", [(1, 1), (2, 2), (2, 4), (4, 2)],
+                         ids=lambda g: f"{g[0]}x{g[1]}")
+@pytest.mark.parametrize("batch", sorted(BATCHES))
+def test_batched_partition_equals_the_lexsort_reference(batch, grid, order):
+    """Field by field against a plain ``np.lexsort((col, row, block))`` of
+    each instance's real edges: lex-sorted input takes the linear pass and
+    counts in ``sorted_instances``; a shuffled copy takes the lexsort
+    fallback, counts only where it holds fewer than two edges, and gives
+    the same arrays as the sorted input."""
+    from repro.sparse.partition import partition_coo_2d_batched
+
+    make, n = BATCHES[batch]
+    pr, pc = grid
+    row, col, val = sorted_input = make()
+    if order == "shuffled":
+        row, col, val = _shuffled(row, col, val)
+    part = partition_coo_2d_batched(row, col, val, n, pr, pc)
+    b = row.shape[0]
+    br, bc = -(-n // pr), -(-n // pc)
+    counts = np.zeros((b, pr * pc), np.int64)
+    for i in range(b):
+        m = row[i] < n
+        r, c, v = row[i][m], col[i][m], val[i][m]
+        blk = (r // br) * pc + c // bc
+        ref = np.lexsort((c, r, blk))
+        counts[i] = np.bincount(blk, minlength=pr * pc)
+        for p in range(pr * pc):
+            sel, k = ref[blk[ref] == p], counts[i, p]
+            a, q = divmod(p, pc)
+            np.testing.assert_array_equal(part.row[a, q, i, :k], r[sel])
+            np.testing.assert_array_equal(part.col[a, q, i, :k], c[sel])
+            np.testing.assert_array_equal(
+                part.val[a, q, i, :k].view(np.uint32), v[sel].view(np.uint32))
+            assert (part.row[a, q, i, k:] == n).all()
+            assert (part.col[a, q, i, k:] == n).all()
+            assert (part.val[a, q, i, k:] == 0).all()
+    np.testing.assert_array_equal(
+        part.nnz, counts.reshape(b, pr, pc).transpose(1, 2, 0))
+    assert part.nnz.dtype == np.int32
+    assert part.cap == max(-(-int(counts.max()) // 8) * 8, 8)
+    real = (row < n).sum(axis=1)
+    assert part.sorted_instances == (b if order == "sorted"
+                                     else int((real < 2).sum()))
+    if order == "shuffled":
+        again = partition_coo_2d_batched(*sorted_input, n, pr, pc)
+        for field in ("row", "col", "val", "nnz"):
+            assert getattr(part, field).tobytes() == \
+                getattr(again, field).tobytes(), field
+
+
+@pytest.mark.parametrize("shuffled", [(), (0,), (2,), (0, 1, 2)],
+                         ids=lambda s: "shuffled " + (",".join(map(str, s))
+                                                      or "none"))
+def test_partition_sorted_counts_the_sorted_instances(shuffled):
+    """The grid driver's partition adds the instances that took the linear
+    pass to the solve's ``partition_sorted``; the shuffled ones take the
+    fallback and hand the device the same blocks."""
+    from repro.core import dist, telemetry
+
+    n = 50
+    row, col, val = _three_sizes(n)
+    drv = dist._DistBatchedAWPM(dist.GridSpec(dist.make_mesh((1, 1))), n)
+    mixed = [x.copy() for x in (row, col, val)]
+    for x, s in zip(mixed, _shuffled(row, col, val)):
+        x[list(shuffled)] = s[list(shuffled)]
+    with telemetry.record():
+        part = drv.partition(*mixed)[0]
+    assert telemetry.recent(1)[0]["counters"]["partition_sorted"] == \
+        3 - len(shuffled)
+    base = drv.partition(row, col, val)[0]
+    for field in ("row", "col", "val", "nnz"):
+        assert getattr(part, field).tobytes() == \
+            getattr(base, field).tobytes(), field

@@ -1,6 +1,7 @@
 """Sparse substrate unit tests."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.sparse import (
     coo_sddmm,
@@ -96,6 +97,58 @@ def test_partition_2d_roundtrip():
             k = int(part.nnz[a, b])
             pairs = list(zip(part.row[a, b, :k].tolist(), part.col[a, b, :k].tolist()))
             assert pairs == sorted(pairs)
+
+
+def _lexsort_order(rr, cc, n, pr, pc):
+    """The plain reference order: ``np.lexsort((col, row, block))`` and the
+    block of each edge."""
+    blk = (rr // -(-n // pr)) * pc + cc // -(-n // pc)
+    order = np.lexsort((cc, rr, blk))
+    return order, blk[order]
+
+
+def _with_duplicates(n, m, seed):
+    """A lex-sorted instance in which some (row, col) pairs repeat with other
+    values: the order keeps the input order among equal pairs."""
+    rr, cc, vv = _rand_coo(n, m, seed)
+    rep = np.arange(0, rr.size, 3)
+    rr, cc = np.concatenate([rr, rr[rep]]), np.concatenate([cc, cc[rep]])
+    vv = np.concatenate([vv, vv[rep] + 1])
+    order = np.lexsort((cc, rr))  # stable: each original before its copy
+    return rr[order], cc[order], vv[order]
+
+
+@pytest.mark.parametrize("order", ["sorted", "shuffled"])
+@pytest.mark.parametrize("grid", [(1, 1), (2, 2), (2, 4), (4, 2)],
+                         ids=lambda g: f"{g[0]}x{g[1]}")
+@pytest.mark.parametrize("instance", ["distinct", "duplicates"])
+def test_partition_2d_equals_the_lexsort_reference(instance, grid, order):
+    """Every block holds its edges in the plain three-key lexsort's order,
+    for lex-sorted input (the linear pass) and for a shuffled copy (the
+    lexsort fallback); counts, capacity and padding as before."""
+    n, (pr, pc) = 50, grid
+    if instance == "distinct":
+        rr, cc, vv = _rand_coo(n, 300, seed=7)
+    else:
+        rr, cc, vv = _with_duplicates(n, 300, seed=7)
+    if order == "shuffled":
+        perm = np.random.default_rng(3).permutation(rr.size)
+        rr, cc, vv = rr[perm], cc[perm], vv[perm]
+    part = partition_coo_2d(rr, cc, vv, n, pr, pc)
+    ref, blk = _lexsort_order(rr, cc, n, pr, pc)
+    counts = np.bincount(blk, minlength=pr * pc)
+    np.testing.assert_array_equal(part.nnz.reshape(-1), counts)
+    assert part.cap == max(-(-int(counts.max()) // 8) * 8, 8)
+    for p in range(pr * pc):
+        k, sel = counts[p], ref[blk == p]
+        a, b = divmod(p, pc)
+        np.testing.assert_array_equal(part.row[a, b, :k], rr[sel])
+        np.testing.assert_array_equal(part.col[a, b, :k], cc[sel])
+        np.testing.assert_array_equal(part.val[a, b, :k].view(np.uint32),
+                                      vv[sel].view(np.uint32))
+        assert (part.row[a, b, k:] == n).all()
+        assert (part.col[a, b, k:] == n).all()
+        assert (part.val[a, b, k:] == 0).all()
 
 
 def test_padded_csr():

@@ -175,7 +175,8 @@ def test_counters_identical_on_every_route(start):
     sorted-segment scan, so their ``mcm_sorted_layers`` equal their
     ``mcm_bfs_layers``; the batched route and every warm solve, whose MCM
     runs on the batched engine, report None. Only the grid keeps the
-    exchange's counters (``a2a_*``); every other route reports None
+    exchange's counters (``a2a_*``) and ``partition_sorted``, 1 for its
+    one lex-sorted instance, cold or warm; every other route reports None
     there."""
     import json
 
@@ -183,7 +184,7 @@ def test_counters_identical_on_every_route(start):
         f"START = {start!r}\n" + ROUTES_SCRIPT, 4)
         .strip().splitlines()[-1])
     first_counters, first_mates, first_sorted, first_a2a = out.pop("single")
-    assert first_a2a == [None, None, None]
+    assert first_a2a == [None, None, None, None]
     for route, (counters, mates, sorted_layers, a2a) in out.items():
         assert counters == first_counters, (route, counters, first_counters)
         assert mates == first_mates, route
@@ -191,10 +192,11 @@ def test_counters_identical_on_every_route(start):
         assert sorted_layers == (counters["mcm_bfs_layers"] if scanned
                                  else None), (route, sorted_layers)
         if route == "grid 2x2":
-            slots, filled, nbytes = a2a
+            slots, filled, nbytes, partition_sorted = a2a
             assert 0 < filled <= slots and nbytes == 13 * slots, a2a
+            assert partition_sorted == 1, a2a
         else:
-            assert a2a == [None, None, None], route
+            assert a2a == [None, None, None, None], route
     assert first_counters["mcm_bfs_layers"] > 0
     assert first_sorted == (first_counters["mcm_bfs_layers"]
                             if start == "cold" else None)
